@@ -21,7 +21,7 @@ events, deadline-miss rate, rejected/expired/quarantined totals) banked
 in a schema-versioned, atomically-written `soak-report.json`
 (serving/slo.py) plus the append-only `quarantine.jsonl` poison ledger.
 
-`--bounded` is the chip_watcher.sh edition (minutes, not hours): one
+`--bounded` is the short edition (minutes, not hours): one
 episode per fault family, the gloo kill drill included. The full
 schedule adds the die (vanish) and stall (watchdog) episodes. Exit 0
 iff every episode met its expectation AND the terminal accounting
@@ -711,7 +711,7 @@ def main(argv=None) -> int:
         description="long-horizon chaos soak (docs/RESILIENCE.md §8)"
     )
     p.add_argument("--bounded", action="store_true",
-                   help="the chip_watcher edition: one episode per "
+                   help="the short edition: one episode per "
                    "fault family, minutes not hours")
     p.add_argument("--out", default="output/soak", metavar="DIR")
     p.add_argument("--ranks", type=positive_int, default=2,

@@ -35,7 +35,7 @@ def telemetry_windowed_run(model, variant: str, nt: int, warmup: int,
     spanned windows — per-step PERCENTILES need more than the single
     sample model.run's one timed window gives (aggregate's p50/p90/p99
     over windows is what catches a straggling stretch the mean hides).
-    Each window boundary costs one device-fetch sync (the span's
+    Each window boundary costs one device sync (the span's
     correctness requirement); windows of many steps amortize it, exactly
     as tic/toc always did.
 
@@ -248,8 +248,8 @@ def main(argv=None) -> int:
             for d in jax.devices()[:n]
         ):
             # A rung whose submesh holds none of this process's devices:
-            # this process cannot allocate on it (jax 0.4.x refuses a
-            # device assignment with no local devices) and the compute is
+            # this process cannot allocate on it (jax refuses a device
+            # assignment with no local devices) and the compute is
             # entirely local to the owning process(es) — sit the rung out
             # (banking its steps for the health plane's global counter).
             steps_banked += args.nt

@@ -35,8 +35,8 @@ drivers of all three workloads and verifies the collective sequence is
 identical across rank-roles and every declared donation actually aliased.
 
 stdlib-only by design (the lowered audit is the one deliberate
-exception — it imports jax, and only runs when invoked): the pinned
-jax-0.4.37 image runs the AST gate with no optional deps, and a
+exception — it imports jax, and only runs when invoked): the installed
+image runs the AST gate with no optional deps, and a
 repo-wide walk stays fast enough for tier-1.
 """
 

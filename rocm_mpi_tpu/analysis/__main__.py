@@ -13,7 +13,7 @@ The repo gate (scripts/lint.sh) runs:
 which is the whole-program interprocedural pass (per-file rules + the
 GL08/GL10/GL01 engine), compared against the committed baseline, with
 the stale-suppression audit on and the machine-readable findings
-artifact published atomically for chip_watcher to archive. `--changed` restricts the reported scope to
+artifact published atomically. `--changed` restricts the reported scope to
 git-dirty files plus their import-graph neighbors — the fast dev loop.
 """
 

@@ -82,8 +82,8 @@ def to_json(findings, files_scanned: int) -> str:
 
 def write_findings(path, findings, files_scanned: int) -> None:
     """Publish the JSON document atomically (tmp + os.replace): the
-    machine-readable artifact lint.sh banks and chip_watcher archives
-    must never be observable torn — GL09's own discipline."""
+    machine-readable artifact lint.sh banks must never be observable
+    torn — GL09's own discipline."""
     path = pathlib.Path(path)
     path.parent.mkdir(parents=True, exist_ok=True)
     tmp = str(path) + ".tmp"

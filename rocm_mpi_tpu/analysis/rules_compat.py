@@ -1,12 +1,13 @@
 """GL03 — compat drift.
 
-The installed jax is 0.4.37 while the code targets newer jax; every
+The installed jax is 0.9.0, and the code supports that one version. Every
 "where does this live / what is it called" question is answered exactly
-once, in `rocm_mpi_tpu/utils/compat.py` (API drift) and
-`rocm_mpi_tpu/utils/backend.py` (backend knobs). A call site that goes to
-`jax.experimental` / version-specific spellings directly re-introduces
-per-call-site drift — the class of bug that made the seed's tier-1 suite
-fail collection outright before PR 1 centralized the shims.
+once, in `rocm_mpi_tpu/utils/compat.py` (API spellings) and
+`rocm_mpi_tpu/utils/backend.py` (backend knobs), so the next version bump
+is fixed in one place. A call site that goes to `jax.experimental` /
+version-specific spellings directly re-introduces per-call-site drift —
+the class of bug that made the seed's tier-1 suite fail collection
+outright before PR 1 centralized the shims.
 
 Checked spellings (each with its owning chokepoint, which is allowlisted):
 
@@ -42,10 +43,11 @@ class CompatDriftRule(Rule):
     name = "compat-drift"
     severity = "error"
     rationale = (
-        "jax 0.4.37 vs modern-API drift (shard_map home, check_vma, "
-        "cost_analysis shape, jax_num_cpu_devices) is fixed once in "
-        "utils/compat.py + utils/backend.py; direct use re-opens the "
-        "per-call-site drift that broke the seed's test collection"
+        "the installed jax (0.9.0) is reached through one chokepoint "
+        "(shard_map home, check_vma, cost_analysis shape, "
+        "jax_num_cpu_devices): utils/compat.py + utils/backend.py; direct "
+        "use re-opens the per-call-site drift that broke the seed's test "
+        "collection"
     )
     hint = "see docs/ANALYSIS.md#gl03"
 
@@ -117,9 +119,8 @@ class CompatDriftRule(Rule):
                 elif dotted.endswith("lax.axis_size") and not in_compat:
                     findings.append(ctx.finding(
                         node, self,
-                        "lax.axis_size does not exist on jax 0.4.x",
-                        "use rocm_mpi_tpu.utils.compat.axis_size (psum(1) "
-                        "fallback)",
+                        "lax.axis_size used directly",
+                        "use rocm_mpi_tpu.utils.compat.axis_size",
                     ))
             # ---- calls ---------------------------------------------------
             elif isinstance(node, ast.Call):
@@ -128,8 +129,8 @@ class CompatDriftRule(Rule):
                         fn.attr == "cost_analysis" and not in_compat:
                     findings.append(ctx.finding(
                         node, self,
-                        ".cost_analysis() returns a per-partition LIST on "
-                        "0.4.x and a dict on newer jax",
+                        ".cost_analysis() called directly — its return "
+                        "shape has changed across jax versions",
                         "use rocm_mpi_tpu.utils.compat.cost_analysis_dict",
                     ))
                 elif isinstance(fn, ast.Attribute) and fn.attr == "update" \
@@ -138,19 +139,18 @@ class CompatDriftRule(Rule):
                             "jax_num_cpu_devices":
                         findings.append(ctx.finding(
                             node, self,
-                            "jax_num_cpu_devices config knob does not "
-                            "exist on jax 0.4.x (silently breaks the "
-                            "virtual-CPU-mesh harness)",
+                            "jax_num_cpu_devices set directly (the "
+                            "virtual-CPU-mesh knob has one owner)",
                             "use rocm_mpi_tpu.utils.backend."
-                            "set_cpu_device_count (XLA_FLAGS fallback)",
+                            "set_cpu_device_count",
                         ))
                 elif astutil.tail_name(astutil.call_name(node)) == \
                         "ShapeDtypeStruct" and not in_compat:
                     if astutil.call_kwarg(node, "vma") is not None:
                         findings.append(ctx.finding(
                             node, self,
-                            "ShapeDtypeStruct(vma=…) is a jax>=0.9 "
-                            "spelling; 0.4.x has no vma tracking",
+                            "ShapeDtypeStruct(vma=…) spelled directly — "
+                            "vma tracking is version-specific",
                             "use rocm_mpi_tpu.utils.compat.out_struct_like",
                         ))
         return findings

@@ -42,7 +42,7 @@ from rocm_mpi_tpu.analysis import astutil
 from rocm_mpi_tpu.analysis.core import ModuleContext, Rule
 
 # The committed artifact families (scripts/lint.sh schema-checks these
-# names; chip_watcher archives them). `quarantine` and `soak-report`
+# names). `quarantine` and `soak-report`
 # joined with the request-plane hardening (docs/SERVING.md "SLOs and
 # admission"; docs/RESILIENCE.md §8); `fleet` covers the ticket
 # journal and the merged fleet report (docs/SERVING.md "The fleet");

@@ -2,7 +2,7 @@
 
 Purpose: demonstrate that the framework layers the diffusion flagship is
 built from — cartesian mesh (parallel.mesh), ppermute halo exchange
-(parallel.halo), Pallas padded-block kernels (ops.*), fetch-forced timers
+(parallel.halo), Pallas padded-block kernels (ops.*), block_until_ready-synced timers
 (utils.metrics) — are workload-agnostic. This is what a *user* adding their
 own stencil model to the framework writes; the reference has no analog (it
 ships exactly one physics model), so this module is additive, not parity.

@@ -71,9 +71,32 @@ def _compute_itemsize(dtype) -> int:
     return max(jnp.dtype(dtype).itemsize, 4)
 
 
+def _tiled_elems(shape) -> int:
+    """Elements a block of `shape` occupies in VMEM: Mosaic lays the last
+    two axes out in (8 sublane x 128 lane) tiles, so a 4-column hide slab
+    fills 128 lanes per row. Budgeting the logical size instead let a
+    12224x4 slab take the whole-block kernel and overrun scoped VMEM by
+    6.5 MB on v5e (tests/test_tpu_compile.py)."""
+    dims = list(shape)
+    if dims:
+        dims[-1] = -(-dims[-1] // 128) * 128
+    if len(dims) >= 2:
+        dims[-2] = -(-dims[-2] // 8) * 8
+    return math.prod(dims)
+
+
 def _compute_nbytes(arr) -> int:
-    """In-kernel working-set bytes per field (see _compute_itemsize)."""
+    """In-kernel working-set bytes per field (see _compute_itemsize).
+    Logical size: the VMEM-resident admission and chunk-class policies
+    were measured against it."""
     return arr.size * _compute_itemsize(arr.dtype)
+
+
+def _block_nbytes(arr) -> int:
+    """Bytes one whole-block operand takes in VMEM as tiled (see
+    _tiled_elems): the whole-block vs striped choice of the per-step
+    kernels, where the logical size let a thin slab overrun."""
+    return _tiled_elems(arr.shape) * _compute_itemsize(arr.dtype)
 
 
 def _out_struct(shape, exemplar):
@@ -151,7 +174,7 @@ def fused_step_padded(Tp, Cp, lam, dt, spacing, interpret=None):
     # are rejected by pallas_call; physics constants are static anyway).
     lam, dt = float(lam), float(dt)
     inv_d2 = tuple(1.0 / (float(d) * float(d)) for d in spacing)
-    nbytes = _compute_nbytes(Cp)
+    nbytes = _block_nbytes(Cp)
     if Tp.ndim in (2, 3) and nbytes > _VMEM_BLOCK_BUDGET_BYTES:
         return _fused_step_striped(Tp, Cp, lam, dt, inv_d2, interpret)
     kernel = functools.partial(
@@ -224,9 +247,7 @@ def _striped_call(kernel, Tp, C, interpret):
     n1, rest = core[0], core[1:]
     rest_p = tuple(n + 2 for n in rest)
     # bf16 operands are upcast to f32 in-kernel: size stripes at f32 width.
-    row_bytes = _compute_itemsize(C.dtype)
-    for n in rest_p:
-        row_bytes *= n
+    row_bytes = _compute_itemsize(C.dtype) * _tiled_elems(rest_p)
     tm = _stripe_height(row_bytes)
     grid = (-(-n1 // tm),)
     zeros = (0,) * len(rest)
@@ -307,7 +328,7 @@ def fused_step_cm(Tp, Cm, spacing, interpret=None):
             "interpret mode for f64 parity runs"
         )
     inv_d2 = tuple(1.0 / (float(d) * float(d)) for d in spacing)
-    nbytes = _compute_nbytes(Cm)
+    nbytes = _block_nbytes(Cm)
     if Tp.ndim in (2, 3) and nbytes > _VMEM_BLOCK_BUDGET_BYTES:
         kernel = functools.partial(_fused_kernel_striped_cm, inv_d2=inv_d2)
         return _striped_call(kernel, Tp, Cm, interpret)
@@ -581,7 +602,7 @@ def _multi_step_kernel(T_ref, Cm_ref, out_ref, *, inv_d2, chunk,
     T' = A∘T + Σ_ax c_ax∘(roll(T,-1,ax)+roll(T,+1,ax)) with A = 1−2Σc_ax
     and c_ax = Cm·inv_d2[ax] hoisted into a once-per-launch prologue —
     one fewer VPU op per axis per step, measured 8 % faster at 252² f32
-    (425→390 ns/step, docs/perstep_bounds_r3.txt protocol). When the
+    (425→390 ns/step, round 3, within-run A/B). When the
     spacing is equal on every axis (true of the benchmark geometry) the
     per-axis coefficients collapse to ONE array c = Cm·inv with
     A = 1−2·ndim·c and the roll pairs sum before the single multiply —
@@ -742,8 +763,9 @@ def fused_multi_step(T, Cp, lam, dt, spacing, n_steps, chunk=None, interpret=Non
     bf16 fields are storage-only (r4): the kernel computes the whole
     chunk in f32 and rounds back once per chunk, so bf16 keeps its
     traffic savings without per-step quantization drift
-    (_upcast_for_compute; error curve in BASELINE.md). Admission and
-    chunk policy therefore budget at f32 width.
+    (_upcast_for_compute; error curve in
+    docs/bf16_error_cpu252_*_r4.txt). Admission and chunk policy
+    therefore budget at f32 width.
     """
     if interpret is None:
         interpret = _interpret_default()
@@ -1037,10 +1059,10 @@ def fused_multi_step_hbm(T, Cp, lam, dt, spacing, n_steps, block_steps=None,
     Python int; for traced n_steps the trip count floors), axis-0 length
     divisible by the depth's stripe height (tb_geometry: 16 for k <= 8,
     32 beyond), and — for the deeper geometry — rows narrow enough for
-    the slab to fit the Mosaic compile envelope (tb_slab_fits). Measured on one v5e chip at 12288²
-    f32: ~2 ms/step — effective T_eff ~900 GB/s, above the chip's raw HBM
-    bandwidth, which a 3-passes-per-step design can never reach (current
-    measured numbers: BASELINE.md's results table).
+    the slab to fit the Mosaic compile envelope (tb_slab_fits). Measured on
+    one v5e chip at 12288² f32, k=8: 1.61 ms/step (chip_smoke.py, PR 21;
+    PERF.md) — more steps per second than a 3-passes-per-step design can
+    reach at the chip's HBM bandwidth.
     """
     if interpret is None:
         interpret = _interpret_default()

@@ -42,20 +42,6 @@ def process_id() -> int:
     return 0
 
 
-def _enable_cpu_collectives() -> None:
-    """Multi-process CPU runs need gloo collectives selected explicitly
-    on jax 0.4.x (`jax_cpu_collectives_implementation` defaults to
-    'none' there — cross-process programs then fail with 'Multiprocess
-    computations aren't implemented on the CPU backend'); newer jax
-    defaults to gloo and drops the knob, hence best-effort."""
-    import jax
-
-    try:
-        jax.config.update("jax_cpu_collectives_implementation", "gloo")
-    except (AttributeError, ValueError):
-        pass
-
-
 def maybe_initialize_distributed() -> bool:
     """Call jax.distributed.initialize() when a multi-host launch is
     requested (RMT_DISTRIBUTED=1, or explicit JAX coordinator env).
@@ -114,7 +100,6 @@ def maybe_initialize_distributed() -> bool:
     from rocm_mpi_tpu.resilience import faults
 
     faults.fault_point("init")
-    _enable_cpu_collectives()
     jax.distributed.initialize(**kwargs)
     _initialized = True
     return True

@@ -280,6 +280,16 @@ def _make_region_splice(
             else:
                 pad_idx = tuple(slice(lo, hi + 2) for lo, hi in bounds)
                 tp = jax.tree_util.tree_map(lambda a: a[pad_idx], Tp)
+                # The barrier keeps the TPU compiler (libtpu 0.0.34) from
+                # fusing a slab kernel's output into its DUS consumer:
+                # with two such slabs that fusion pass aborts the
+                # process (tests/test_tpu_compile.py pins the 2x2 hide
+                # step). Only libtpu needs it; it is kept on every backend
+                # so the CPU tests run the program the chip compiles. Its
+                # cost is not measured (PERF.md, open questions).
+                return lax.optimization_barrier(
+                    padded_update(tp, cp, lam, dt, spacing)
+                )
             return padded_update(tp, cp, lam, dt, spacing)
 
         # (2)+(3) region updates, (4) spliced in place: every box is

@@ -60,7 +60,8 @@ _SHAPE_RE = re.compile(
     r"\b(pred|s8|u8|s16|u16|bf16|f16|s32|u32|f32|s64|u64|f64|c64|c128)"
     r"\[([0-9,]*)\]"
 )
-_OP_RE = re.compile(r"^(?:ROOT\s+)?%[\w.\-]+\s*=\s*(.*?)\s([\w\-]+)\(")
+_OP_RE = re.compile(r"^(?:ROOT\s+)?%([\w.\-]+)\s*=\s*(.*?)\s([\w\-]+)\(")
+_NAME_RE = re.compile(r"%([\w.\-]+)")
 
 # Ops that move no tensor bytes of their own (parameters/constants are
 # charged where they are consumed, as operand reads).
@@ -79,14 +80,28 @@ def _tokens_bytes(text: str) -> list[int]:
     ]
 
 
-def hlo_wire_bytes(hlo_text: str) -> int:
-    """Bytes this partition's program SENDS over collectives per
-    invocation: the summed operand bytes of its `collective-permute` ops.
-    Unlike the modeled total, this figure is exact and lowering-stable —
-    a schedule that re-grows an exchange (the old per-sweep coefficient
-    re-exchange) moves it by whole slabs, so the gate holds it to the
-    analytic wire ideal with almost no tolerance."""
-    total = 0
+def _call_args(line: str, start: int) -> str:
+    """The text inside the op's parentheses, `start` just past `op(`."""
+    depth = 1
+    for i in range(start, len(line)):
+        c = line[i]
+        if c == "(":
+            depth += 1
+        elif c == ")":
+            depth -= 1
+            if depth == 0:
+                return line[start:i]
+    return line[start:]
+
+
+def entry_ops(hlo_text: str):
+    """Yield (op, result_bytes, operand_bytes) per instruction of the
+    optimized entry computation. HLO text (jax 0.9.0) names operands
+    without their types (`collective-permute(%wrapped_slice)`), so
+    operand shapes resolve through a name -> bytes table built from the
+    computation's own definitions, which precede their uses. An operand
+    name the table lacks is reported as None."""
+    sizes: dict[str, int] = {}
     in_entry = False
     for raw in hlo_text.splitlines():
         line = raw.strip()
@@ -98,10 +113,44 @@ def hlo_wire_bytes(hlo_text: str) -> int:
         if not in_entry:
             continue
         m = _OP_RE.match(line)
-        if m and m.group(2) == "collective-permute":
-            body = line.split(", metadata=")[0]
-            result = sum(_tokens_bytes(m.group(1)))
-            total += sum(_tokens_bytes(body)) - result
+        if not m:
+            continue
+        name, result_text, op = m.groups()
+        result_bytes = sum(_tokens_bytes(result_text))
+        sizes[name] = result_bytes
+        operands = [
+            sizes.get(n) for n in _NAME_RE.findall(_call_args(line, m.end()))
+        ]
+        yield op, result_bytes, operands
+
+
+def hlo_wire_bytes(hlo_text: str) -> int:
+    """Bytes this partition's program SENDS over collectives per
+    invocation: the summed operand bytes of its `collective-permute` ops.
+    Unlike the modeled total, this figure is exact and lowering-stable —
+    a schedule that re-grows an exchange (the old per-sweep coefficient
+    re-exchange) moves it by whole slabs, so the gate holds it to the
+    analytic wire ideal with almost no tolerance.
+
+    Raises ValueError when a collective's operand cannot be sized or a
+    program with collectives reads 0 bytes: a parse that silently reads
+    0 would let every wire row of the gate pass without measuring."""
+    total = collectives = 0
+    for op, _, operands in entry_ops(hlo_text):
+        if op != "collective-permute":
+            continue
+        collectives += 1
+        if not operands or None in operands:
+            raise ValueError(
+                "collective-permute operand not defined in the entry "
+                "computation: HLO text format drift"
+            )
+        total += sum(operands)
+    if collectives and not total:
+        raise ValueError(
+            f"{collectives} collective-permute op(s) but 0 wire bytes: "
+            "HLO text format drift"
+        )
     return total
 
 
@@ -122,41 +171,29 @@ def hlo_bytes_accessed(hlo_text: str) -> int:
         window it emits);
       * `collective-permute`: operand + result (send + receive);
       * parameters, constants, tuple plumbing: free (charged at use).
+
+    Raises ValueError on an operand it cannot size, as hlo_wire_bytes
+    does: counting it as 0 would loosen every traffic row unseen.
     """
     total = 0
-    in_entry = False
-    for raw in hlo_text.splitlines():
-        line = raw.strip()
-        if line.startswith("ENTRY "):
-            in_entry = True
-            continue
-        if in_entry and line.startswith("}"):
-            break
-        if not in_entry or "=" not in line:
-            continue
-        m = _OP_RE.match(line)
-        if not m:
-            continue
-        result_text, op = m.group(1), m.group(2)
+    for op, result_bytes, operands in entry_ops(hlo_text):
         if op in _FREE_OPS:
             continue
-        # Strip trailing metadata: shapes never appear inside it, but the
-        # op_name strings could in principle — cut at ", metadata=".
-        body = line.split(", metadata=")[0]
-        result_bytes = sum(_tokens_bytes(result_text))
-        operand_bytes = sum(_tokens_bytes(body)) - result_bytes
+        if None in operands:
+            raise ValueError(
+                f"{op} operand not defined in the entry computation: "
+                "HLO text format drift"
+            )
         if op == "dynamic-update-slice":
-            toks = _tokens_bytes(body[m.end():])
-            update = toks[1] if len(toks) > 1 else result_bytes
-            total += 2 * update
+            total += 2 * (operands[1] if len(operands) > 1 else result_bytes)
         elif op in ("slice", "dynamic-slice"):
             total += 2 * result_bytes
         elif op == "fusion":
             total += result_bytes + sum(
-                min(t, result_bytes) for t in _tokens_bytes(body[m.end():])
+                min(t, result_bytes) for t in operands
             )
         else:
-            total += operand_bytes + result_bytes
+            total += sum(operands) + result_bytes
     return total
 
 

@@ -1,10 +1,8 @@
 """Deterministic fault injection (docs/RESILIENCE.md §3).
 
-Three rounds of accelerator outage (165 failed probes over ~11.5 h,
-docs/chip_watcher_r5.log) made failure this framework's most common
-input — so failure must be INJECTABLE, deterministically, at the exact
-points the resilience layer defends, or its recovery paths are dead code
-until the next real outage tests them in production.
+Failure must be INJECTABLE, deterministically, at the exact points the
+resilience layer defends, or its recovery paths are dead code until the
+next real outage tests them in production.
 
 A fault plan is a comma-separated spec, from the `--inject-fault` app
 flag or the RMT_INJECT_FAULT env var (the launcher forwards it to every
@@ -29,8 +27,8 @@ rank):
                             handle it
     truncate-latest         after the next completed save, truncate the
                             largest file of the newest checkpoint step
-    delay=S@step=K          sleep S seconds at step K (flapping-tunnel
-                            stall analog; exercises heartbeat reporting)
+    delay=S@step=K          sleep S seconds at step K (transient-stall
+                            analog; exercises heartbeat reporting)
     stall@step=K            block FOREVER in a time.monotonic busy-wait
                             at step K — the wedged-in-a-collective
                             analog. Unlike `delay` it never resumes, so

@@ -1,12 +1,10 @@
 """Supervised segmented runs: retry/backoff around checkpointed advance
 (docs/RESILIENCE.md §1).
 
-The round-5 outage record (165 failed probes over ~11.5 h,
-docs/chip_watcher_r5.log) is the operating reality: the backend flaps,
-and a long run's expected failure count is > 0. `run_supervised` is the
-process-level answer — the same discipline the bash chip watcher applies
-from outside, moved inside the run where it can resume from the latest
-VALID checkpoint instead of restarting from step 0:
+A long run's expected failure count is > 0 (preemption, a lost host,
+checkpoint-IO errors). `run_supervised` is the process-level answer: it
+resumes from the latest VALID checkpoint instead of restarting from
+step 0:
 
     state = run_supervised(advance, init_state, nt, directory, every)
 
@@ -61,7 +59,7 @@ def default_retryable(exc: BaseException) -> bool:
     if isinstance(exc, (KeyboardInterrupt, SystemExit)):
         return False
     # XlaRuntimeError subclasses RuntimeError in every jax this targets;
-    # OSError covers checkpoint-IO flaps (the tunnel drops mid-write).
+    # OSError covers checkpoint-IO failures (a write dropped mid-save).
     return isinstance(exc, (RuntimeError, OSError))
 
 

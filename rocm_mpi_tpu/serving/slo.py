@@ -303,8 +303,8 @@ def validate_decomposition_block(block) -> list[str]:
 
 def write_soak_report(path, doc: dict) -> None:
     """Atomic tmp+rename write (GL09 discipline: the soak report is a
-    schema-versioned artifact an out-of-process reader — chip_watcher's
-    archive step, the next triage — may pick up while the soak is still
+    schema-versioned artifact an out-of-process reader — the next
+    triage — may pick up while the soak is still
     finishing)."""
     problems = validate_soak_report(doc)
     if problems:

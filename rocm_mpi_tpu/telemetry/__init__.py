@@ -8,7 +8,7 @@ bool so instrumented code costs nothing when telemetry is off:
     telemetry.configure(directory="out/telemetry", rank=jax.process_index())
     with telemetry.span("step_window", phase="step", steps=50) as sp:
         T = advance(T, Cp, 50)
-        sp.sync(T)                      # device-fetch sync, not block_until_ready
+        sp.sync(T)                      # jax.block_until_ready
     telemetry.gauge("run.gpts", r.gpts)
     telemetry.record_event("restored", step=120)   # resilience kinds
 

@@ -371,7 +371,7 @@ def _validate_elastic_record(doc: dict) -> list[str]:
 # Event families whose archived records carry committed inner structure
 # (docs/RESILIENCE.md §7): the preemption decision trail and the
 # storage-fault plane. Validated wherever a telemetry JSONL stream gets
-# banked (chip_watcher archives rank streams per burst) — a drifted
+# banked — a drifted
 # writer must fail here, not as an unreadable loss-window audit after
 # the next real eviction/outage.
 _GUARDED_EVENT_PREFIXES = ("preempt.", "ckpt.")

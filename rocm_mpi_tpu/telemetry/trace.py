@@ -1,6 +1,6 @@
 """Chrome trace-event export: one track per rank, openable in Perfetto.
 
-Replaces the raw `docs/prof_trace_hide8192_r3`-style dumps with the
+Complements raw `jax.profiler` (.xplane.pb) dumps with the
 standard trace-event JSON every Chrome/Perfetto build renders
 (https://ui.perfetto.dev, chrome://tracing). Mapping:
 

@@ -1,29 +1,26 @@
-"""jax version compatibility shims (installed: 0.4.37; code targets newer).
+"""JAX API chokepoint for the installed jax (0.9.0).
 
 One module owns every "where does this live / what is it called in this
-jax" question, so version drift is fixed in exactly one place:
+jax" question, so the next version bump is fixed in exactly one place
+(graftlint GL03 forbids the raw spellings anywhere else):
 
-* `shard_map` — newer jax exports it at the top level and calls the
-  replication-check kwarg `check_vma`; 0.4.x has it under
-  `jax.experimental.shard_map` with the kwarg named `check_rep`. The
-  wrapper resolves the import once and renames the kwarg to whatever the
-  resolved implementation actually accepts (either direction, so the
-  call sites stay written against the modern API).
+* `shard_map`, `axis_size`, `cost_analysis_dict`, `out_struct_like` —
+  the modern-API spellings the call sites use;
+* `install_compile_listener` — the private dispatch hook the compile
+  tracker taps;
+* the jax.experimental modules the framework uses (`pallas`, its `tpu`
+  sublayer, `multihost_utils`), resolved lazily via module `__getattr__`
+  (PEP 562) so importing compat for shard_map alone does not pay the
+  Pallas import. This module's `__all__` is the one allowlist a version
+  bump has to revisit.
 
 `utils.backend.set_cpu_device_count` is the same idea for the
 virtual-CPU-device knob.
-
-The jax.experimental modules the framework uses (`pallas`, its `tpu`
-sublayer, `multihost_utils`) resolve HERE too, lazily via module
-`__getattr__` (PEP 562) so importing compat for shard_map alone does not
-pay the Pallas import: graftlint rule GL03 forbids `jax.experimental`
-anywhere else in the tree, which makes this module's `__all__` the one
-stable allowlist a version bump has to revisit.
 """
 
 from __future__ import annotations
 
-import inspect
+from jax import shard_map
 
 __all__ = [
     "axis_size",
@@ -36,44 +33,18 @@ __all__ = [
     "shard_map",
 ]
 
-try:  # newer jax: top-level export
-    from jax import shard_map as _shard_map_impl
-except ImportError:  # jax 0.4.x
-    from jax.experimental.shard_map import shard_map as _shard_map_impl
-
-_SHARD_MAP_PARAMS = frozenset(
-    inspect.signature(_shard_map_impl).parameters
-)
-
-
-def shard_map(*args, **kwargs):
-    """`jax.shard_map` with the replication-check kwarg renamed to match
-    the installed implementation (`check_vma` <-> `check_rep`)."""
-    for ours, theirs in (("check_vma", "check_rep"), ("check_rep", "check_vma")):
-        if ours in kwargs and ours not in _SHARD_MAP_PARAMS:
-            kwargs[theirs] = kwargs.pop(ours)
-    return _shard_map_impl(*args, **kwargs)
-
 
 def axis_size(axis_name) -> int:
-    """`lax.axis_size` (newer jax) for 0.4.x too: `psum(1, name)` of the
-    static literal 1 constant-folds to the mesh axis size at trace time —
-    a Python int, usable to build ppermute permutations."""
+    """`lax.axis_size`: the mesh axis size as a Python int at trace time,
+    usable to build ppermute permutations."""
     from jax import lax
 
-    if hasattr(lax, "axis_size"):
-        return lax.axis_size(axis_name)
-    return lax.psum(1, axis_name)
+    return lax.axis_size(axis_name)
 
 
 def cost_analysis_dict(compiled) -> dict:
-    """`compiled.cost_analysis()` as a flat dict: 0.4.x returns a
-    one-dict-per-partition LIST (take the first), newer jax the dict
-    itself; both normalize to {} when analysis is unavailable."""
-    cost = compiled.cost_analysis()
-    if isinstance(cost, (list, tuple)):
-        cost = cost[0] if cost else {}
-    return dict(cost or {})
+    """`compiled.cost_analysis()` as a dict ({} when unavailable)."""
+    return dict(compiled.cost_analysis() or {})
 
 
 BACKEND_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
@@ -85,24 +56,18 @@ _COMPILE_LISTENER_MODE: str | None = None
 
 def install_compile_listener(on_interval, on_event=None) -> str | None:
     """Tap jax's compile pipeline for the compile tracker
-    (telemetry/compiles.py). Where the hook lands is pure version drift,
-    so it lives HERE, the one chokepoint a jax bump revisits:
-
-    * preferred ("named"): wrap `jax._src.dispatch.log_elapsed_time`,
-      the context manager every trace/lower/backend-compile interval in
-      0.4.x runs under — its `fun_name` is the per-program identity the
-      public monitoring API does not carry. Call sites resolve it as a
-      module attribute at call time, so the install-once rebind below is
-      effective without reimporting anything.
-    * fallback ("events"): `jax.monitoring`'s duration listener — same
-      events, `name=None` (per-program attribution degrades to totals,
-      the tracker still counts).
+    (telemetry/compiles.py) by wrapping `jax._src.dispatch.log_elapsed_time`,
+    the context manager every trace/lower/backend-compile interval runs
+    under — its `fun_name` is the per-program identity the public
+    monitoring API does not carry. Call sites resolve it as a module
+    attribute at call time, so the install-once rebind below is effective
+    without reimporting anything.
 
     `on_interval(event, name, dur_s)` receives every completed interval
     (event is e.g. BACKEND_COMPILE_EVENT); `on_event(event)` receives
     point events (persistent-cache hit/miss). Both are wrapped so a
     listener exception can never break a compile. Installs at most once
-    per process; returns the active mode ("named"/"events"/None).
+    per process; returns the mode ("named").
     """
     global _COMPILE_LISTENER_MODE
     if _COMPILE_LISTENER_MODE is not None:
@@ -116,95 +81,61 @@ def install_compile_listener(on_interval, on_event=None) -> str | None:
         except Exception:  # noqa: BLE001 — never break a compile
             pass
 
-    mode = None
-    try:
-        from jax._src import dispatch as _dispatch
+    from jax._src import dispatch as _dispatch
 
-        _orig = _dispatch.log_elapsed_time
+    _orig = _dispatch.log_elapsed_time
 
-        @contextlib.contextmanager
-        def _tapped_log_elapsed_time(*args, **kwargs):
-            # Signature-transparent on purpose: the pinned jax calls
-            # (fmt, fun_name=…, event=…), but a bumped jax that adds or
-            # renames a parameter must cost ATTRIBUTION, not the run —
-            # a TypeError here would propagate out of every jit trace.
-            fun_name = kwargs.get("fun_name")
-            event = kwargs.get("event")
-            if len(args) > 1 and fun_name is None:
-                fun_name = args[1]
-            if len(args) > 2 and event is None:
-                event = args[2]
-            t0 = time.monotonic()
-            with _orig(*args, **kwargs):
-                yield
-            # Only a COMPLETED interval counts (an aborted compile is an
-            # error, not a compile); jax's own listeners already fired
-            # inside _orig's exit.
-            _safe_interval(event, fun_name, time.monotonic() - t0)
+    @contextlib.contextmanager
+    def _tapped_log_elapsed_time(*args, **kwargs):
+        # Signature-transparent: the installed jax calls
+        # (fmt, fun_name=…, event=…); a parameter a later jax adds or
+        # renames must cost ATTRIBUTION, not the run — a TypeError here
+        # would propagate out of every jit trace.
+        fun_name = kwargs.get("fun_name")
+        event = kwargs.get("event")
+        if len(args) > 1 and fun_name is None:
+            fun_name = args[1]
+        if len(args) > 2 and event is None:
+            event = args[2]
+        t0 = time.monotonic()
+        with _orig(*args, **kwargs):
+            yield
+        # Only a COMPLETED interval counts (an aborted compile is an
+        # error, not a compile); jax's own listeners already fired
+        # inside _orig's exit.
+        _safe_interval(event, fun_name, time.monotonic() - t0)
 
-        # The install-once seam this function exists for — not a
-        # trace-time knob (GL02's hazard); cached programs are
-        # unaffected, only future compiles pass through the tap.
-        # (carried a GL02 inline suppression until the
-        # --strict-suppressions audit proved it dead: the purity rule
-        # only flags module-state writes reachable from traced bodies,
-        # and this install-once seam never was)
-        _dispatch.log_elapsed_time = _tapped_log_elapsed_time
-        mode = "named"
-    except Exception:  # noqa: BLE001 — private-module drift: fall back
-        try:
-            import jax.monitoring
-
-            jax.monitoring.register_event_duration_secs_listener(
-                lambda event, duration, **kw: _safe_interval(
-                    event, None, duration
-                )
-            )
-            mode = "events"
-        except Exception:  # noqa: BLE001
-            return None
+    # The install-once seam this function exists for — not a trace-time
+    # knob (GL02's hazard); cached programs are unaffected, only future
+    # compiles pass through the tap.
+    _dispatch.log_elapsed_time = _tapped_log_elapsed_time
     if on_event is not None:
-        try:
-            import jax.monitoring
+        import jax.monitoring
 
-            def _safe_event(event, **kw):
-                try:
-                    on_event(event)
-                except Exception:  # noqa: BLE001
-                    pass
+        def _safe_event(event, **kw):
+            try:
+                on_event(event)
+            except Exception:  # noqa: BLE001
+                pass
 
-            jax.monitoring.register_event_listener(_safe_event)
-        except Exception:  # noqa: BLE001 — hit/miss counts degrade to 0
-            pass
-    _COMPILE_LISTENER_MODE = mode
-    return mode
+        jax.monitoring.register_event_listener(_safe_event)
+    _COMPILE_LISTENER_MODE = "named"
+    return _COMPILE_LISTENER_MODE
 
 
 def _resolve_lazy(name: str):
-    """The jax.experimental residents, resolved on first attribute access.
-
-    Newer jax is probed first where a module has (or grows) a top-level
-    home, the 0.4.x spelling second — the same both-directions policy as
-    the shard_map shim, so neither an upgrade nor the pinned image breaks
-    the import site.
-    """
+    """The jax.experimental residents, resolved on first attribute access."""
     if name == "pallas":
-        try:
-            from jax import pallas  # newer jax, if/when it graduates
-        except ImportError:
-            from jax.experimental import pallas
+        from jax.experimental import pallas
+
         return pallas
     if name == "pallas_tpu":
-        try:
-            from jax.pallas import tpu  # type: ignore[import-not-found]
-        except ImportError:
-            from jax.experimental.pallas import tpu
+        from jax.experimental.pallas import tpu
+
         return tpu
     if name == "multihost_utils":
-        try:
-            from jax import multihost_utils  # newer jax
-        except ImportError:
-            from jax.experimental import multihost_utils
+        from jax.experimental import multihost_utils
+
         return multihost_utils
     raise AttributeError(f"module {__name__!r} has no attribute {name!r}")
 
@@ -216,15 +147,11 @@ def __getattr__(name: str):  # PEP 562: lazy jax.experimental resolution
 
 
 def out_struct_like(shape, exemplar):
-    """ShapeDtypeStruct matching `exemplar`'s dtype and (where the
-    installed jax tracks it) mesh-varying axes: under jax>=0.9 check_vma,
-    pallas_call outputs inside shard_map must declare which mesh axes
-    they vary over, so propagate the input's vma set; 0.4.x has no vma
-    tracking and takes the plain struct."""
+    """ShapeDtypeStruct matching `exemplar`'s dtype and mesh-varying axes:
+    under check_vma, pallas_call outputs inside shard_map must declare
+    which mesh axes they vary over, so propagate the input's vma set."""
     import jax
 
-    if hasattr(jax, "typeof"):
-        return jax.ShapeDtypeStruct(
-            shape, exemplar.dtype, vma=jax.typeof(exemplar).vma
-        )
-    return jax.ShapeDtypeStruct(shape, exemplar.dtype)
+    return jax.ShapeDtypeStruct(
+        shape, exemplar.dtype, vma=jax.typeof(exemplar).vma
+    )

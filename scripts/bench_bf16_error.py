@@ -1,7 +1,7 @@
 """bf16-vs-f32 error at run length (VERDICT r3 #4).
 
 The bf16 fast path is a *labeled precision trade* the user opts into with
---dtype bf16 (BASELINE.md): it halves per-step memory traffic. Round 3
+--dtype bf16: it halves per-step memory traffic. Round 3
 documented its error at 4 steps only; this harness characterizes the
 error-vs-steps curve out to the reference's full 1000-step run
 (/root/reference/scripts/diffusion_2D_perf.jl:47 — nt=1000) at the
@@ -34,7 +34,6 @@ sys.path.insert(0, str(pathlib.Path(__file__).resolve().parent.parent))
 
 
 from rocm_mpi_tpu.utils.backend import (
-    apply_platform_override,
     enable_persistent_cache,
     require_accelerator,
 )  # noqa: E402
@@ -129,7 +128,6 @@ def main(argv=None) -> int:
                    "curves)")
     args = p.parse_args(argv)
 
-    apply_platform_override()
     enable_persistent_cache()
     import jax
 

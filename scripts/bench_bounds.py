@@ -25,7 +25,6 @@ RTT vs collective latency vs kernel time, then attack the dominant term"):
    exchanges.
 
 Run on the chip:  python scripts/bench_bounds.py [N]
-Committed output: docs/perstep_bounds_r3.txt
 """
 
 import functools

@@ -7,7 +7,7 @@ machinery whose *benefit* (hiding the exchange) needs ≥2 chips, but whose
 *cost* does not: on one chip the same decomposition can be timed against
 the monolithic whole-shard kernel the perf variant runs.
 
-A/B protocol (within one process, the docs/perstep_bounds_r3.txt style):
+A/B protocol (within one process, baseline first and last):
 for each shard size × b_width, time
   mono  — the per-step Cm-masked whole-shard program
           (ops.pallas_kernels.masked_step, what perf runs unsharded), and
@@ -34,7 +34,6 @@ WARMUP = 4_096
 
 
 from rocm_mpi_tpu.utils.backend import (
-    apply_platform_override,
     enable_persistent_cache,
     require_accelerator,
 )  # noqa: E402
@@ -48,7 +47,6 @@ def main(argv=None) -> int:
     require_accel = "--require-accelerator" in argv
     argv = [a for a in argv if a != "--require-accelerator"]
     timed = int(argv[0]) if argv else TIMED_DEFAULT
-    apply_platform_override()
     enable_persistent_cache()
     import jax
     import jax.numpy as jnp

@@ -12,7 +12,7 @@ result against the host-staged oracle.
 
 Exercises every multi-host branch VERDICT r1 flagged as dead code:
 distributed.maybe_initialize_distributed, gather.gather_to_host0's
-process_count>1 path, and metrics.force's non-addressable branch — plus
+process_count>1 path, and metrics.force on a non-addressable array — plus
 the deep-halo sweep (width-k exchange crossing the process boundary, the
 flagship multi-chip schedule) against the same oracle, and the wave
 workload's perf, hide (overlap), and deep-sweep paths (the state-pair
@@ -70,7 +70,7 @@ def main() -> int:
     step = model.step_fn("shard")
     for _ in range(n_steps):
         T = step(T, Cp)
-    metrics.force(T)  # non-addressable branch: block_until_ready, no fetch
+    metrics.force(T)  # non-addressable global array
 
     # Deep-halo sweep over the same mesh: the width-4 ghost exchange (one
     # message per neighbor per 4 steps — the flagship multi-chip schedule)

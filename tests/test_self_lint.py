@@ -30,6 +30,7 @@ GATE_SCOPE = [
     str(REPO / "rocm_mpi_tpu"),
     str(REPO / "apps"),
     str(REPO / "bench.py"),
+    str(REPO / "chip_smoke.py"),
 ]
 
 

@@ -113,6 +113,45 @@ ENTRY %main (p0: f64[2,8]) -> f64[2,8] {
 """
         assert hlo_wire_bytes(text) == 2 * 2 * 8 * 8
 
+    def test_bare_operand_names_resolve_through_definitions(self):
+        # jax 0.9.0 prints operands as bare names; both parsers size them
+        # from the entry computation's own definitions.
+        from rocm_mpi_tpu.perf.traffic import (
+            hlo_bytes_accessed,
+            hlo_wire_bytes,
+        )
+
+        text = """
+HloModule m
+ENTRY %main (p0: f32[2,8]) -> f32[2,8] {
+  %p0 = f32[2,8]{1,0} parameter(0)
+  %s = f32[1,8]{1,0} slice(%p0), slice={[0:1], [0:8]}
+  %cp = f32[1,8]{1,0} collective-permute(%s), channel_id=1, source_target_pairs={{0,1}}
+  ROOT %add = f32[2,8]{1,0} add(%p0, %p0)
+}
+"""
+        assert hlo_wire_bytes(text) == 32
+        # slice 2*32, permute 32+32, add 64+64+64
+        assert hlo_bytes_accessed(text) == 64 + 64 + 192
+
+    @pytest.mark.parametrize("parser", [
+        "hlo_wire_bytes", "hlo_bytes_accessed",
+    ])
+    def test_unsized_operand_is_an_error_not_zero(self, parser):
+        # A parse that reads an unsized operand as 0 bytes would pass
+        # every row of the gate without measuring: refuse instead.
+        from rocm_mpi_tpu.perf import traffic
+
+        text = """
+HloModule m
+ENTRY %main (p0: f32[2,8]) -> f32[2,8] {
+  %p0 = f32[2,8]{1,0} parameter(0)
+  ROOT %cp = f32[2,8]{1,0} collective-permute(%undefined), channel_id=1, source_target_pairs={{0,1}}
+}
+"""
+        with pytest.raises(ValueError, match="format drift"):
+            getattr(traffic, parser)(text)
+
     def test_budgets_file_is_committed_and_sane(self):
         from rocm_mpi_tpu.perf.traffic import load_budgets
 
