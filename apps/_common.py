@@ -86,7 +86,8 @@ def make_parser(
     )
     p.add_argument(
         "--profile", default=None, metavar="DIR",
-        help="trace the timed loop with jax.profiler into DIR",
+        help="trace the run (set-up, warmup, timed steps) with "
+        "jax.profiler into DIR",
     )
     p.add_argument(
         "--deep", type=int, default=0, metavar="K",
@@ -427,9 +428,9 @@ def setup_jax(args):
 
 def profile_context(jax, args):
     """The one --profile idiom (SURVEY.md §5.1): a jax.profiler trace over
-    the timed loop when --profile DIR was given, a no-op otherwise. Shared
-    by run_app and the wave app so the profiling convention cannot
-    diverge between workloads."""
+    the run (set-up, warmup and timed steps) when --profile DIR was
+    given, a no-op otherwise. Shared by run_app and the wave app so the
+    profiling convention cannot diverge between workloads."""
     import contextlib
 
     if getattr(args, "profile", None):

@@ -13,8 +13,9 @@ SURVEY.md §2.2 D8). Reference defaults: fact=12 → 12288², nt=100,
 b_width=(32,4).
 
 The profiling twin (C5, …_perf_hide_prof.jl) is the --profile flag, not a
-file fork: `--profile DIR` wraps the timed loop in jax.profiler.trace
-(warmup excluded), viewable in TensorBoard/Perfetto.
+file fork: `--profile DIR` wraps the run in jax.profiler.trace (the
+fields' set-up, the warmup and the timed steps), viewable in
+TensorBoard/Perfetto.
 
   python apps/diffusion_2d_perf_hide.py --cpu-devices 8 --fact 0 --nx 512 --ny 512
   python apps/diffusion_2d_perf_hide.py --profile /tmp/trace

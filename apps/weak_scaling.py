@@ -120,9 +120,10 @@ def telemetry_windowed_run(model, variant: str, nt: int, warmup: int,
         timer = metrics.Timer(label="step_window", phase="step", steps=w,
                               variant=variant, window=i, driver=driver,
                               workload="diffusion")
-        timer.tic(T)
-        T = advance(T, Cp, w)
-        timer.toc(T)
+        metrics.force(T)
+        with timer:
+            T = advance(T, Cp, w)
+            timer.toc(T)
         wtime += timer.elapsed
         done += w
     flight.progress(step=step_base + done)

@@ -37,6 +37,7 @@ from rocm_mpi_tpu.ops.diffusion import (
 )
 from rocm_mpi_tpu.parallel.halo import exchange_halo, global_boundary_mask
 from rocm_mpi_tpu.parallel.mesh import GlobalGrid, init_global_grid
+from rocm_mpi_tpu.utils import compat
 from rocm_mpi_tpu.utils import metrics
 
 
@@ -123,6 +124,20 @@ def auto_scan_chunk(op: str, grid, dtype, config: str | None) -> int | None:
     if tuned and tuned.get("chunk"):
         return int(tuned["chunk"])
     return None
+
+
+def _driver_layer(advance):
+    """Trace `advance` under layer="driver" (docs/TELEMETRY.md
+    "Device-side names"): the once-per-call prepare and the loop's own
+    ops carry it, and so does every op of the step that no inner layer
+    (exchange, splice) names."""
+
+    @functools.wraps(advance)
+    def tagged(*args, **kwargs):
+        with compat.set_xla_metadata(layer="driver"):
+            return advance(*args, **kwargs)
+
+    return tagged
 
 
 def warn_host_transport_ignored(variant: str, stacklevel: int = 3) -> None:
@@ -472,6 +487,7 @@ class HeatDiffusion:
         dt = cfg.jax_dtype(cfg.dt)
 
         @functools.partial(jax.jit, donate_argnums=0)
+        @_driver_layer
         def advance(T, Cp, n):
             # Loop-invariant coefficient (e.g. the perf rung's Cm), traced
             # once OUTSIDE the fori_loop — zero per-step host round-trips
@@ -526,6 +542,7 @@ class HeatDiffusion:
         )
 
         @functools.partial(jax.jit, donate_argnums=0)
+        @_driver_layer
         def advance(T, Cp, n):
             C = Cp if prep is None else prep(Cp, cfg.lam, dt)
 
@@ -731,6 +748,7 @@ class HeatDiffusion:
         shape1 = (-1,) + (1,) * bgrid.space.ndim
 
         @functools.partial(jax.jit, donate_argnums=0)
+        @_driver_layer
         def advance(Tb, Cp, lane_steps, n):
             C = Cp if prep is None else prep(Cp)
 
@@ -925,9 +943,10 @@ class HeatDiffusion:
                               driver=driver, workload="diffusion")
         if warmup:
             T = advance(T, Cp, warmup)
-        timer.tic(T)
-        T = advance(T, Cp, nt - warmup)
-        wtime = timer.toc(T)
+        metrics.force(T)
+        with timer:
+            T = advance(T, Cp, nt - warmup)
+            wtime = timer.toc(T)
         return RunResult(T=T, wtime=wtime, nt=nt, warmup=warmup, config=cfg)
 
     def _run_single_shard(
@@ -999,9 +1018,10 @@ class HeatDiffusion:
                               steps=nt - warmup, variant=key,
                               workload="diffusion")
         T = advance(T, Cp, warmup)  # n=0 still compiles the shared program
-        timer.tic(T)
-        T = advance(T, Cp, nt - warmup)
-        wtime = timer.toc(T)
+        metrics.force(T)
+        with timer:
+            T = advance(T, Cp, nt - warmup)
+            wtime = timer.toc(T)
         return RunResult(T=T, wtime=wtime, nt=nt, warmup=warmup, config=cfg)
 
     def run_vmem_resident(
@@ -1271,9 +1291,10 @@ class HeatDiffusion:
                               steps=nt - warmup, variant="deep",
                               workload="diffusion")
         T = advance(T, Cp, warmup)
-        timer.tic(T)
-        T = advance(T, Cp, nt - warmup)
-        wtime = timer.toc(T)
+        metrics.force(T)
+        with timer:
+            T = advance(T, Cp, nt - warmup)
+            wtime = timer.toc(T)
         return RunResult(T=T, wtime=wtime, nt=nt, warmup=warmup, config=cfg)
 
     def _run_host_staged(self, nt: int, warmup: int) -> RunResult:
@@ -1298,8 +1319,8 @@ class HeatDiffusion:
                               steps=nt - warmup, variant="shard-host",
                               workload="diffusion")
         T_np = stepper.run(T_np, Cp_np, warmup)
-        timer.tic()
-        T_np = stepper.run(T_np, Cp_np, nt - warmup)
-        wtime = timer.toc()
+        with timer:
+            T_np = stepper.run(T_np, Cp_np, nt - warmup)
+        wtime = timer.elapsed
         T_out = jax.device_put(T_np, self.grid.sharding)
         return RunResult(T=T_out, wtime=wtime, nt=nt, warmup=warmup, config=cfg)

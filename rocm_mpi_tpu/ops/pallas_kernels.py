@@ -189,6 +189,7 @@ def fused_step_padded(Tp, Cp, lam, dt, spacing, interpret=None):
         ],
         out_specs=pl.BlockSpec(memory_space=pltpu.VMEM),
         interpret=interpret,
+        name="fused_step_padded",
     )(Tp, Cp)
 
 
@@ -230,8 +231,9 @@ def _stripe_height(row_bytes: int) -> int:
     return max(8, (per_buffer // max(1, row_bytes)) // 8 * 8)
 
 
-def _striped_call(kernel, Tp, C, interpret):
-    """Shared launch of the 3-slot striped kernels over ceil(n1/tm) stripes.
+def _striped_call(kernel, Tp, C, interpret, name):
+    """Shared launch of the 3-slot striped kernels over ceil(n1/tm) stripes,
+    named `name` in the compiled program (the public function's name).
 
     Output stripe i (tm core rows) reads padded rows [i·tm, i·tm+tm+2),
     assembled in-kernel from padded row-blocks i and i+1 — overlapping
@@ -272,6 +274,7 @@ def _striped_call(kernel, Tp, C, interpret):
             (tm,) + rest, lambda i: (i,) + zeros, memory_space=pltpu.VMEM
         ),
         interpret=interpret,
+        name=name,
     )(Tp, Tp, C)
 
 
@@ -279,7 +282,7 @@ def _fused_step_striped(Tp, Cp, lam, dt, inv_d2, interpret):
     kernel = functools.partial(
         _fused_kernel_striped, lam=lam, dt=dt, inv_d2=inv_d2
     )
-    return _striped_call(kernel, Tp, Cp, interpret)
+    return _striped_call(kernel, Tp, Cp, interpret, "fused_step_padded")
 
 
 # ---------------------------------------------------------------------------
@@ -331,7 +334,7 @@ def fused_step_cm(Tp, Cm, spacing, interpret=None):
     nbytes = _block_nbytes(Cm)
     if Tp.ndim in (2, 3) and nbytes > _VMEM_BLOCK_BUDGET_BYTES:
         kernel = functools.partial(_fused_kernel_striped_cm, inv_d2=inv_d2)
-        return _striped_call(kernel, Tp, Cm, interpret)
+        return _striped_call(kernel, Tp, Cm, interpret, "fused_step_cm")
     return pl.pallas_call(
         functools.partial(_fused_kernel_whole_cm, inv_d2=inv_d2),
         out_shape=_out_struct(Cm.shape, Cm),
@@ -341,6 +344,7 @@ def fused_step_cm(Tp, Cm, spacing, interpret=None):
         ],
         out_specs=pl.BlockSpec(memory_space=pltpu.VMEM),
         interpret=interpret,
+        name="fused_step_cm",
     )(Tp, Cm)
 
 
@@ -414,6 +418,7 @@ def kp_step_padded(Tp, Cp, lam, dt, spacing, interpret=None):
         in_specs=[vmem],
         out_specs=(vmem, vmem),
         interpret=interpret,
+        name="kp_flux",
     )(Tp)
     dTdt = pl.pallas_call(
         functools.partial(_residual_kernel, inv_d=inv_d),
@@ -421,6 +426,7 @@ def kp_step_padded(Tp, Cp, lam, dt, spacing, interpret=None):
         in_specs=[vmem, vmem, vmem],
         out_specs=vmem,
         interpret=interpret,
+        name="kp_residual",
     )(qx, qy, Cp)
     return pl.pallas_call(
         functools.partial(_update_kernel, dt=dt),
@@ -428,6 +434,7 @@ def kp_step_padded(Tp, Cp, lam, dt, spacing, interpret=None):
         in_specs=[vmem, vmem],
         out_specs=vmem,
         interpret=interpret,
+        name="kp_update",
     )(Tp, dTdt)
 
 
@@ -821,6 +828,7 @@ def fused_multi_step(T, Cp, lam, dt, spacing, n_steps, chunk=None, interpret=Non
         ],
         out_specs=pl.BlockSpec(memory_space=pltpu.VMEM),
         interpret=interpret,
+        name="fused_multi_step",
     )
     # For traced n_steps divisibility can't be checked at trace time: the
     # trip count floors, so a non-multiple silently rounds DOWN to the
@@ -871,6 +879,7 @@ def multi_step_cm(T, Cm, spacing, n_steps: int, interpret=None):
         ],
         out_specs=pl.BlockSpec(memory_space=pltpu.VMEM),
         interpret=interpret,
+        name="multi_step_cm",
     )(T, Cm)
 
 
@@ -1093,14 +1102,16 @@ def fused_multi_step_hbm(T, Cp, lam, dt, spacing, n_steps, block_steps=None,
     lam, dt = float(lam), float(dt)
     inv_d2 = tuple(1.0 / (float(d) * float(d)) for d in spacing)
     Cm = _edge_masked_cm(T, Cp, lam, dt)
-    sweep = _make_tb_sweep(T, inv_d2, k, g, tm, interpret)
+    sweep = _make_tb_sweep(T, inv_d2, k, g, tm, interpret,
+                           "fused_multi_step_hbm")
     return lax.fori_loop(0, n_steps // k, lambda _, x: sweep(x, Cm), T)
 
 
-def _make_tb_sweep(T, inv_d2, k, g, tm, interpret):
+def _make_tb_sweep(T, inv_d2, k, g, tm, interpret, name):
     """Build sweep(T, Cm) -> T advanced k steps, one temporal-blocked
     memory pass (the pallas_call shared by fused_multi_step_hbm and
-    multi_step_cm_hbm). Caller guarantees the shape constraints."""
+    multi_step_cm_hbm, named `name` after the caller). Caller
+    guarantees the shape constraints."""
     core, gup, gdn = _stripe_ghost_specs(tm, g, T.shape[0], T.shape[1:])
     kernel = functools.partial(_tb_kernel, inv_d2=inv_d2, k=k, g=g, tm=tm)
     call = pl.pallas_call(
@@ -1110,6 +1121,7 @@ def _make_tb_sweep(T, inv_d2, k, g, tm, interpret):
         in_specs=[gup, core, gdn, gup, core, gdn],
         out_specs=core,
         interpret=interpret,
+        name=name,
     )
     return lambda T, Cm: call(T, T, T, Cm, Cm, Cm)
 
@@ -1156,7 +1168,8 @@ def multi_step_cm_hbm(T, Cm, spacing, n_steps: int, interpret=None):
             f"axis-0 length {n0} must be a multiple of {tm} (>= 2 stripes)"
         )
     inv_d2 = tuple(1.0 / (float(d) * float(d)) for d in spacing)
-    return _make_tb_sweep(T, inv_d2, int(n_steps), g, tm, interpret)(T, Cm)
+    return _make_tb_sweep(T, inv_d2, int(n_steps), g, tm, interpret,
+                          "multi_step_cm_hbm")(T, Cm)
 
 
 # ---------------------------------------------------------------------------
@@ -1207,6 +1220,7 @@ def _masked_step_striped(T, Cm, inv_d2, interpret, tm, g):
         in_specs=[gup, core, gdn, core],
         out_specs=core,
         interpret=interpret,
+        name="masked_step",
     )(T, T, T, Cm)
 
 
@@ -1221,7 +1235,10 @@ def masked_step(T, Cm, spacing, interpret=None, tm=None, config=None):
     and no where-mask pass. Dispatch: VMEM-resident roll kernel
     (multi_step_cm, n=1) for fields that fit; the ghost-block striped sweep
     for HBM-resident fields with stripe-divisible rows; zero-ghost pad +
-    the padded-contract striped kernel for everything else.
+    the padded-contract striped kernel for everything else. The compiled
+    kernel carries the name of the function that launches it:
+    `masked_step` for the striped sweep, `multi_step_cm` or
+    `fused_step_cm` for the other two.
 
     `tm` overrides the stripe height (tuning knob — the threads=(32,8)
     analog); must be a multiple of 8. `config="auto"` consults the tuning

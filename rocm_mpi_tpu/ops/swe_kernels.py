@@ -170,6 +170,7 @@ def swe_step_padded_pallas(Sp, Mus, consts, dt, spacing, interpret=None):
         in_specs=[vmem] * (2 * ndim + 1),
         out_specs=(vmem,) * (ndim + 1),
         interpret=interpret,
+        name="swe_step_padded_pallas",
     )(*Sp, *Mus)
     return tuple(outs)
 
@@ -235,6 +236,7 @@ def swe_multi_step_masked(h, us, Mus, cH, cg, n_steps: int, interpret=None):
         in_specs=[vmem] * (2 * ndim + 1),
         out_specs=(vmem,) * (ndim + 1),
         interpret=interpret,
+        name="swe_multi_step_masked",
     )(h, *us, *Mus)
     return outs[0], tuple(outs[1:])
 

@@ -101,6 +101,7 @@ def wave_step_padded_pallas(Up, Uprev, C2, dt, spacing, interpret=None):
         in_specs=[vmem, vmem, vmem],
         out_specs=vmem,
         interpret=interpret,
+        name="wave_step_padded_pallas",
     )(Up, Uprev, C2)
 
 
@@ -160,6 +161,7 @@ def wave_step_padded_masked_pallas(Up, Uprev, M, Cw, spacing,
         in_specs=[vmem, vmem, vmem, vmem],
         out_specs=vmem,
         interpret=interpret,
+        name="wave_step_padded_masked_pallas",
     )(Up, Uprev, M, Cw)
 
 
@@ -277,6 +279,7 @@ def wave_multi_step_masked(U, Uprev, M, Cw, spacing, n_steps: int,
         in_specs=[vmem, vmem, vmem, vmem],
         out_specs=(vmem, vmem),
         interpret=interpret,
+        name="wave_multi_step_masked",
     )(U, Uprev, M, Cw)
 
 

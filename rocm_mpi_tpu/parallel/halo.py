@@ -48,6 +48,7 @@ from jax import lax
 from rocm_mpi_tpu import telemetry
 from rocm_mpi_tpu.parallel import wire
 from rocm_mpi_tpu.parallel.mesh import GlobalGrid
+from rocm_mpi_tpu.utils import compat
 
 
 def exchange_nbytes(local_shape, itemsize: int, width: int = 1,
@@ -90,14 +91,16 @@ def place_core(u, width: int = 1, axes=None):
     `u` placed at offset `width` there — ONE staging write, the only
     whole-block copy the in-place exchange pays. Edge-of-domain ghost
     slices that no neighbor overwrites stay zero, which IS the framework's
-    zero-ghost boundary convention.
+    zero-ghost boundary convention. Its ops carry layer="exchange"
+    (docs/TELEMETRY.md "Device-side names").
     """
     axes = set(range(u.ndim) if axes is None else axes)
     shape = tuple(
         n + 2 * width if a in axes else n for a, n in enumerate(u.shape)
     )
     start = tuple(width if a in axes else 0 for a in range(u.ndim))
-    return lax.dynamic_update_slice(jnp.zeros(shape, u.dtype), u, start)
+    with compat.set_xla_metadata(layer="exchange"):
+        return lax.dynamic_update_slice(jnp.zeros(shape, u.dtype), u, start)
 
 
 def exchange_into(buf, grid: GlobalGrid, width: int = 1, axes=None,
@@ -128,7 +131,15 @@ def exchange_into(buf, grid: GlobalGrid, width: int = 1, axes=None,
     ("int8", "int8_delta") additionally take and return `wire_state` —
     the flat per-slab state tuple `wire.init_exchange_state` builds —
     and the return value becomes `(buf, new_state)`.
+
+    Every op it traces, the codec's and the ppermutes included, carries
+    layer="exchange" (docs/TELEMETRY.md "Device-side names").
     """
+    with compat.set_xla_metadata(layer="exchange"):
+        return _exchange_into(buf, grid, width, axes, wire_mode, wire_state)
+
+
+def _exchange_into(buf, grid, width, axes, wire_mode, wire_state):
     axes = tuple(range(grid.ndim) if axes is None else axes)
     exchanged = set(axes)
     ndim = buf.ndim

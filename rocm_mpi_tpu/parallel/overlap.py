@@ -51,6 +51,7 @@ from jax import lax
 from rocm_mpi_tpu import telemetry
 from rocm_mpi_tpu.parallel.halo import exchange_halo, exchange_halo_batched
 from rocm_mpi_tpu.parallel.mesh import GlobalGrid
+from rocm_mpi_tpu.utils import compat
 
 
 def effective_b_width(local_shape, b_width) -> tuple[int, ...]:
@@ -224,7 +225,12 @@ def _make_region_splice(
     `make_overlap_step`, factored over an ALREADY-exchanged padded
     state `Tp` so the single-lane and lane-batched steps share one
     seam (the batched edition exchanges through
-    `exchange_halo_batched` and vmaps this per lane)."""
+    `exchange_halo_batched` and vmaps this per lane).
+
+    Every op of the splice carries layer="splice", and each region
+    update region="interior" or region="slab" besides (docs/TELEMETRY.md
+    "Device-side names"), so a device trace splits the step's time
+    between the interior kernel, the slab kernels and the splice."""
     local = grid.local_shape
     ndim = grid.ndim
 
@@ -268,6 +274,10 @@ def _make_region_splice(
             edge_hi.append(hi)
 
     def splice(Tl, Tp, Cpl, lam, dt, spacing):
+        with compat.set_xla_metadata(layer="splice"):
+            return _splice(Tl, Tp, Cpl, lam, dt, spacing)
+
+    def _splice(Tl, Tp, Cpl, lam, dt, spacing):
         def region(bounds):
             """Candidate update of the core box given by `bounds`. Slab
             boxes read the padded state; ghost-free boxes (the interior)
@@ -300,7 +310,9 @@ def _make_region_splice(
         # tree's whole-shard staging copies.
         new = Tl
         for bounds in all_boxes:
-            res = region(bounds)
+            kind = "interior" if ghost_free(bounds) else "slab"
+            with compat.set_xla_metadata(region=kind):
+                res = region(bounds)
             origin = tuple(lo for lo, _ in bounds)
             new = jax.tree_util.tree_map(
                 lambda o, r: lax.dynamic_update_slice(o, r, origin),

@@ -15,6 +15,12 @@ module-level no-op singleton — no allocation, no clock read, no lock;
 never depends on the sync, only timing fidelity does). The disabled
 cost is a function call and one global read, safe inside per-step loops.
 
+On the profiler's clock: an enabled span also opens a
+`jax.profiler.TraceAnnotation` of its name for its lifetime, so under
+any profile (`jax.profiler.trace`, an app's `--profile`) the span shows
+by name in the host plane, beside the device ops it dispatched. Only
+once jax is imported: a process without jax runs no profiler.
+
 Nesting is tracked per thread (a depth counter in threading.local), so
 spans opened on the launcher's drain threads or inside a supervised
 retry don't corrupt each other's stacks; the emitted record carries
@@ -24,6 +30,7 @@ slices on a rank's track.
 
 from __future__ import annotations
 
+import sys
 import threading
 import time
 
@@ -36,10 +43,23 @@ def _depth() -> int:
     return getattr(_stack, "depth", 0)
 
 
+def _profiler_annotation(name: str):
+    """An entered `jax.profiler.TraceAnnotation` named `name`, to be
+    closed with `.__exit__(None, None, None)`; None when jax is not
+    imported."""
+    jax = sys.modules.get("jax")
+    if jax is None:
+        return None
+    annotation = jax.profiler.TraceAnnotation(name)
+    annotation.__enter__()
+    return annotation
+
+
 class Span:
     """One open span; emitted as a single record at __exit__."""
 
-    __slots__ = ("name", "attrs", "_t_wall", "_t_mono", "_depth", "_tid")
+    __slots__ = ("name", "attrs", "_t_wall", "_t_mono", "_depth", "_tid",
+                 "_annotation")
 
     def __init__(self, name: str, attrs: dict):
         self.name = name
@@ -55,6 +75,7 @@ class Span:
             # flight recorder's "last phase entered" must already say so
             # (heartbeat sidecar, telemetry/flight.py).
             flight.enter_phase(self.name, self.attrs)
+        self._annotation = _profiler_annotation(self.name)
         self._t_wall = time.time()
         self._t_mono = time.perf_counter()
         return self
@@ -72,6 +93,8 @@ class Span:
 
     def __exit__(self, exc_type, exc, tb):
         dur = time.perf_counter() - self._t_mono
+        if self._annotation is not None:
+            self._annotation.__exit__(None, None, None)
         _stack.depth = self._depth
         fields = {
             "t": self._t_wall,
@@ -120,10 +143,12 @@ def span(name: str, **attrs):
 
 def span_record(name: str, t_wall: float, dur_s: float,
                 error: str | None = None, **attrs) -> None:
-    """Emit a span record for an interval timed by OTHER machinery
-    (utils.metrics.Timer's labeled mode): the interval is already over,
-    so it never passes through the nesting stack. `error` lands at the
-    record's top level, matching Span.__exit__'s failed-body shape."""
+    """Emit a span record for an interval timed by OTHER machinery (the
+    compile listener's `compile.backend`): the interval is already over,
+    so it never passes through the nesting stack, and it cannot be
+    replayed into a profile either, since a profiler annotation is
+    opened and closed as the work runs. `error` lands at the record's
+    top level, matching Span.__exit__'s failed-body shape."""
     if not events.enabled():
         return
     fields = {"t": t_wall, "dur_s": dur_s, "depth": _depth(),

@@ -8,6 +8,9 @@ jax" question, so the next version bump is fixed in exactly one place
   the modern-API spellings the call sites use;
 * `install_compile_listener` — the private dispatch hook the compile
   tracker taps;
+* `set_xla_metadata` — the context manager that puts XLA
+  frontend_attributes on the ops traced inside it (the layer tags of
+  docs/TELEMETRY.md "Device-side names");
 * the jax.experimental modules the framework uses (`pallas`, its `tpu`
   sublayer, `multihost_utils`), resolved lazily via module `__getattr__`
   (PEP 562) so importing compat for shard_map alone does not pay the
@@ -30,6 +33,7 @@ __all__ = [
     "out_struct_like",
     "pallas",
     "pallas_tpu",
+    "set_xla_metadata",
     "shard_map",
 ]
 
@@ -121,6 +125,15 @@ def install_compile_listener(on_interval, on_event=None) -> str | None:
         jax.monitoring.register_event_listener(_safe_event)
     _COMPILE_LISTENER_MODE = "named"
     return _COMPILE_LISTENER_MODE
+
+
+def set_xla_metadata(**names):
+    """`jax.experimental.xla_metadata.set_xla_metadata(**names)`: a
+    context manager under which every traced op carries `names` as XLA
+    frontend_attributes (values lowercased)."""
+    from jax.experimental.xla_metadata import set_xla_metadata as _set
+
+    return _set(**names)
 
 
 def _resolve_lazy(name: str):

@@ -49,25 +49,36 @@ class Timer:
 
     __exit__ calls toc() only when the body didn't — an explicit
     toc(*sync) inside the block keeps the device sync semantics and
-    wins over the exit stamp. A `label` routes the measured interval into
-    the telemetry stream as a span record (phase attribution for code
-    that already times with Timer), with `attrs` carried along; unlabeled
-    timers stay telemetry-silent, exactly as before.
+    wins over the exit stamp. A `label` opens a `telemetry.span` of that
+    name, with `attrs`, from tic to toc (a record in the telemetry
+    stream and an annotation on the profiler's clock, when telemetry is
+    enabled); unlabeled timers stay telemetry-silent, exactly as before.
+    Use the `with` form where the timed work can raise: it closes the
+    span error-flagged.
     """
 
     def __init__(self, label: str | None = None, **attrs):
         self._t0 = None
-        self._t0_wall = None
+        self._span = None
         self.elapsed = None
         self.label = label
         self.attrs = attrs
+
+    def _close_span(self, exc_type=None):
+        if self._span is not None:
+            self._span.__exit__(exc_type, None, None)
+            self._span = None
 
     def tic(self, *sync):
         """Start timing. Pass device arrays to sync on first."""
         for x in sync:
             force(x)
         self.elapsed = None
-        self._t0_wall = time.time()
+        self._close_span()
+        if self.label is not None:
+            from rocm_mpi_tpu.telemetry.spans import span
+
+            self._span = span(self.label, **self.attrs).__enter__()
         self._t0 = time.perf_counter()
 
     def toc(self, *sync) -> float:
@@ -77,11 +88,7 @@ class Timer:
         if self._t0 is None:
             raise RuntimeError("toc() before tic()")
         self.elapsed = time.perf_counter() - self._t0
-        if self.label is not None and _tel.enabled():
-            from rocm_mpi_tpu.telemetry.spans import span_record
-
-            span_record(self.label, self._t0_wall, self.elapsed,
-                        **self.attrs)
+        self._close_span()
         return self.elapsed
 
     def __enter__(self):
@@ -98,11 +105,7 @@ class Timer:
                 # hours a run burned before the supervisor gave up must
                 # show in the stream, error-flagged like a failed span.
                 self.elapsed = time.perf_counter() - self._t0
-                if self.label is not None and _tel.enabled():
-                    from rocm_mpi_tpu.telemetry.spans import span_record
-
-                    span_record(self.label, self._t0_wall, self.elapsed,
-                                error=exc_type.__name__, **self.attrs)
+                self._close_span(exc_type)
         return False
 
 

@@ -45,6 +45,13 @@ from rocm_mpi_tpu.serving.service import (  # noqa: E402
 from rocm_mpi_tpu.telemetry import compiles  # noqa: E402
 
 
+@pytest.fixture(autouse=True)
+def _fresh_compile_accounting():
+    """The steady-state recompile count is process-wide: start each test
+    from zero, whatever an earlier test in the same process compiled."""
+    compiles.reset()
+
+
 def _put(arr, sharding):
     return jax.device_put(np.asarray(arr), sharding)
 

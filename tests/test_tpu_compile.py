@@ -13,7 +13,10 @@ loads it. Nothing here runs; compile times are ~1-2 s each except the
 temporal-blocked sweep (~8 s).
 """
 
+import collections
+import contextlib
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -126,3 +129,216 @@ def test_sharded_step_2x2(topo, monkeypatch, variant, n_local):
     text = step.lower(a, a).compile().as_text()
     assert "tpu_custom_call" in text
     assert "collective-permute" in text
+
+
+# ---- device-side names: kernel names and layer tags ---------------------
+
+_HEAD = re.compile(r"^(ENTRY )?%([\w.\-]+) .*\{$")
+_INSTR = re.compile(r"^(?:ROOT )?%([\w.\-]+) = (.*)$")
+_STRUCTURAL = {"parameter", "constant", "get-tuple-element", "tuple",
+               "bitcast", "copy", "copy-start", "copy-done", "while",
+               "opt-barrier", "partition-id", "replica-id"}
+# What XLA's HLO importer, run by the Shardy pass on programs
+# partitioned over chips, re-creates without frontend_attributes on
+# jax 0.9 (tests/test_device_tags.py keeps the finding as an xfail): the
+# data movement of the exchange and the splice may lose its layer tag
+# there, and nothing else may.
+_IMPORT_DROPS = {"slice", "pad", "dynamic-update-slice", "dynamic-slice",
+                 "concatenate", "reverse", "iota"}
+
+
+def _opcode(rhs: str) -> str:
+    """The opcode of an instruction's right-hand side (shape first)."""
+    i = 0
+    if rhs.startswith("("):
+        depth = 0
+        for i, ch in enumerate(rhs):
+            depth += (ch == "(") - (ch == ")")
+            if depth == 0:
+                break
+        i += 1
+    else:
+        i = rhs.find(" ")
+    return rhs[i:].lstrip().split("(", 1)[0]
+
+
+def _program(text: str) -> dict:
+    """{computation: [(name, opcode, shape, layer, region, line)]} of a
+    compiled module's text, with `fused` and `bodies` (the while bodies,
+    nested ones included) as sets of computation names."""
+    comps, cur = {}, None
+    for line in text.splitlines():
+        head = _HEAD.match(line)
+        if head:
+            cur = comps.setdefault(head.group(2), [])
+            continue
+        m = _INSTR.match(line.strip())
+        if m and cur is not None:
+            rhs = m.group(2)
+            tags = dict(re.findall(r'\b(layer|region)="(\w+)"', rhs))
+            cur.append((m.group(1), _opcode(rhs), rhs.split(" ", 1)[0],
+                        tags.get("layer"), tags.get("region"), rhs))
+    bodies = {b for ops in comps.values() for *_, rhs in ops
+              for b in re.findall(r"\bbody=%([\w.\-]+)", rhs)}
+    fused = {f for ops in comps.values() for *_, rhs in ops
+             if " fusion(" in rhs
+             for f in re.findall(r"\bcalls=%([\w.\-]+)", rhs)}
+    return {"comps": comps, "bodies": bodies, "fused": fused}
+
+
+def _kernels(prog) -> list:
+    return [op for ops in prog["comps"].values() for op in ops
+            if 'custom_call_target="tpu_custom_call"' in op[5]]
+
+
+def _compiled(topo, mp, variant, n_local, dims=(2, 2), shardy=True):
+    """The compiled scan driver (the benchmark's program) for the
+    described chips: `variant` at `n_local`² a chip on a `dims` mesh,
+    partitioned by Shardy (what runs) or GSPMD."""
+    from rocm_mpi_tpu.config import DiffusionConfig
+    from rocm_mpi_tpu.models import HeatDiffusion
+
+    mp.setattr(pk, "_interpret_default", lambda: False)
+    n = dims[0] * n_local
+    devices = topo.devices[: dims[0] * dims[1]]
+    model = HeatDiffusion(
+        DiffusionConfig(global_shape=(n, n), dtype="f32", dims=dims,
+                        nt=100, warmup=10),
+        devices=devices,
+    )
+    advance, _ = model.scan_advance_fn(variant)
+    a = _sds((n, n), model.grid.sharding)
+    saved = jax.config.jax_use_shardy_partitioner
+    jax.config.update("jax_use_shardy_partitioner", shardy)
+    try:
+        return advance.lower(a, a, 100).compile().as_text()
+    finally:
+        jax.config.update("jax_use_shardy_partitioner", saved)
+
+
+CELLS = {"perf": dict(variant="perf", n_local=1024, dims=(1, 1)),
+         "perf-vmem": dict(variant="perf", n_local=512, dims=(1, 1)),
+         "hide": dict(variant="hide", n_local=126)}
+
+
+@pytest.fixture(scope="module")
+def programs(topo):
+    """program(cell, shardy=True, tagged=True): the parsed compiled
+    program, each compiled once for the module. Untagged means
+    compat.set_xla_metadata made a no-op."""
+    from rocm_mpi_tpu.utils import compat
+
+    cache = {}
+
+    def program(cell, shardy=True, tagged=True):
+        key = (cell, shardy, tagged)
+        if key not in cache:
+            with pytest.MonkeyPatch.context() as mp:
+                if not tagged:
+                    mp.setattr(compat, "set_xla_metadata",
+                               lambda **names: contextlib.nullcontext())
+                cache[key] = _program(
+                    _compiled(topo, mp, **CELLS[cell], shardy=shardy))
+        return cache[key]
+
+    return program
+
+
+def _untagged_in_bodies(prog) -> list:
+    """Loop-body ops that are no kernel, no structural op and no loop
+    control, and carry no layer tag."""
+    out = []
+    for comp in prog["bodies"]:
+        for op in prog["comps"][comp]:
+            name, opcode, _, layer, _, rhs = op
+            if opcode in _STRUCTURAL or layer is not None:
+                continue
+            if 'custom_call_target="tpu_custom_call"' in rhs:
+                continue
+            out.append(op)
+    return out
+
+
+@pytest.mark.parametrize("cell, kernel", [("perf", "masked_step"),
+                                          ("perf-vmem", "multi_step_cm")])
+def test_perf_scan_names_its_kernel(programs, cell, kernel):
+    """A field past the VMEM budget (the benchmark's) takes masked_step's
+    striped sweep, named after it; one that fits takes the kernel
+    masked_step delegates to, which keeps its own function's name."""
+    assert {op[0].rsplit(".", 1)[0] for op in _kernels(programs(cell))} \
+        == {kernel}
+
+
+def test_hide_step_names_kernels_and_regions(programs):
+    kernels = _kernels(programs("hide"))
+    assert {op[0].rsplit(".", 1)[0] for op in kernels} == {"fused_step_cm"}
+    assert sorted(op[4] for op in kernels) == ["interior"] + ["slab"] * 4
+    assert all(op[3] == "splice" for op in kernels)
+    assert not [op for op in kernels
+                if re.match(r"(closed_call|shard_map)\b", op[0])]
+
+
+def test_perf_loop_body_is_tagged(programs):
+    assert _untagged_in_bodies(programs("perf")) == []
+
+
+@pytest.mark.parametrize("shardy", [False, True], ids=["gspmd", "shardy"])
+def test_hide_loop_body_is_tagged(programs, shardy):
+    """Every compute op of the hide step names its layer. Compiled as
+    the program runs (Shardy), only the data movement XLA's importer
+    re-creates may lose the tag; partitioned by GSPMD, which compiles the
+    same ops (test below), every op keeps it."""
+    prog = programs("hide", shardy=shardy)
+    untagged = _untagged_in_bodies(prog)
+    if not shardy:
+        assert untagged == []
+        return
+    for name, opcode, *_, rhs in untagged:
+        if opcode == "fusion":
+            inner = {op[1] for f in re.findall(r"calls=%([\w.\-]+)", rhs)
+                     for op in prog["comps"][f]}
+            assert inner & _IMPORT_DROPS, (name, inner)
+        else:
+            assert opcode in _IMPORT_DROPS, (name, opcode)
+
+
+def _mixed_fusions(prog) -> list:
+    """Fusions whose fused ops carry two different layer tags."""
+    return sorted(
+        (f, tags) for f in prog["fused"]
+        for tags in [{op[3] for op in prog["comps"][f] if op[3]}]
+        if len(tags) > 1)
+
+
+@pytest.mark.parametrize("shardy", [False, True], ids=["gspmd", "shardy"])
+def test_hide_step_fuses_no_two_layers(programs, shardy):
+    """A fusion of two layers' ops would charge one layer with the
+    other's time."""
+    assert _mixed_fusions(programs("hide", shardy=shardy)) == []
+
+
+def _ops_signature(prog):
+    """The multiset of (opcode, shape) over every computation, and the
+    number of fusions."""
+    ops = collections.Counter(
+        (op[1], op[2]) for comp in prog["comps"].values() for op in comp)
+    return ops, len(prog["fused"])
+
+
+@pytest.mark.parametrize("cell", ["perf", "hide"])
+def test_tags_change_no_op(programs, cell):
+    """The tags are metadata: the program compiled without them
+    (compat.set_xla_metadata a no-op) has the same ops, shapes and
+    fusions."""
+    plain = programs(cell, tagged=False)
+    assert not any(op[3] for comp in plain["comps"].values()
+                   for op in comp)
+    assert _ops_signature(programs(cell)) == _ops_signature(plain)
+
+
+def test_partitioners_compile_the_same_hide_step(programs):
+    """Shardy (what runs) and GSPMD (which keeps every tag) compile the
+    hide step to the same ops, so a GSPMD-compiled trace splits the
+    data movement of the program that runs."""
+    assert _ops_signature(programs("hide")) == \
+        _ops_signature(programs("hide", shardy=False))
