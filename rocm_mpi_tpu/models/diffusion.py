@@ -140,6 +140,21 @@ def _driver_layer(advance):
     return tagged
 
 
+def _whole_shard_interior(dtype):
+    """The hide splice's `interior_update` on the Cm contract:
+    `masked_step`, the `perf` step's own sweep, on the whole unpadded
+    shard — so no near-whole-shard box is sliced out for a Pallas
+    operand and written back (its edge values are wrong where a shard
+    face is not a global face; the slabs overwrite them). None for f64,
+    which Mosaic cannot compile: its interior box stays sliced, for the
+    jnp twin."""
+    if jnp.dtype(dtype).itemsize > 4:
+        return None
+    from rocm_mpi_tpu.ops.pallas_kernels import masked_step
+
+    return lambda Tl, cm, lam, dt, spacing: masked_step(Tl, cm, spacing)
+
+
 def warn_host_transport_ignored(variant: str, stacklevel: int = 3) -> None:
     """The one warning for halo_transport='host' on a variant that keeps its
     device-side communication (only 'shard' routes to the host-staged
@@ -419,10 +434,11 @@ class HeatDiffusion:
         return one_step, prepare
 
     def _make_hide_step(self):
-        """Overlap step (parallel.overlap): Pallas strips for f32/bf16, jnp
-        strips for f64 (Mosaic has no f64) — BOTH on the Cm contract, so
-        no caller pays a trailing whole-shard Dirichlet select. Returns
-        (step, prepare)."""
+        """Overlap step (parallel.overlap): Pallas slabs and a whole-shard
+        `masked_step` interior for f32/bf16, jnp slabs and a sliced jnp
+        interior for f64 (Mosaic has no f64) — BOTH on the Cm contract,
+        so no caller pays a trailing whole-shard Dirichlet select.
+        Returns (step, prepare)."""
         from rocm_mpi_tpu.parallel.overlap import make_overlap_step
 
         cfg, grid = self.config, self.grid
@@ -453,6 +469,7 @@ class HeatDiffusion:
         local = make_overlap_step(
             grid, pu, cfg.b_width, mask_boundary=False,
             wire_mode=cfg.wire_mode,
+            interior_update=_whole_shard_interior(cfg.jax_dtype),
         )
         prepare = self._cm_prepare()
 
@@ -649,12 +666,15 @@ class HeatDiffusion:
         one width-1 exchange of the whole lane batch whose collectives
         are dataflow-independent of every interior box, so XLA hides
         the (lane-aggregate) exchange under the vmapped interior
-        compute. Runs the Cm jnp twin (`ops.diffusion.step_cm_padded`)
-        on every dtype — the same kernel the single-lane f64 hide and
-        the CPU traffic audit lower, bitwise-equal to the Pallas Cm
-        form — with the mask+divide folded into the prepared
-        coefficient (`prepare`), so held cells come back unchanged
-        from the region updates themselves."""
+        compute. The slabs run the Cm jnp twin
+        (`ops.diffusion.step_cm_padded`) on every dtype — the same
+        kernel the single-lane f64 hide and the CPU traffic audit
+        lower, bitwise-equal to the Pallas Cm form — with the
+        mask+divide folded into the prepared coefficient (`prepare`),
+        so held cells come back unchanged from the region updates
+        themselves. The interior is the single-lane hide's: a
+        whole-shard `masked_step` per lane for the compiled dtypes,
+        the sliced jnp twin for f64."""
         from rocm_mpi_tpu.ops.diffusion import step_cm_padded
         from rocm_mpi_tpu.parallel.overlap import make_batched_overlap_step
 
@@ -667,6 +687,7 @@ class HeatDiffusion:
         batched_local = make_batched_overlap_step(
             bgrid, pu, cfg.b_width, mask_boundary=False,
             wire_mode=cfg.wire_mode,
+            interior_update=_whole_shard_interior(cfg.jax_dtype),
         )
 
         def prepare(Cp):

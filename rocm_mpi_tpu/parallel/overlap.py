@@ -20,19 +20,28 @@ Per step, inside one shard_map program:
      the interior compute concurrently (this dataflow independence is the
      whole trick: no user-visible queues, priorities, or signals —
      SURVEY.md §2.2 D8, made explicit rather than left to XLA's
-     slice-of-concatenate simplifier);
+     slice-of-concatenate simplifier). A caller with a whole-shard,
+     ghost-free update (`interior_update`) computes it over the whole
+     unpadded shard in one call: no slice of the shard goes in, and its
+     full-shard result is the output buffer. Its values within one cell
+     of a shard face are wrong wherever that face is not a global face,
+     and step 4 overwrites every one of them;
   3. update the boundary slabs once their ghosts arrive;
   4. write every region's result into one output buffer with
      `lax.dynamic_update_slice` — no per-axis concatenate tree, no
-     staging copies; with the masked-coefficient contract (below) held
-     cells come back unchanged from the region update itself, so there is
-     no trailing whole-shard Dirichlet `jnp.where` either.
+     staging copies; the slabs cover every cell within b >= 1 of a shard
+     face. With the masked-coefficient contract (below) held cells come
+     back unchanged from the region update itself, so there is no
+     trailing whole-shard Dirichlet `jnp.where` either.
 
 Traffic (the A_eff accounting docs/PERF.md formalizes): the old splice
 rebuilt the shard through a tree of `jnp.concatenate`s (one staging copy
 per axis level) and then paid a whole-shard select; the in-place splice
 writes each region exactly once into a buffer XLA can alias with the
-input block.
+input block. A Pallas region update needs materialised operands, so a
+sliced interior box of nearly the whole shard costs a copy of the shard
+in and its write back out; the whole-shard `interior_update` form
+slices nothing out of the shard and writes only the thin slabs back.
 
 Unlike the reference's two-queue scheme, correctness never rests on manual
 signal ordering (hide.jl:69,86-90): the schedule is derived from dataflow,
@@ -80,6 +89,7 @@ def make_overlap_step(
     b_width: tuple[int, ...],
     mask_boundary: bool = True,
     wire_mode: str = "f32",
+    interior_update: Callable | None = None,
 ):
     """Build the shard-local overlap step (any ndim).
 
@@ -126,6 +136,17 @@ def make_overlap_step(
     and so on; the innermost box is the ghost-free interior. Only the
     axis-0/…​ slabs read exchanged ghosts — the interior reads the unpadded
     local block, which is what makes the exchange hideable.
+
+    `interior_update(Tl, Cpl, lam, dt, spacing) -> Tl_new` (optional) is
+    a whole-shard, ghost-free form of the same update: it takes the
+    unpadded state and the core-shaped aux tree as they are, and must be
+    right on every cell at least one cell from a shard face (its edge
+    values only have to be finite — the slabs overwrite them). Given,
+    the interior is one call of it on the whole shard, whose result is
+    the buffer the slabs are written into; without it, the interior box
+    is sliced out and updated by `padded_update` like the slabs. The
+    `overlap.step` annotation records the form (`interior="whole_shard"`,
+    `"sliced"`, or `"none"` when the slabs tile the whole shard).
     """
     from rocm_mpi_tpu.parallel import wire
 
@@ -135,17 +156,19 @@ def make_overlap_step(
     # BUILD its per-step variants and run its deep schedule.
     wire.validate_mode(wire_mode)
     bw = effective_b_width(grid.local_shape, b_width)
-    splice = _make_region_splice(grid, padded_update, bw, mask_boundary)
+    splice, interior = _make_region_splice(
+        grid, padded_update, bw, mask_boundary, interior_update
+    )
 
     def local_step(Tl, Cpl, lam, dt, spacing):
         if telemetry.enabled():
-            # Trace-time: the slab geometry this compiled overlap step
-            # uses (the per-leaf halo.exchange byte annotations fire
-            # inside exchange_halo below).
+            # Trace-time: the slab geometry and interior form this
+            # compiled overlap step uses (the per-leaf halo.exchange byte
+            # annotations fire inside exchange_halo below).
             telemetry.annotate(
                 "overlap.step", b_width=tuple(int(b) for b in bw),
                 leaves=len(jax.tree_util.tree_leaves(Tl)),
-                wire=wire_mode,
+                wire=wire_mode, interior=interior,
             )
         # (1) halo exchange of the current state — edge-slice ppermutes,
         # one exchange per state leaf (SWE: 3 fields; diffusion/wave: 1),
@@ -165,6 +188,7 @@ def make_batched_overlap_step(
     b_width: tuple[int, ...],
     mask_boundary: bool = False,
     wire_mode: str = "f32",
+    interior_update: Callable | None = None,
 ):
     """The lane-batched overlap step (docs/SERVING.md "The pipeline"):
     the masked-seam hide of `make_overlap_step`, vmapped over the
@@ -185,13 +209,17 @@ def make_batched_overlap_step(
 
     Stateless wire modes only (f32/bf16), enforced by
     `exchange_halo_batched`. `mask_boundary` defaults to False — every
-    in-repo batched caller is on the Cm masked-coefficient contract."""
+    in-repo batched caller is on the Cm masked-coefficient contract.
+    `interior_update` is `make_overlap_step`'s, vmapped with the rest of
+    the splice."""
     from rocm_mpi_tpu.parallel import wire
 
     wire.validate_mode(wire_mode)
     space = bgrid.space
     bw = effective_b_width(space.local_shape, b_width)
-    splice = _make_region_splice(space, padded_update, bw, mask_boundary)
+    splice, interior = _make_region_splice(
+        space, padded_update, bw, mask_boundary, interior_update
+    )
 
     def batched_local(Tb_l, Cpl, lam, dt, spacing):
         if telemetry.enabled():
@@ -200,7 +228,7 @@ def make_batched_overlap_step(
                 b_width=tuple(int(b) for b in bw),
                 lanes=int(jax.tree_util.tree_leaves(Tb_l)[0].shape[0]),
                 leaves=len(jax.tree_util.tree_leaves(Tb_l)),
-                wire=wire_mode,
+                wire=wire_mode, interior=interior,
             )
         Tp_b = jax.tree_util.tree_map(
             lambda t: exchange_halo_batched(t, bgrid,
@@ -219,13 +247,16 @@ def _make_region_splice(
     padded_update: Callable,
     bw: tuple[int, ...],
     mask_boundary: bool,
+    interior_update: Callable | None = None,
 ):
-    """Build `splice(Tl, Tp, Cpl, lam, dt, spacing) -> Tl_new`: the
-    boundary-slab/interior decomposition and the in-place DUS splice of
-    `make_overlap_step`, factored over an ALREADY-exchanged padded
-    state `Tp` so the single-lane and lane-batched steps share one
-    seam (the batched edition exchanges through
-    `exchange_halo_batched` and vmaps this per lane).
+    """Build `(splice, interior)`: `splice(Tl, Tp, Cpl, lam, dt,
+    spacing) -> Tl_new` is the boundary-slab/interior decomposition and
+    the in-place DUS splice of `make_overlap_step`, factored over an
+    ALREADY-exchanged padded state `Tp` so the single-lane and
+    lane-batched steps share one seam (the batched edition exchanges
+    through `exchange_halo_batched` and vmaps this per lane);
+    `interior` names the interior's form ("whole_shard", "sliced" or
+    "none") for the step's annotation.
 
     Every op of the splice carries layer="splice", and each region
     update region="interior" or region="slab" besides (docs/TELEMETRY.md
@@ -259,6 +290,15 @@ def _make_region_splice(
             lo >= 1 and hi <= local[a] - 1
             for a, (lo, hi) in enumerate(bounds)
         )
+
+    if not any(ghost_free(b) for b in all_boxes):
+        interior = "none"
+    elif interior_update is not None:
+        interior = "whole_shard"
+        # The slabs alone are spliced into the whole-shard result.
+        all_boxes = [b for b in all_boxes if not ghost_free(b)]
+    else:
+        interior = "sliced"
 
     if mask_boundary:
         # Build-time edge indicators (numpy): cell lies on the shard face
@@ -307,8 +347,13 @@ def _make_region_splice(
         # — XLA may alias it with Tl's storage (dead after the exchange),
         # and each region+DUS link lowers to an in-place update-slice
         # fusion (observed on the CPU backend) instead of the old concat
-        # tree's whole-shard staging copies.
-        new = Tl
+        # tree's whole-shard staging copies. The whole-shard interior
+        # is itself the seed: only the slabs are written into it.
+        if interior == "whole_shard":
+            with compat.set_xla_metadata(region="interior"):
+                new = interior_update(Tl, Cpl, lam, dt, spacing)
+        else:
+            new = Tl
         for bounds in all_boxes:
             kind = "interior" if ghost_free(bounds) else "slab"
             with compat.set_xla_metadata(region=kind):
@@ -334,4 +379,4 @@ def _make_region_splice(
             lambda old, nw: jnp.where(mask, old, nw), Tl, new
         )
 
-    return splice
+    return splice, interior

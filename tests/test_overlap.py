@@ -40,6 +40,99 @@ def test_hide_matches_ap_f32_pallas_strips():
     )
 
 
+def _spy_masked_step(monkeypatch):
+    """Record the shape of every `masked_step` call the hide step
+    makes: the whole-shard interior is one call on the unpadded
+    shard."""
+    import rocm_mpi_tpu.ops.pallas_kernels as pk
+
+    shapes, orig = [], pk.masked_step
+    monkeypatch.setattr(
+        pk, "masked_step",
+        lambda T, *a, **k: (shapes.append(T.shape), orig(T, *a, **k))[1],
+    )
+    return shapes
+
+
+def _f64_ap(cfg, T=None):
+    """The f64 `ap` oracle of `cfg`: `nt` steps from its own initial
+    state, or one step from `T`."""
+    import dataclasses
+
+    ref = HeatDiffusion(dataclasses.replace(cfg, dtype="f64"))
+    if T is None:
+        return np.asarray(ref.run(variant="ap").T)
+    _, Cp = ref.init_state()
+    return np.asarray(ref.step_fn("ap")(np.asarray(T, np.float64), Cp))
+
+
+@pytest.mark.parametrize("shape,dims,b_width", [
+    # One-cell slabs: they cover exactly the ring the whole-shard
+    # sweep gets wrong where a shard face is not a global face.
+    ((64, 64), (2, 2), (1, 1)),
+    # The reference's frame, on shards with an interior box.
+    ((160, 48), (2, 2), (32, 4)),
+    ((24, 24, 24), (2, 2, 2), (4, 4, 4)),
+], ids=["b1", "b32x4", "3d"])
+def test_hide_whole_shard_interior_matches_f64_ap(monkeypatch, shape, dims,
+                                                  b_width):
+    """The f32 hide step takes its interior as one whole-shard
+    `masked_step` and agrees with the f64 `ap` oracle."""
+    shapes = _spy_masked_step(monkeypatch)
+    cfg = DiffusionConfig(global_shape=shape, lengths=(10.0,) * len(shape),
+                          nt=20, warmup=0, dims=dims, b_width=b_width,
+                          dtype="f32")
+    model = HeatDiffusion(cfg)
+    got = np.asarray(model.run(variant="hide").T, np.float64)
+    assert set(shapes) == {model.grid.local_shape}
+    np.testing.assert_allclose(got, _f64_ap(cfg), rtol=2e-5, atol=1e-6)
+
+
+@pytest.mark.parametrize("b_width", [(1, 1), (32, 4)], ids=["b1", "b32x4"])
+def test_hide_overwrites_whole_shard_edge_values(b_width):
+    """Every cell next to a shard face holds a value 10^6 times the
+    field's: the whole-shard sweep's value there is wildly wrong where
+    the face is not a global face, so a step that kept any of them
+    would miss the f64 oracle by far more than rounding."""
+    cfg = DiffusionConfig(global_shape=(160, 48), lengths=(10.0, 10.0),
+                          nt=1, warmup=0, dims=(2, 2), b_width=b_width,
+                          dtype="f32")
+    model = HeatDiffusion(cfg)
+    n0, n1 = model.grid.local_shape
+    rows, cols = np.indices(cfg.global_shape)
+    ring = (rows % n0 == 0) | (rows % n0 == n0 - 1) | (
+        (cols % n1 == 0) | (cols % n1 == n1 - 1))
+    rng = np.random.default_rng(7)
+    T = rng.uniform(0.5, 1.0, cfg.global_shape)
+    T[ring] *= 1e6
+    T = T.astype(np.float32)
+    _, Cp = model.init_state()
+    got = np.asarray(model.step_fn("hide")(T, Cp), np.float64)
+    np.testing.assert_allclose(got, _f64_ap(cfg, T), rtol=2e-5, atol=1e-6)
+
+
+@pytest.mark.parametrize("dtype,b_width,form", [
+    ("f32", (4, 4), "whole_shard"),
+    ("f64", (4, 4), "sliced"),
+    ("f32", (8, 8), "none"),  # the slabs tile the 16x16 shard
+])
+def test_overlap_step_annotates_interior_form(monkeypatch, dtype, b_width,
+                                              form):
+    from rocm_mpi_tpu.telemetry import events
+
+    monkeypatch.setattr(events, "_ENABLED", True)
+    monkeypatch.setattr(events, "_DIR", None)
+    events.clear()
+    model = HeatDiffusion(DiffusionConfig(
+        global_shape=(32, 32), nt=1, warmup=0, dims=(2, 2),
+        b_width=b_width, dtype=dtype))
+    T, Cp = model.init_state()
+    model.step_fn("hide").lower(T, Cp)
+    recs = events.records(kind="trace", name="overlap.step")
+    events.clear()
+    assert [r["attrs"]["interior"] for r in recs] == [form]
+
+
 def test_hide_with_reference_b_width_clamped():
     # Reference b_width=(32,4) on shards smaller than the frame: clamp.
     _compare(

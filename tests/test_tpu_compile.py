@@ -15,6 +15,7 @@ temporal-blocked sweep (~8 s).
 
 import collections
 import contextlib
+import math
 import os
 import re
 
@@ -270,9 +271,12 @@ def test_perf_scan_names_its_kernel(programs, cell, kernel):
 
 
 def test_hide_step_names_kernels_and_regions(programs):
+    """The interior is one whole-shard `masked_step`, which launches
+    `multi_step_cm` for a 126² shard (it fits VMEM); the four slabs are
+    `fused_step_cm`."""
     kernels = _kernels(programs("hide"))
-    assert {op[0].rsplit(".", 1)[0] for op in kernels} == {"fused_step_cm"}
-    assert sorted(op[4] for op in kernels) == ["interior"] + ["slab"] * 4
+    assert sorted((op[4], op[0].rsplit(".", 1)[0]) for op in kernels) == \
+        [("interior", "multi_step_cm")] + [("slab", "fused_step_cm")] * 4
     assert all(op[3] == "splice" for op in kernels)
     assert not [op for op in kernels
                 if re.match(r"(closed_call|shard_map)\b", op[0])]
@@ -342,3 +346,56 @@ def test_partitioners_compile_the_same_hide_step(programs):
     data movement of the program that runs."""
     assert _ops_signature(programs("hide")) == \
         _ops_signature(programs("hide", shardy=False))
+
+
+def _loop_ops(prog) -> list:
+    """(computation, op) of every op in the while bodies and in the
+    fusions they call, transitively."""
+    seen, todo, out = set(), list(prog["bodies"]), []
+    while todo:
+        comp = todo.pop()
+        if comp in seen or comp not in prog["comps"]:
+            continue
+        seen.add(comp)
+        for op in prog["comps"][comp]:
+            out.append((comp, op))
+            todo += re.findall(r"\bcalls=%([\w.\-]+)", op[5])
+    return out
+
+
+def _elements(shape: str) -> int:
+    dims = re.match(r"\w+\[([\d,]*)\]", shape).group(1)
+    return math.prod(int(d) for d in dims.split(",") if d)
+
+
+@pytest.mark.parametrize("n_local", [126, N])
+def test_hide_step_splices_into_whole_shard_interior(topo, n_local):
+    """The 2x2 hide step's interior is one kernel on the whole unpadded
+    shard (region="interior"), the exchange is still there, and at the
+    benchmark's 12288² shard no whole-shard copy is sliced out for the
+    interior or written back: no slice, and no buffer write of an
+    update, is half the shard or more. Two whole-shard copies stay,
+    both put in by the compiler: the field in the transposed layout
+    (minor-to-major {0,1}), as before, and the carry copy, since the
+    interior kernel reads the field it replaces."""
+    with pytest.MonkeyPatch.context() as mp:
+        prog = _program(_compiled(topo, mp, "hide", n_local))
+    assert [op[4] for op in _kernels(prog)].count("interior") == 1
+    loop = _loop_ops(prog)
+    assert any(op[1] == "collective-permute-start" for _, op in loop)
+    if n_local != N:
+        return
+    half = N * N // 2
+    shapes = {(comp, op[0]): op[2] for comp, op in loop}
+    big = collections.Counter()
+    for comp, (name, opcode, shape, *_, rhs) in loop:
+        if opcode == "slice" and _elements(shape) >= half:
+            big["slice"] += 1
+        elif opcode == "dynamic-update-slice":
+            operands = rhs.split(opcode + "(", 1)[1]
+            update = re.findall(r"%([\w.\-]+)", operands)[1]
+            if _elements(shapes[(comp, update)]) >= half:
+                big["dynamic-update-slice"] += 1
+        elif opcode == "copy" and _elements(shape) >= half:
+            big["copy " + re.search(r"\{([\d,]+)", shape).group(1)] += 1
+    assert big == {"copy 0,1": 1, "copy 1,0": 1}
