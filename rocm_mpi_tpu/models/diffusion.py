@@ -644,8 +644,10 @@ class HeatDiffusion:
             mask = global_boundary_mask(space)
 
             def lane(Tl, Tpl):
-                new = step_fused_padded(Tpl, Cl, cfg.lam, dt, cfg.spacing)
-                return jnp.where(mask, Tl, new)
+                with compat.set_xla_metadata(layer="lane_step"):
+                    new = step_fused_padded(Tpl, Cl, cfg.lam, dt,
+                                            cfg.spacing)
+                    return jnp.where(mask, Tl, new)
 
             return jax.vmap(lane)(Tb_l, Tp)
 
@@ -775,8 +777,12 @@ class HeatDiffusion:
 
             def body(i, T):
                 new = step(T, C)
-                active = (i < lane_steps).reshape(shape1)
-                return jnp.where(active, new, T)
+                # The per-lane freeze belongs to the lane step: XLA fuses
+                # the stencil into this select, and a fusion carries its
+                # root's tag.
+                with compat.set_xla_metadata(layer="lane_step"):
+                    active = (i < lane_steps).reshape(shape1)
+                    return jnp.where(active, new, T)
 
             return lax.fori_loop(0, n, body, Tb)
 

@@ -448,7 +448,8 @@ def validate_manifest_doc(doc: dict) -> list[str]:
                     f"pipeline.bubble {bubble!r} outside [0, 1]"
                 )
             for field in ("assemble_s", "dispatch_s", "fetch_s",
-                          "resolve_s", "busy_s", "wall_s"):
+                          "fetch_wait_s", "resolve_s", "busy_s",
+                          "wall_s"):
                 v = pipe.get(field)
                 if v is not None and (
                     not isinstance(v, (int, float))

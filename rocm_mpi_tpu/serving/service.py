@@ -717,10 +717,12 @@ class SimulationService:
         # bubble gauge is measured against. time.monotonic by design —
         # interval arithmetic on the scheduler clock, not a telemetry
         # measurement (the spans carry those).
+        # `fetch_wait_s` is the part of `fetch_s` spent blocked on the
+        # device, so the fetch stage's own host work is the difference.
         self._pipe = {
             "batches": 0, "assemble_s": 0.0, "dispatch_s": 0.0,
-            "fetch_s": 0.0, "resolve_s": 0.0, "busy_s": 0.0,
-            "wall_s": 0.0,
+            "fetch_s": 0.0, "fetch_wait_s": 0.0, "resolve_s": 0.0,
+            "busy_s": 0.0, "wall_s": 0.0,
         }
         self._inflight_n = 0
         self._inflight_since: float | None = None
@@ -1272,6 +1274,7 @@ class SimulationService:
             with telemetry.span("serve.fetch", phase="serve",
                                 bin=key.key_str(), width=width):
                 jax.block_until_ready(fl.out)
+                self._pipe["fetch_wait_s"] += self._now() - t0
                 host = None
                 if fl.need_host and all(
                     leaf.is_fully_addressable for leaf in fl.out
@@ -1697,6 +1700,7 @@ class SimulationService:
             with telemetry.span("serve.fetch", phase="serve",
                                 bin=kstr, width=width):
                 jax.block_until_ready(out)
+                self._pipe["fetch_wait_s"] += self._now() - t0
                 host = tuple(np.asarray(leaf) for leaf in out)
             anchors.clear()
             self._pipe["fetch_s"] += self._now() - t0
@@ -1897,9 +1901,13 @@ class SimulationService:
 
     def pipeline_stats(self) -> dict:
         """Lifetime pipeline accounting (the manifest's `pipeline`
-        block, docs/SERVING.md "The pipeline"): per-stage host walls,
-        the resolved batches, and the device bubble — the fraction of
-        the cumulative drain-execute wall with NO batch in flight."""
+        block, docs/SERVING.md "The pipeline"): per-stage host walls
+        (`fetch_wait_s` the part of `fetch_s` blocked on the device),
+        the resolved batches, the device bubble — the fraction of the
+        cumulative drain-execute wall with NO batch in flight — and the
+        lane-steps of the resolved batches: `lane_steps_executed`
+        (width × steps run, idle lanes and frozen tails included) and
+        `lane_steps_useful` (each live lane's own step count)."""
         p = self._pipe
         wall = p["wall_s"]
         bubble = max(0.0, 1.0 - p["busy_s"] / wall) if wall > 0 else 0.0
@@ -1910,9 +1918,16 @@ class SimulationService:
             "assemble_s": round(p["assemble_s"], 6),
             "dispatch_s": round(p["dispatch_s"], 6),
             "fetch_s": round(p["fetch_s"], 6),
+            "fetch_wait_s": round(p["fetch_wait_s"], 6),
             "resolve_s": round(p["resolve_s"], 6),
             "busy_s": round(p["busy_s"], 6),
             "wall_s": round(p["wall_s"], 6),
+            "lane_steps_executed": sum(
+                st.machine_steps for st in self._stats.values()
+            ),
+            "lane_steps_useful": sum(
+                st.useful_steps for st in self._stats.values()
+            ),
         }
 
     def _fail_ticket(self, t: Ticket, error: str) -> None:

@@ -399,3 +399,30 @@ def test_hide_step_splices_into_whole_shard_interior(topo, n_local):
         elif opcode == "copy" and _elements(shape) >= half:
             big["copy " + re.search(r"\{([\d,]+)", shape).group(1)] += 1
     assert big == {"copy 0,1": 1, "copy 1,0": 1}
+
+
+def test_serve_lane_step_fusion_names_the_lane_step(topo, one_chip):
+    """The serving cell's program, 8 lanes of 1024² on one chip: XLA
+    fuses each lane's stencil into the per-lane freeze select, and that
+    fusion names the lane step, not the loop (layer="driver")."""
+    from rocm_mpi_tpu.config import DiffusionConfig
+    from rocm_mpi_tpu.models import HeatDiffusion
+
+    n = 1024
+    model = HeatDiffusion(
+        DiffusionConfig(global_shape=(n, n), dtype="f32", dims=(1, 1)),
+        devices=topo.devices[:1],
+    )
+    advance, bg = model.batched_advance_fn(batch=8, variant="shard",
+                                           devices=topo.devices[:1])
+    tb = jax.ShapeDtypeStruct((8, n, n), jnp.float32, sharding=bg.sharding)
+    cp = jax.ShapeDtypeStruct((n, n), jnp.float32, sharding=bg.aux_sharding)
+    steps = jax.ShapeDtypeStruct((8,), jnp.int32, sharding=bg.batch_sharding)
+    prog = _program(advance.lower(tb, cp, steps, 8).compile().as_text())
+    stencils = [
+        op for comp in prog["bodies"] for op in prog["comps"][comp]
+        if op[1] == "fusion" and {"multiply", "select"} <= {
+            inner[1] for f in re.findall(r"calls=%([\w.\-]+)", op[5])
+            for inner in prog["comps"][f]}]
+    assert stencils
+    assert {op[3] for op in stencils} == {"lane_step"}
