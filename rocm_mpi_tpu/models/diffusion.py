@@ -526,11 +526,17 @@ class HeatDiffusion:
         """(jitted (T, Cp, n) -> T, chunk q) — the donation-aware scan
         driver: the hot loop is a `lax.scan` over a STATIC q-step chunk
         inside a dynamic-trip fori_loop, with the carried field donated
-        (`donate_argnums=0`). The scan carry is XLA's double buffer — the
-        functional analog of the reference's `T, T2 = T2, T` swap
-        (perf.jl:50) — so steady-state stepping allocates nothing: the
-        donated input buffer and the scan carry pair are the only field
-        storage the program ever touches.
+        (`donate_argnums=0`). The scan takes two steps an iteration
+        (`unroll=2`): a step reads the field it replaces, so with one
+        step an iteration XLA writes it to a temporary and copies that
+        back into the carry, every step. With two, the first step writes
+        the temporary and the second the carry's own buffer — XLA's
+        double buffer, the functional analog of the reference's
+        `T, T2 = T2, T` swap (perf.jl:50) — and no copy is left. An odd
+        q ends each chunk with one single step, which pays the copy once
+        a chunk. Steady-state stepping allocates nothing: the donated
+        input buffer and that temporary are the only field storage the
+        program ever touches.
 
         `q` defaults to the largest chunk serving both timing windows with
         one compiled program (gcd of warmup and the timed window —
@@ -567,7 +573,8 @@ class HeatDiffusion:
                 return step(carry, C, cfg.lam, dt, cfg.spacing, grid), None
 
             def body(_, carry):
-                carry, _ = lax.scan(q_steps, carry, xs=None, length=q)
+                carry, _ = lax.scan(q_steps, carry, xs=None, length=q,
+                                    unroll=2)
                 return carry
 
             return lax.fori_loop(0, n // q, body, T)

@@ -273,10 +273,11 @@ def test_perf_scan_names_its_kernel(programs, cell, kernel):
 def test_hide_step_names_kernels_and_regions(programs):
     """The interior is one whole-shard `masked_step`, which launches
     `multi_step_cm` for a 126² shard (it fits VMEM); the four slabs are
-    `fused_step_cm`."""
+    `fused_step_cm`. The scan driver takes two steps an iteration, so
+    each is there twice."""
     kernels = _kernels(programs("hide"))
     assert sorted((op[4], op[0].rsplit(".", 1)[0]) for op in kernels) == \
-        [("interior", "multi_step_cm")] + [("slab", "fused_step_cm")] * 4
+        [("interior", "multi_step_cm")] * 2 + [("slab", "fused_step_cm")] * 8
     assert all(op[3] == "splice" for op in kernels)
     assert not [op for op in kernels
                 if re.match(r"(closed_call|shard_map)\b", op[0])]
@@ -368,24 +369,10 @@ def _elements(shape: str) -> int:
     return math.prod(int(d) for d in dims.split(",") if d)
 
 
-@pytest.mark.parametrize("n_local", [126, N])
-def test_hide_step_splices_into_whole_shard_interior(topo, n_local):
-    """The 2x2 hide step's interior is one kernel on the whole unpadded
-    shard (region="interior"), the exchange is still there, and at the
-    benchmark's 12288² shard no whole-shard copy is sliced out for the
-    interior or written back: no slice, and no buffer write of an
-    update, is half the shard or more. Two whole-shard copies stay,
-    both put in by the compiler: the field in the transposed layout
-    (minor-to-major {0,1}), as before, and the carry copy, since the
-    interior kernel reads the field it replaces."""
-    with pytest.MonkeyPatch.context() as mp:
-        prog = _program(_compiled(topo, mp, "hide", n_local))
-    assert [op[4] for op in _kernels(prog)].count("interior") == 1
-    loop = _loop_ops(prog)
-    assert any(op[1] == "collective-permute-start" for _, op in loop)
-    if n_local != N:
-        return
-    half = N * N // 2
+def _whole_field_moves(loop, n) -> collections.Counter:
+    """Slices, buffer writes of an update, and copies (by layout) of
+    half the `n`² field or more among the loop ops `loop`."""
+    half = n * n // 2
     shapes = {(comp, op[0]): op[2] for comp, op in loop}
     big = collections.Counter()
     for comp, (name, opcode, shape, *_, rhs) in loop:
@@ -398,7 +385,47 @@ def test_hide_step_splices_into_whole_shard_interior(topo, n_local):
                 big["dynamic-update-slice"] += 1
         elif opcode == "copy" and _elements(shape) >= half:
             big["copy " + re.search(r"\{([\d,]+)", shape).group(1)] += 1
-    assert big == {"copy 0,1": 1, "copy 1,0": 1}
+    return big
+
+
+def test_perf_scan_steps_twice_an_iteration_with_no_field_copy(topo):
+    """The benchmark's 12288² perf program (q = 10): the scan driver's
+    loop takes two `masked_step`s an iteration, so the first writes a
+    temporary and the second the carry's own buffer, and the loop holds
+    no copy of half the field or more. With one step an iteration XLA
+    copies each step's result back into the carry, since the kernel
+    reads the field it replaces."""
+    with pytest.MonkeyPatch.context() as mp:
+        prog = _program(_compiled(topo, mp, "perf", N, dims=(1, 1)))
+    per_body = collections.Counter(
+        comp for comp in prog["bodies"] for op in prog["comps"][comp]
+        if op[0].rsplit(".", 1)[0] == "masked_step")
+    assert list(per_body.values()) == [2]
+    assert _whole_field_moves(_loop_ops(prog), N) == {}
+
+
+@pytest.mark.parametrize("n_local", [126, N])
+def test_hide_step_splices_into_whole_shard_interior(topo, n_local):
+    """The 2x2 hide step's interior is one kernel on the whole unpadded
+    shard (region="interior"), two steps to an iteration of the scan
+    driver, the exchange is still there, and at the benchmark's 12288²
+    shard no whole-shard copy is sliced out for the interior or written
+    back: no slice, and no buffer write of an update, is half the shard
+    or more. The carry copy is gone: the first step of an iteration
+    writes a temporary and the second the carry's own buffer, so
+    neither interior kernel writes the field it reads. One whole-shard
+    copy stays, put in by the compiler: the field in the transposed
+    layout (minor-to-major {0,1}) for the first step's column-edge
+    slabs; the second step reads its column edges from the row-major
+    field."""
+    with pytest.MonkeyPatch.context() as mp:
+        prog = _program(_compiled(topo, mp, "hide", n_local))
+    assert [op[4] for op in _kernels(prog)].count("interior") == 2
+    loop = _loop_ops(prog)
+    assert any(op[1] == "collective-permute-start" for _, op in loop)
+    if n_local != N:
+        return
+    assert _whole_field_moves(loop, N) == {"copy 0,1": 1}
 
 
 def test_serve_lane_step_fusion_names_the_lane_step(topo, one_chip):

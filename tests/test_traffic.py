@@ -253,6 +253,24 @@ class TestReworkedPathParity:
         for u1, u2 in zip(r1.us, r2.us):
             np.testing.assert_array_equal(np.asarray(u1), np.asarray(u2))
 
+    @pytest.mark.parametrize("variant, dims", [("perf", (1, 1)),
+                                               ("hide", (2, 2))],
+                             ids=["perf", "hide"])
+    @pytest.mark.parametrize("nt, warmup, q", [(20, 10, 10), (15, 5, 5)],
+                             ids=["q10", "q5"])
+    def test_diffusion_scan_driver_bitwise_equals_step_driver(
+            self, variant, dims, nt, warmup, q):
+        # The f32 Pallas rungs (masked_step, interpret mode) under the
+        # scan driver's two steps an iteration: an even chunk, and an odd
+        # one that ends each chunk with a single step.
+        m = self._diffusion(dtype="f32", dims=dims, nt=nt, warmup=warmup)
+        assert m.scan_advance_fn(variant)[1] == q
+        r_step = m.run(variant=variant)
+        r_scan = m.run(variant=variant, driver="scan")
+        np.testing.assert_array_equal(
+            np.asarray(r_step.T), np.asarray(r_scan.T)
+        )
+
     def test_wave_masked_hide_bitwise_equals_perf(self):
         # The mask-as-data select (wave_step_padded_masked) is built to be
         # fp-IDENTICAL to perf's expression on updating cells and to hold
