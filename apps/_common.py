@@ -95,7 +95,6 @@ def make_parser(
         "instead of width-1 every step (parallel.deep_halo; f32/bf16)",
     )
     add_wire_mode_flag(p)
-    add_driver_flag(p)
     p.add_argument(
         "--save-field", default=None, metavar="PATH.npy",
         help="dump the final gathered field as .npy (process 0)",
@@ -119,21 +118,6 @@ def add_wire_mode_flag(p) -> None:
         help="on-wire halo slab precision (default f32 — bitwise-"
         "identical exchange; bf16 halves the wire; int8/int8_delta "
         "quantize with error feedback and need --deep)",
-    )
-
-
-def add_driver_flag(p) -> None:
-    """The shared --driver knob: which multi-step loop form runs the
-    per-step variants. "scan" (default) is the donation-aware lax.scan
-    driver (models.*.scan_advance_fn — allocation-free steady state);
-    "step" the classic per-step fori_loop. Results are bitwise identical;
-    telemetry stamps the form so summaries from different drivers can't
-    be compared silently."""
-    p.add_argument(
-        "--driver", default="scan", choices=["step", "scan"],
-        help="multi-step loop form for per-step variants (default: scan, "
-        "the donation-aware lax.scan driver); --deep and --checkpoint "
-        "schedules have their own loop forms and ignore this",
     )
 
 
@@ -474,7 +458,7 @@ def emit_run_gauges(result, variant: str, driver: str | None = None,
     """Bank the run's headline rates into the telemetry stream (no-op
     when collection is off; rate properties divide by the timed window,
     so a fully-resumed nt=0 run emits nothing). `driver` stamps the loop
-    form (step/scan) and `wire` the on-wire halo precision on the
+    form (scan/deep) and `wire` the on-wire halo precision on the
     gauges, so summaries from different drivers or wire modes can't be
     compared silently (aggregate folds non-f32 wire into the gauge key,
     like the driver suffix)."""
@@ -557,20 +541,18 @@ def run_app(variant: str, args) -> int:
                         wire=getattr(args, "wire_mode", None))
     else:
         log0("Starting the time loop 🚀...", end="")
-        driver = getattr(args, "driver", "step")
+        # The loop form stamped on the gauges: the deep schedule's k-step
+        # sweeps or the scan driver — the same spellings weak_scaling
+        # uses, so the two harnesses' gauges land under one key.
+        driver = "deep" if getattr(args, "deep", 0) else "scan"
         with profile_ctx:
             if getattr(args, "deep", 0):
-                # The deep schedule is its own loop form (k-step sweeps);
-                # --driver selects among the per-step loop forms only.
-                # Stamp "deep" — the same spelling weak_scaling uses — so
-                # the two harnesses' gauges land under one key.
                 result = model.run_deep(
                     block_steps=args.deep,
                     wire_mode=getattr(args, "wire_mode", None),
                 )
-                driver = "deep"
             else:
-                result = model.run(variant=variant, driver=driver)
+                result = model.run(variant=variant)
         log0("done")
 
         per_chip = result.t_eff / grid.nprocs

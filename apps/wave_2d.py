@@ -58,13 +58,11 @@ def make_parser():
     )
     from _common import (
         add_checkpoint_flags,
-        add_driver_flag,
         add_telemetry_flag,
         add_wire_mode_flag,
     )
 
     add_wire_mode_flag(p)
-    add_driver_flag(p)
     add_telemetry_flag(p)
     add_checkpoint_flags(p)
     return p
@@ -149,7 +147,7 @@ def main(argv=None) -> int:
         runner = model.run_vmem_resident
     else:
         label = args.variant
-        runner = lambda: model.run(variant=args.variant, driver=args.driver)
+        runner = lambda: model.run(variant=args.variant)
     from _common import profile_context
 
     profile_ctx = profile_context(jax, args)

@@ -28,8 +28,8 @@ sys.path.insert(0, str(pathlib.Path(__file__).resolve().parent.parent))
 
 
 def telemetry_windowed_run(model, variant: str, nt: int, warmup: int,
-                           windows: int, driver: str = "step",
-                           step_base: int = 0, config: str | None = None):
+                           windows: int, step_base: int = 0,
+                           config: str | None = None):
     """The --telemetry run path (diffusion): the same warmup/timed
     protocol as model.run, but the timed loop split into `windows`
     spanned windows — per-step PERCENTILES need more than the single
@@ -39,11 +39,11 @@ def telemetry_windowed_run(model, variant: str, nt: int, warmup: int,
     correctness requirement); windows of many steps amortize it, exactly
     as tic/toc always did.
 
-    `driver` picks the loop form (step/scan — models run the same step
-    program either way); the scan driver's static chunk q quantizes the
-    windows (every window a multiple of q, guaranteed non-degenerate by
-    q | gcd(warmup, timed)), and every span carries the driver stamp so
-    summaries from different drivers can't be compared silently.
+    The run is the scan driver's, as model.run's is: its static chunk q
+    quantizes the windows (every window a multiple of q, guaranteed
+    non-degenerate by q | gcd(warmup, timed)), and every span carries
+    the "scan" driver stamp so summaries from different loop forms
+    can't be compared silently.
 
     Under the health plane (--health / RMT_HEALTH, flight recorder on)
     each window boundary additionally runs, in this order: (1) the halo
@@ -76,11 +76,9 @@ def telemetry_windowed_run(model, variant: str, nt: int, warmup: int,
         # Same contract as model.run: a degenerate window must fail
         # loudly here, not as a later divide-by-zero or a negative rate.
         raise ValueError(f"need 0 <= warmup < nt, got {warmup}, {nt}")
-    if driver == "scan":
-        advance, unit = model.scan_advance_fn(variant, nt=nt, warmup=warmup,
-                                              config=config)
-    else:
-        advance, unit = model.advance_fn(variant), 1
+    advance, unit = model.scan_advance_fn(variant, nt=nt, warmup=warmup,
+                                          config=config)
+    driver = "scan"
     T, Cp = model.init_state()
     from rocm_mpi_tpu import telemetry
 
@@ -161,14 +159,8 @@ def main(argv=None) -> int:
                    "up to all available)")
     p.add_argument("--json", action="store_true",
                    help="emit one JSON line per count as well")
-    from _common import (
-        add_driver_flag,
-        add_health_flag,
-        add_telemetry_flag,
-        setup_jax,
-    )
+    from _common import add_health_flag, add_telemetry_flag, setup_jax
 
-    add_driver_flag(p)
     add_telemetry_flag(p)
     add_health_flag(p)
     p.add_argument("--telemetry-windows", type=int, default=8, metavar="W",
@@ -231,9 +223,9 @@ def main(argv=None) -> int:
     # rung never publishes, which the read side treats as
     # "not participating", never as "stalled").
     steps_banked = 0
-    # The loop-form stamp every gauge/probe carries (the deep schedule is
-    # its own form; --driver only selects among the per-step loop forms).
-    run_driver = "deep" if args.variant == "deep" else args.driver
+    # The loop-form stamp every gauge/probe carries: the deep schedule's
+    # k-step sweeps, or the scan driver every per-step variant runs on.
+    run_driver = "deep" if args.variant == "deep" else "scan"
     # Process-0-gated output: on a multi-host slice every process runs this
     # script, but only one may report (rank-0 printing, SURVEY.md §5.5).
     log0(
@@ -298,12 +290,11 @@ def main(argv=None) -> int:
             # graftlint: disable-next=GL08
             r = telemetry_windowed_run(
                 model, args.variant, args.nt, args.warmup,
-                args.telemetry_windows, driver=args.driver,
-                step_base=steps_banked, config=run_config,
+                args.telemetry_windows, step_base=steps_banked,
+                config=run_config,
             )
         else:
-            r = model.run(variant=args.variant, driver=args.driver,
-                          config=run_config)
+            r = model.run(variant=args.variant, config=run_config)
         steps_banked += args.nt
         probe_model = model  # the last rung this process participated in
         per_dev = r.gpts / n
@@ -314,7 +305,7 @@ def main(argv=None) -> int:
             base_per_dev, base_n = per_dev, n
         eff = per_dev / base_per_dev
         # The driver stamp rides every gauge: a "scan"-driver summary and
-        # a "step"-driver summary are different measurements and must not
+        # a "deep" one are different measurements and must not
         # regress-gate against each other silently.
         if telemetry.enabled():
             telemetry.gauge("run.gpts", round(r.gpts, 6), devices=n,

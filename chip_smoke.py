@@ -96,7 +96,7 @@ def config(n: int, nt: int, warmup: int, dims=(1, 1)):
         "--dtype", "f32", "--nt", str(nt), "--warmup", str(warmup),
         "--dims", ",".join(str(d) for d in dims),
     ])
-    return build_config(args), args.driver
+    return build_config(args)
 
 
 def _check(label: str, got, ref, T0, nt: int) -> str:
@@ -149,13 +149,11 @@ def phase_perf(n: int = REF_N, nt: int = SMOKE_NT,
     """`perf` (one Pallas kernel per step) at n² vs the `ap` oracle."""
     from rocm_mpi_tpu.models import HeatDiffusion
 
-    cfg, driver = config(n, nt, warmup)
-    r, wall, comp = _timed(
-        lambda: HeatDiffusion(cfg).run(variant="perf", driver=driver)
-    )
+    cfg = config(n, nt, warmup)
+    r, wall, comp = _timed(lambda: HeatDiffusion(cfg).run(variant="perf"))
     ref, T0 = _oracle(cfg, nt)
     ev = _check("perf", r.T, ref, T0, nt)
-    return (f"[perf] {n}² f32 dims=(1,1) driver={driver} nt={nt} "
+    return (f"[perf] {n}² f32 dims=(1,1) driver=scan nt={nt} "
             f"(reference: {REF_PERF_NT}) warmup={warmup}: compile "
             f"{comp:.2f} s, {_rate_line(r)}, wall {wall:.2f} s; {ev}")
 
@@ -165,7 +163,7 @@ def phase_tb(n: int = REF_N, k: int = TB_K, nt: int = 13 * TB_K,
     """Temporal-blocked HBM sweeps (k steps per pass) vs the oracle."""
     from rocm_mpi_tpu.models import HeatDiffusion
 
-    cfg, _ = config(n, nt, warmup)
+    cfg = config(n, nt, warmup)
     r, wall, comp = _timed(
         lambda: HeatDiffusion(cfg).run_hbm_blocked(block_steps=k)
     )
@@ -219,7 +217,7 @@ def phase_serve(n: int = SERVE_N, count: int = SERVE_REQUESTS,
                 f"serve: {t.request.request_id} returned a "
                 f"{field.shape} field with non-finite values"
             )
-    cfg, _ = config(n, nt, 0)
+    cfg = config(n, nt, 0)
     ref, T0 = _oracle(cfg, tickets[0].steps_run)
     import jax.numpy as jnp
 
@@ -239,16 +237,14 @@ def phase_four_chips(n_local: int, nt: int, warmup: int) -> list[str]:
     from rocm_mpi_tpu.models import HeatDiffusion
 
     n = 2 * n_local
-    cfg, driver = config(n, nt, warmup, dims=(2, 2))
+    cfg = config(n, nt, warmup, dims=(2, 2))
     ref = _oracle(cfg, nt, devices=jax.devices()[:1])[0]
     model = HeatDiffusion(cfg)
     ref = jax.device_put(ref, model.grid.sharding)  # frees device 0
     T0 = model.init_state()[0]
     lines = []
     for variant in ("perf", "hide"):
-        r, wall, comp = _timed(
-            lambda: model.run(variant=variant, driver=driver)
-        )
+        r, wall, comp = _timed(lambda: model.run(variant=variant))
         shards = {s.device: s.data.shape for s in r.T.addressable_shards}
         if set(shards) != set(jax.devices()) or set(shards.values()) != {
             (n_local, n_local)
@@ -260,7 +256,7 @@ def phase_four_chips(n_local: int, nt: int, warmup: int) -> list[str]:
         ev = _check(variant, r.T, ref, T0, nt)
         lines.append(
             f"[2x2 {variant}] {n}² global ({n_local}²/chip) f32 "
-            f"driver={driver} nt={nt} warmup={warmup}: compile {comp:.2f} s, "
+            f"driver=scan nt={nt} warmup={warmup}: compile {comp:.2f} s, "
             f"{_rate_line(r)}, wall {wall:.2f} s; one shard on each of "
             f"{len(shards)} devices; {ev}"
         )

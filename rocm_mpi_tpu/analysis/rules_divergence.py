@@ -11,7 +11,7 @@ divergence spans functions and modules:
   the tuning cache from ITS OWN filesystem; a per-rank winning `chunk`
   builds divergently traced scan programs — mismatched collective
   counts per invocation. Shipped fix: `process_count() > 1` → defaults
-  (models/diffusion.auto_scan_chunk, parallel/deep_halo.auto_deep_k).
+  (models/driver.auto_scan_chunk, parallel/deep_halo.auto_deep_k).
 * **PR 6 (elastic restore):** resuming on a different mesh must rebuild
   the exchange machinery identically on every rank; a rank that
   branches on locally-read manifest content into a different

@@ -10,10 +10,10 @@ reference's app ladder (SURVEY.md §2.1 C1-C4):
   Pallas/overlap variants ("kp", "perf", "hide") are added by
   rocm_mpi_tpu.ops.pallas_kernels / parallel.overlap and registered here.
 
-The hot loop lives *inside* one jitted `lax.fori_loop` — the TPU-first
-answer to the reference's per-step `wait(@roc …); update_halo!` host
-round-trips (scripts/diffusion_2D_perf.jl:47-52): nothing leaves the device
-between tic and toc.
+The hot loop lives *inside* one jitted loop (models/driver.py) — the
+TPU-first answer to the reference's per-step `wait(@roc …); update_halo!`
+host round-trips (scripts/diffusion_2D_perf.jl:47-52): nothing leaves the
+device between tic and toc.
 """
 
 from __future__ import annotations
@@ -24,11 +24,11 @@ from typing import Callable
 
 import jax
 import jax.numpy as jnp
-from jax import lax
 
 from rocm_mpi_tpu.utils.compat import shard_map
 
 from rocm_mpi_tpu.config import DiffusionConfig
+from rocm_mpi_tpu.models.driver import Driver, Result, effective_block_steps
 from rocm_mpi_tpu.ops.diffusion import (
     gaussian_ic,
     step_flux_form,
@@ -36,35 +36,9 @@ from rocm_mpi_tpu.ops.diffusion import (
     step_fused_padded,
 )
 from rocm_mpi_tpu.parallel.halo import exchange_halo, global_boundary_mask
-from rocm_mpi_tpu.parallel.mesh import GlobalGrid, init_global_grid
+from rocm_mpi_tpu.parallel.mesh import GlobalGrid
 from rocm_mpi_tpu.utils import compat
 from rocm_mpi_tpu.utils import metrics
-
-
-def effective_block_steps(
-    nt: int, warmup: int, k: int, *, label: str = "block_steps",
-    warn: bool = True, stacklevel: int = 3,
-) -> int:
-    """The sweep/chunk depth actually usable for the given step counts:
-    gcd(warmup, nt-warmup, k) — both windows must be multiples of the
-    depth so one compiled program serves both. The single source of truth
-    for every runner (and for callers labeling artifacts by depth), so the
-    warned, reported, and executed k can never diverge.
-    """
-    import math
-    import warnings
-
-    if k < 1:
-        raise ValueError(f"{label} must be >= 1, got {k}")
-    eff = math.gcd(math.gcd(warmup, nt - warmup), k) or 1
-    if warn and eff != k:
-        warnings.warn(
-            f"{label} degraded: {k} requested but warmup={warmup} / "
-            f"timed={nt - warmup} force k={eff}; pick step counts "
-            f"divisible by {k} to keep the full k-steps-per-sweep saving.",
-            stacklevel=stacklevel,
-        )
-    return eff
 
 
 def default_deep_depth(local_shape, itemsize: int) -> int:
@@ -95,49 +69,6 @@ def default_deep_depth(local_shape, itemsize: int) -> int:
     if padded_bytes(k) > _VMEM_BLOCK_BUDGET_BYTES:
         k = min(k, DEFAULT_TB_STEPS)
     return max(1, k)
-
-
-def auto_scan_chunk(op: str, grid, dtype, config: str | None) -> int | None:
-    """The scan drivers' `config="auto"` seam, shared by all three
-    models: the tuning cache's preferred chunk for `op` at this
-    shard/topology, or None (= the default whole-window policy) on a
-    miss or a non-auto config. The caller still gcd's the preference
-    against its windows (effective_block_steps) — auto never breaks the
-    divisibility contract, it only prefers a different quantum."""
-    if config in (None, "default"):
-        return None
-    if config != "auto":
-        raise ValueError(
-            f"config must be None, 'default' or 'auto', got {config!r}"
-        )
-    if jax.process_count() > 1:
-        # Multi-controller: every process resolves from ITS OWN cache
-        # file, and a divergent chunk means divergently traced programs
-        # across ranks. The defaults are deterministic everywhere; auto
-        # stays hands-off until a broadcast-consistent resolve exists.
-        return None
-    from rocm_mpi_tpu.tuning import resolve as tuning_resolve
-
-    tuned = tuning_resolve.resolve(
-        op, grid.local_shape, dtype, topology=grid.dims
-    )
-    if tuned and tuned.get("chunk"):
-        return int(tuned["chunk"])
-    return None
-
-
-def _driver_layer(advance):
-    """Trace `advance` under layer="driver" (docs/TELEMETRY.md
-    "Device-side names"): the once-per-call prepare and the loop's own
-    ops carry it, and so does every op of the step that no inner layer
-    (exchange, splice) names."""
-
-    @functools.wraps(advance)
-    def tagged(*args, **kwargs):
-        with compat.set_xla_metadata(layer="driver"):
-            return advance(*args, **kwargs)
-
-    return tagged
 
 
 def _whole_shard_interior(dtype):
@@ -173,30 +104,18 @@ def warn_host_transport_ignored(variant: str, stacklevel: int = 3) -> None:
 
 
 @dataclasses.dataclass
-class RunResult:
+class RunResult(Result):
     T: jax.Array  # final temperature field (global, sharded)
-    wtime: float  # seconds over the timed steps
-    nt: int
-    warmup: int
-    config: DiffusionConfig
 
     @property
-    def wtime_it(self) -> float:
-        return metrics.wtime_per_it(self.wtime, self.nt, self.warmup)
-
-    @property
-    def t_eff(self) -> float:
-        return metrics.t_eff_gbs(
-            self.T.shape, self.T.dtype.itemsize, self.wtime_it
-        )
-
-    @property
-    def gpts(self) -> float:
-        return metrics.gpts_per_s(self.T.shape, self.wtime_it)
+    def field(self):
+        return self.T
 
 
-class HeatDiffusion:
+class HeatDiffusion(Driver):
     """Heat diffusion on a sharded global grid, with selectable step variant."""
+
+    workload = "diffusion"
 
     def __init__(
         self,
@@ -204,14 +123,8 @@ class HeatDiffusion:
         grid: GlobalGrid | None = None,
         devices=None,
     ):
-        self.config = config
-        if grid is None:
-            grid = init_global_grid(
-                *config.global_shape,
-                lengths=config.lengths,
-                dims=config.dims,
-                devices=devices,
-            )
+        super().__init__(config, grid, devices)
+        grid = self.grid
         if grid.global_shape != config.global_shape:
             raise ValueError(
                 f"grid shape {grid.global_shape} != config {config.global_shape}"
@@ -220,7 +133,6 @@ class HeatDiffusion:
             raise ValueError(
                 f"grid lengths {grid.lengths} != config {config.lengths}"
             )
-        self.grid = grid
         self._step_fns: dict[str, Callable] = {}
         self._prep_fns: dict[str, Callable] = {}
         self.register_variant("ap", self._make_jnp_step(step_flux_form))
@@ -269,6 +181,13 @@ class HeatDiffusion:
             return jnp.full(grid.global_shape, cfg.cp0, dtype=dtype)
 
         return make_T(), make_Cp()
+
+    def state_and_aux(self):
+        T, Cp = self.init_state()
+        return (T,), Cp
+
+    def _result(self, state, **window) -> RunResult:
+        return RunResult(T=state[0], **window)
 
     # ---- variants -------------------------------------------------------
 
@@ -392,17 +311,25 @@ class HeatDiffusion:
 
         return step, prepare
 
-    def step_fn(self, variant: str):
-        """jitted single step (T, Cp) -> T (no donation; compile-check safe)."""
+    def _step(self, variant: str):
+        """(`step(state, C) -> state`, `prepare(Cp) -> C` or None): a
+        registered variant in the schedule layer's form (models/driver.py)."""
         cfg, grid = self.config, self.grid
         step = self._get_step(variant)
         prep = self._prep_fns.get(variant)
         dt = cfg.jax_dtype(cfg.dt)
+        return (
+            lambda s, C: (step(s[0], C, cfg.lam, dt, cfg.spacing, grid),),
+            None if prep is None else (lambda Cp: prep(Cp, cfg.lam, dt)),
+        )
+
+    def step_fn(self, variant: str):
+        """jitted single step (T, Cp) -> T (no donation; compile-check safe)."""
+        step, prep = self._step(variant)
 
         @jax.jit
         def one_step(T, Cp):
-            C = Cp if prep is None else prep(Cp, cfg.lam, dt)
-            return step(T, C, cfg.lam, dt, cfg.spacing, grid)
+            return step((T,), Cp if prep is None else prep(Cp))[0]
 
         return one_step
 
@@ -418,18 +345,15 @@ class HeatDiffusion:
         (their loop carry reuses the field buffer), which is what lets
         XLA update the exchanged buffer in place instead of inserting a
         defensive copy. Callers must then rebind T from the result."""
-        cfg, grid = self.config, self.grid
-        step = self._get_step(variant)
-        prep = self._prep_fns.get(variant)
-        dt = cfg.jax_dtype(cfg.dt)
+        step, prep = self._step(variant)
 
         @jax.jit
         def prepare(Cp):
-            return Cp if prep is None else prep(Cp, cfg.lam, dt)
+            return Cp if prep is None else prep(Cp)
 
         @functools.partial(jax.jit, donate_argnums=(0,) if donate else ())
         def one_step(T, C):
-            return step(T, C, cfg.lam, dt, cfg.spacing, grid)
+            return step((T,), C)[0]
 
         return one_step, prepare
 
@@ -484,128 +408,13 @@ class HeatDiffusion:
 
         return step, prepare
 
-    def advance_fn(self, variant: str):
-        """jitted (T, Cp, n_steps) -> T after n_steps.
-
-        `n_steps` is *traced* (dynamic fori_loop bound) so the warmup call
-        and the timed call share one compiled program — otherwise the timed
-        window would include a recompile for the new static step count and
-        the warmup would fail its purpose (perf.jl:48's it==11 tic assumes
-        the code is warm).
-
-        NOTE: donates T (argument 0) — the functional analog of the
-        reference's `T, T2 = T2, T` double-buffer swap (perf.jl:50): XLA
-        reuses the input buffer instead of allocating a second field. The
-        caller must not reuse the passed-in T afterwards.
-        """
-        cfg, grid = self.config, self.grid
-        step = self._get_step(variant)
-        prep = self._prep_fns.get(variant)
-        dt = cfg.jax_dtype(cfg.dt)
-
-        @functools.partial(jax.jit, donate_argnums=0)
-        @_driver_layer
-        def advance(T, Cp, n):
-            # Loop-invariant coefficient (e.g. the perf rung's Cm), traced
-            # once OUTSIDE the fori_loop — zero per-step host round-trips
-            # and zero per-step mask/divide work by construction.
-            C = Cp if prep is None else prep(Cp, cfg.lam, dt)
-            body = lambda _, T: step(T, C, cfg.lam, dt, cfg.spacing, grid)
-            return lax.fori_loop(0, n, body, T)
-
-        return advance
-
-    def scan_advance_fn(
-        self,
-        variant: str,
-        nt: int | None = None,
-        warmup: int | None = None,
-        chunk: int | None = None,
-        config: str | None = None,
-    ):
-        """(jitted (T, Cp, n) -> T, chunk q) — the donation-aware scan
-        driver: the hot loop is a `lax.scan` over a STATIC q-step chunk
-        inside a dynamic-trip fori_loop, with the carried field donated
-        (`donate_argnums=0`). The scan takes two steps an iteration
-        (`unroll=2`): a step reads the field it replaces, so with one
-        step an iteration XLA writes it to a temporary and copies that
-        back into the carry, every step. With two, the first step writes
-        the temporary and the second the carry's own buffer — XLA's
-        double buffer, the functional analog of the reference's
-        `T, T2 = T2, T` swap (perf.jl:50) — and no copy is left. An odd
-        q ends each chunk with one single step, which pays the copy once
-        a chunk. Steady-state stepping allocates nothing: the donated
-        input buffer and that temporary are the only field storage the
-        program ever touches.
-
-        `q` defaults to the largest chunk serving both timing windows with
-        one compiled program (gcd of warmup and the timed window —
-        effective_block_steps); `n` must be a multiple of q (the outer
-        trip count floors, the step-count convention the deep advance
-        shares). `config="auto"` treats a tuning-cache chunk (op
-        "diffusion.scan", keyed per shard/topology) as the preference an
-        unset `chunk` gcd's from — traffic-neutral and bitwise-identical
-        at any q (scan==step is pinned), so auto only moves window
-        quantization. The caller must rebind T from the result (GL01:
-        the passed-in buffer is donated).
-        """
-        cfg, grid = self.config, self.grid
-        step = self._get_step(variant)
-        prep = self._prep_fns.get(variant)
-        dt = cfg.jax_dtype(cfg.dt)
-        nt_v = cfg.nt if nt is None else nt
-        wu_v = cfg.warmup if warmup is None else warmup
-        explicit = chunk is not None
-        if not explicit:
-            chunk = auto_scan_chunk("diffusion.scan", grid, cfg.jax_dtype,
-                                    config)
-        q = effective_block_steps(
-            nt_v, wu_v, (nt_v - wu_v) if chunk is None else chunk,
-            label="scan driver chunk", warn=explicit,
-        )
-
-        @functools.partial(jax.jit, donate_argnums=0)
-        @_driver_layer
-        def advance(T, Cp, n):
-            C = Cp if prep is None else prep(Cp, cfg.lam, dt)
-
-            def q_steps(carry, _):
-                return step(carry, C, cfg.lam, dt, cfg.spacing, grid), None
-
-            def body(_, carry):
-                carry, _ = lax.scan(q_steps, carry, xs=None, length=q,
-                                    unroll=2)
-                return carry
-
-            return lax.fori_loop(0, n // q, body, T)
-
-        return advance, q
-
     # ---- multi-tenant batching (docs/SERVING.md) ------------------------
 
-    def make_batched_grid(self, batch: int, batch_dims: int = 1,
-                          devices=None):
-        """The space×batch mesh for `batch` lanes of THIS model's space
-        problem (mesh.init_batched_grid), space decomposition pinned to
-        the model's own grid dims so a lane's spatial shards match its
-        standalone twin's."""
-        from rocm_mpi_tpu.parallel.mesh import init_batched_grid
-
-        cfg = self.config
-        return init_batched_grid(
-            batch,
-            *cfg.global_shape,
-            lengths=cfg.lengths,
-            space_dims=self.grid.dims,
-            batch_dims=batch_dims,
-            devices=devices,
-        )
-
     def _make_batched_step(self, bgrid, variant: str):
-        """(`step(Tb, C) -> Tb`, prepare-or-None) over `(batch, *space)`
-        lane-batched state (C is the UNBATCHED space-shaped coefficient
-        every lane shares — physics is a bin-key field,
-        docs/SERVING.md; `prepare(Cp) -> C` is the loop-invariant
+        """(`step(state, C) -> state`, prepare-or-None) over
+        `(batch, *space)` lane-batched state (C is the UNBATCHED
+        space-shaped coefficient every lane shares — physics is a bin-key
+        field, docs/SERVING.md; `prepare(Cp) -> C` is the loop-invariant
         coefficient transform, traced once per jitted program like the
         unbatched variants' prep). "shard" runs the explicit exchange
         machinery — shard_map over the space×batch mesh, the per-lane
@@ -626,10 +435,10 @@ class HeatDiffusion:
         if variant in ("ap", "fused"):
             raw = (step_flux_form if variant == "ap" else step_fused)
 
-            def step(Tb, C):
-                return jax.vmap(
+            def step(s, C):
+                return (jax.vmap(
                     lambda T: raw(T, C, cfg.lam, dt, cfg.spacing)
-                )(Tb)
+                )(s[0]),)
 
             return step, None
 
@@ -658,14 +467,14 @@ class HeatDiffusion:
 
             return jax.vmap(lane)(Tb_l, Tp)
 
-        def step(Tb, C):
-            return shard_map(
+        def step(s, C):
+            return (shard_map(
                 lane_local,
                 mesh=bgrid.mesh,
                 in_specs=(bgrid.spec, bgrid.aux_spec),
                 out_specs=bgrid.spec,
                 check_vma=False,
-            )(Tb, C)
+            )(s[0], C),)
 
         return step, None
 
@@ -711,8 +520,8 @@ class HeatDiffusion:
                 out_specs=bgrid.aux_spec, check_vma=False,
             )(Cp)
 
-        def step(Tb, Cm):
-            return shard_map(
+        def step(s, Cm):
+            return (shard_map(
                 lambda Tb_l, Cml: batched_local(
                     Tb_l, Cml, cfg.lam, dt, cfg.spacing
                 ),
@@ -720,7 +529,7 @@ class HeatDiffusion:
                 in_specs=(bgrid.spec, bgrid.aux_spec),
                 out_specs=bgrid.spec,
                 check_vma=False,
-            )(Tb, Cm)
+            )(s[0], Cm),)
 
         return step, prepare
 
@@ -733,7 +542,11 @@ class HeatDiffusion:
         prepared coefficient (hide), C is the PREPARED operand —
         `batched_prepare_fn` builds it, exactly as prepared_step_fn
         splits the single-lane audit surface."""
-        step, _ = self._make_batched_step(bgrid, variant)
+        batched, _ = self._make_batched_step(bgrid, variant)
+
+        def step(Tb, C):
+            return batched((Tb,), C)[0]
+
         return jax.jit(step, donate_argnums=(0,) if donate else ())
 
     def batched_prepare_fn(self, bgrid, variant: str = "shard"):
@@ -745,109 +558,17 @@ class HeatDiffusion:
             return jax.jit(lambda C: C)
         return jax.jit(prep)
 
-    def batched_advance_fn(
-        self,
-        batch: int | None = None,
-        variant: str = "shard",
-        bgrid=None,
-        batch_dims: int = 1,
-        devices=None,
-    ):
-        """(jitted `advance(Tb, Cp, lane_steps, n) -> Tb`, bgrid) — the
-        multi-tenant batched advance (docs/SERVING.md): `Tb` is
-        `(batch, *space)` lane-batched state sharded `bgrid.spec`; `Cp`
-        the single space-shaped coefficient all lanes share;
-        `lane_steps` a `(batch,)` int32 of per-lane step counts (the bin
-        scheduler's steps padding: the batch runs `n` = max steps, a
-        lane freezes bitwise once its own count is reached — the
-        pass-through select is exact, so every lane is bitwise-equal to
-        a standalone run of its own length); `n` the dynamic trip count.
-        Donates Tb (rebind from the result; the lowered audit proves
-        the aliasing from the compiled program —
-        analysis/lowered.audit_batched_drivers). One compiled program
-        serves any lane_steps/n mix — the bin scheduler's
-        compile-amortization contract (`compiles.steady_state == 0`).
-        Variant "hide" runs the lane-batched overlap step with its Cm
-        coefficient prepared once inside the jitted program, exactly
-        like the unbatched drivers' prep."""
-        if bgrid is None:
-            if batch is None:
-                raise ValueError("pass batch= or a prebuilt bgrid=")
-            bgrid = self.make_batched_grid(batch, batch_dims, devices)
-        step, prep = self._make_batched_step(bgrid, variant)
-        shape1 = (-1,) + (1,) * bgrid.space.ndim
-
-        @functools.partial(jax.jit, donate_argnums=0)
-        @_driver_layer
-        def advance(Tb, Cp, lane_steps, n):
-            C = Cp if prep is None else prep(Cp)
-
-            def body(i, T):
-                new = step(T, C)
-                # The per-lane freeze belongs to the lane step: XLA fuses
-                # the stencil into this select, and a fusion carries its
-                # root's tag.
-                with compat.set_xla_metadata(layer="lane_step"):
-                    active = (i < lane_steps).reshape(shape1)
-                    return jnp.where(active, new, T)
-
-            return lax.fori_loop(0, n, body, Tb)
-
-        return advance, bgrid
-
-    def batched_ladder_advance_fn(
-        self,
-        batch: int | None = None,
-        bgrid=None,
-        batch_dims: int = 1,
-        devices=None,
-    ):
-        """(jitted `advance(Tb, Cp, hold, dt_lam, inv_d2, lane_steps, n) ->
-        Tb`, bgrid) — the LADDER edition of the batched advance
-        (docs/SERVING.md "Continuous batching"): this model's shape is
-        the ladder RUNG, and each lane may embed a smaller original
-        domain at the origin corner. Geometry rides traced per-lane
-        operands instead of trace constants, so ONE compiled program
-        serves every original shape on the rung:
-
-          * `hold` — (batch, *space) bool, True on a lane's held cells:
-            its original domain's global Dirichlet ring AND every cell
-            outside the embedded domain (pad cells freeze bitwise at
-            their initial value, exactly like a finished lane's steps);
-          * `dt_lam` — (batch,) per-lane dt·λ; `inv_d2` — a TUPLE of
-            ndim (batch,) per-axis reciprocal spacing² operands — dt·λ
-            multiplied in the compute dtype, each reciprocal rounded
-            exactly as XLA folds the standalone divide-by-constant
-            (ops.diffusion.step_fused_padded_geom has the ulp
-            rationale).
-
-        Because the held ring separates each embedded interior from the
-        padding, interior cells read only original-domain values — every
-        lane is bitwise-equal to its standalone run ('shard' variant,
-        lossless 'f32' wire; the service gates eligibility). Donates Tb.
-        """
-        if bgrid is None:
-            if batch is None:
-                raise ValueError("pass batch= or a prebuilt bgrid=")
-            bgrid = self.make_batched_grid(batch, batch_dims, devices)
+    def _ladder_step(self, bgrid):
+        """The ladder's batched step, `step(state, (Cp, hold, dt_lam,
+        inv_d2)) -> state`: `dt_lam` the per-lane dt·λ, multiplied in the
+        compute dtype (batched_ladder_advance_fn)."""
         step = self.batched_ladder_step_fn(bgrid)
-        shape1 = (-1,) + (1,) * bgrid.space.ndim
-
-        @functools.partial(jax.jit, donate_argnums=0)
-        def advance(Tb, Cp, hold, dt_lam, inv_d2, lane_steps, n):
-            def body(i, T):
-                new = step(T, Cp, hold, dt_lam, *inv_d2)
-                active = (i < lane_steps).reshape(shape1)
-                return jnp.where(active, new, T)
-
-            return lax.fori_loop(0, n, body, Tb)
-
-        return advance, bgrid
+        return lambda s, P: (step(s[0], P[0], P[1], P[2], *P[3]),)
 
     def batched_ladder_step_fn(self, bgrid):
         """The UNJITTED per-step program of `batched_ladder_advance_fn` —
         `step(Tb, Cp, hold, dt_lam, *inv_d2) -> Tb`, the shard_map'd
-        body the advance's fori_loop repeats. Exposed separately so the
+        body the advance's loop repeats. Exposed separately so the
         traffic audit can price ONE ladder step (the HLO byte model
         reads the entry computation only; a loop body would be
         invisible to it).
@@ -902,10 +623,7 @@ class HeatDiffusion:
         from rocm_mpi_tpu.parallel.deep_halo import make_deep_sweep
 
         cfg = self.config
-        if bgrid is None:
-            if batch is None:
-                raise ValueError("pass batch= or a prebuilt bgrid=")
-            bgrid = self.make_batched_grid(batch, batch_dims, devices)
+        bgrid = self._bgrid(batch, bgrid, batch_dims, devices)
         k = block_steps
         if k is None:
             from rocm_mpi_tpu.ops.pallas_kernels import _compute_itemsize
@@ -917,40 +635,19 @@ class HeatDiffusion:
         dt = cfg.jax_dtype(cfg.dt)
         sched = make_deep_sweep(bgrid, k, cfg.lam, dt, cfg.spacing,
                                 wire_mode=wm)
+        return self._sweep_advance(sched), bgrid, sched.k
 
-        @functools.partial(jax.jit, donate_argnums=0)
-        def advance(Tb, Cp, n):
-            Cm = sched.prepare(Cp)
-            return lax.fori_loop(
-                0, n // k, lambda _, x: sched.sweep(x, Cm), Tb
-            )
-
-        return advance, bgrid, sched.k
-
-    # ---- driver ---------------------------------------------------------
+    # ---- runs -----------------------------------------------------------
 
     def run(
         self, variant: str = "ap", nt: int | None = None,
-        warmup: int | None = None, driver: str = "step",
-        config: str | None = None,
+        warmup: int | None = None, config: str | None = None,
     ) -> RunResult:
-        """Run `nt` steps; time all but the first `warmup` (perf.jl:47-53).
-
-        `driver` selects the multi-step loop form: "step" is the classic
-        per-step fori_loop advance; "scan" the donation-aware lax.scan
-        driver (scan_advance_fn — allocation-free steady state). Both run
-        the same step program in the same order; results are bitwise
-        identical. The host-staged oracle path ignores the driver (it is
-        a numpy loop). `config="auto"` lets the scan driver's chunk
-        consult the tuning cache (scan_advance_fn).
-        """
+        """Run `nt` steps on the scan driver; time all but the first
+        `warmup` (perf.jl:47-53; Driver.run). halo_transport='host' on
+        variant 'shard' runs the host-staged numpy oracle instead."""
         cfg = self.config
-        nt = cfg.nt if nt is None else nt
-        warmup = cfg.warmup if warmup is None else warmup
-        if not 0 <= warmup < nt:
-            raise ValueError(f"need 0 <= warmup < nt, got {warmup}, {nt}")
-        if driver not in ("step", "scan"):
-            raise ValueError(f"driver must be 'step' or 'scan', got {driver!r}")
+        nt, warmup = self._windows(nt, warmup)
         if cfg.halo_transport == "host":
             if variant == "shard":
                 return self._run_host_staged(nt, warmup)
@@ -965,23 +662,7 @@ class HeatDiffusion:
                 "deep schedule.",
                 stacklevel=2,
             )
-        T, Cp = self.init_state()
-        if driver == "scan":
-            # q divides both windows by construction (gcd).
-            advance, _ = self.scan_advance_fn(variant, nt=nt, warmup=warmup,
-                                              config=config)
-        else:
-            advance = self.advance_fn(variant)
-        timer = metrics.Timer(label="step_window", phase="step",
-                              steps=nt - warmup, variant=variant,
-                              driver=driver, workload="diffusion")
-        if warmup:
-            T = advance(T, Cp, warmup)
-        metrics.force(T)
-        with timer:
-            T = advance(T, Cp, nt - warmup)
-            wtime = timer.toc(T)
-        return RunResult(T=T, wtime=wtime, nt=nt, warmup=warmup, config=cfg)
+        return super().run(variant, nt, warmup, config)
 
     def _run_single_shard(
         self, nt, warmup, multi_step_fn, granularity: int, granularity_kw: str,
@@ -1008,18 +689,13 @@ class HeatDiffusion:
         (they ride the dynamic `n`).
         """
         cfg = self.config
-        nt = cfg.nt if nt is None else nt
-        warmup = cfg.warmup if warmup is None else warmup
-        if not 0 <= warmup < nt:
-            raise ValueError(f"need 0 <= warmup < nt, got {warmup}, {nt}")
+        nt, warmup = self._windows(nt, warmup)
         if self.grid.nprocs != 1:
             raise ValueError("single-shard fast paths require an unsharded grid")
         key = granularity_kw
         gran = effective_block_steps(
             nt, warmup, granularity, warn=explicit, label=key, stacklevel=4
         )
-
-        T, Cp = self.init_state()
         dt = cfg.jax_dtype(cfg.dt)
 
         kw = {key: gran}
@@ -1048,15 +724,7 @@ class HeatDiffusion:
             if cache_key is not None:
                 program_cache[cache_key] = advance
 
-        timer = metrics.Timer(label="step_window", phase="step",
-                              steps=nt - warmup, variant=key,
-                              workload="diffusion")
-        T = advance(T, Cp, warmup)  # n=0 still compiles the shared program
-        metrics.force(T)
-        with timer:
-            T = advance(T, Cp, nt - warmup)
-            wtime = timer.toc(T)
-        return RunResult(T=T, wtime=wtime, nt=nt, warmup=warmup, config=cfg)
+        return self._timed(advance, nt, warmup, variant=key)
 
     def run_vmem_resident(
         self,
@@ -1228,108 +896,25 @@ class HeatDiffusion:
         )["wire_mode"]
         return tuned if tuned is not None else self.config.wire_mode
 
-    def deep_advance_fn(
-        self,
-        block_steps: int | None = None,
-        nt: int | None = None,
-        warmup: int | None = None,
-        config: str | None = None,
-        wire_mode: str | None = None,
-    ):
-        """(jitted (T, Cp, n_steps) -> T, executed depth k) — the deep
-        schedule's advance as a first-class function, so callers beyond
-        run_deep (the --checkpoint segmented loop) can drive the sweep.
-        `n_steps` must be a multiple of k (the fori_loop trip count
-        floors) — the step-count convention every model's deep advance
-        shares (wave/swe match). `config="auto"` lets an unset
-        block_steps (and an unset wire_mode) consult the tuning cache
-        (effective_deep_depth / effective_wire_mode). For the stateful
-        wire modes the advance carries the exchange state internally
-        (zero-initialized per call — the first-sweep contract) and still
-        returns just T."""
+    def _deep_schedule(self, k: int, wire_mode: str):
+        """The deep-halo schedule (parallel.deep_halo.make_deep_sweep)
+        behind deep_advance_fn and run_deep. Works on any mesh (including
+        1 device, where it reduces to the VMEM-resident loop plus crop
+        overhead). f32/bf16 only on real TPUs (the local kernel is
+        Pallas). Default depth 32 — the measured single-chip optimum at
+        252² with the A/c kernel (r3: k=8 1.02 µs/step, k=16 0.889, k=32
+        0.848); on a pod slice larger k also divides the message count
+        further. Mid-size shards prefer the deepest VMEM-fitting depth;
+        HBM-resident shards cap the default at 8 (default_deep_depth)."""
         from rocm_mpi_tpu.parallel.deep_halo import make_deep_sweep
 
         cfg = self.config
         if cfg.halo_transport == "host":
             # The warning lives with the schedule builder so EVERY deep
             # caller (run_deep, the --checkpoint segmented loop) gets it.
-            warn_host_transport_ignored("deep", stacklevel=3)
-        k = self.effective_deep_depth(nt, warmup, block_steps,
-                                      config=config)
-        wm = self.effective_wire_mode(wire_mode, config)
-        dt = cfg.jax_dtype(cfg.dt)
-        sched = make_deep_sweep(self.grid, k, cfg.lam, dt, cfg.spacing,
-                                wire_mode=wm)
-
-        if sched.init_wire is None:
-
-            @functools.partial(jax.jit, donate_argnums=0)
-            def advance(T, Cp, n_steps):
-                # The time-invariant coefficient's width-k exchange +
-                # masking runs ONCE per compiled advance, outside the
-                # sweep loop — the loop carries only the bare field
-                # (DeepSchedule contract).
-                Cm = sched.prepare(Cp)
-                return lax.fori_loop(
-                    0, n_steps // k, lambda _, x: sched.sweep(x, Cm), T
-                )
-
-        else:
-
-            @functools.partial(jax.jit, donate_argnums=0)
-            def advance(T, Cp, n_steps):
-                Cm = sched.prepare(Cp)
-                ws0 = sched.init_wire(T.dtype)
-
-                def body(_, carry):
-                    T_, ws = carry
-                    return sched.sweep(T_, Cm, ws)
-
-                T_out, _ws = lax.fori_loop(
-                    0, n_steps // k, body, (T, ws0)
-                )
-                return T_out
-
-        return advance, k
-
-    def run_deep(
-        self,
-        nt: int | None = None,
-        warmup: int | None = None,
-        block_steps: int | None = None,
-        config: str | None = None,
-        wire_mode: str | None = None,
-    ) -> RunResult:
-        """Sharded fast path: deep-halo sweeps (parallel.deep_halo) — one
-        width-k ghost exchange per k steps, the multi-chip form of temporal
-        blocking. Works on any mesh (including 1 device, where it reduces
-        to the VMEM-resident loop plus crop overhead). f32/bf16 only on
-        real TPUs (the local kernel is Pallas). Default depth 32 — the
-        measured single-chip optimum at 252² with the A/c kernel (r3:
-        k=8 1.02 µs/step, k=16 0.889, k=32 0.848); on a pod slice larger
-        k also divides the message count further. Mid-size shards prefer
-        the deepest VMEM-fitting depth; HBM-resident shards cap the
-        default at 8 (default_deep_depth).
-        """
-        cfg = self.config
-        nt = cfg.nt if nt is None else nt
-        warmup = cfg.warmup if warmup is None else warmup
-        if not 0 <= warmup < nt:
-            raise ValueError(f"need 0 <= warmup < nt, got {warmup}, {nt}")
-        advance, _ = self.deep_advance_fn(
-            block_steps=block_steps, nt=nt, warmup=warmup, config=config,
-            wire_mode=wire_mode,
-        )
-        T, Cp = self.init_state()
-        timer = metrics.Timer(label="step_window", phase="step",
-                              steps=nt - warmup, variant="deep",
-                              workload="diffusion")
-        T = advance(T, Cp, warmup)
-        metrics.force(T)
-        with timer:
-            T = advance(T, Cp, nt - warmup)
-            wtime = timer.toc(T)
-        return RunResult(T=T, wtime=wtime, nt=nt, warmup=warmup, config=cfg)
+            warn_host_transport_ignored("deep", stacklevel=4)
+        return make_deep_sweep(self.grid, k, cfg.lam, cfg.jax_dtype(cfg.dt),
+                               cfg.spacing, wire_mode=wire_mode)
 
     def _run_host_staged(self, nt: int, warmup: int) -> RunResult:
         """Debug oracle: numpy stepper with host-staged halos

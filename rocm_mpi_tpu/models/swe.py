@@ -49,11 +49,10 @@ from jax import lax
 from rocm_mpi_tpu.utils.compat import shard_map
 
 from rocm_mpi_tpu.config import DTYPES
+from rocm_mpi_tpu.models.driver import Driver, Result, effective_block_steps
 from rocm_mpi_tpu.ops.diffusion import gaussian_ic
 from rocm_mpi_tpu.ops.swe_kernels import masked_swe_step, swe_coeffs
 from rocm_mpi_tpu.parallel.halo import exchange_halo
-from rocm_mpi_tpu.parallel.mesh import GlobalGrid, init_global_grid
-from rocm_mpi_tpu.utils import metrics
 
 
 @dataclasses.dataclass(frozen=True)
@@ -111,51 +110,27 @@ class SWEConfig:
 
 
 @dataclasses.dataclass
-class SWERunResult:
+class SWERunResult(Result):
     h: jax.Array
     us: tuple
-    wtime: float
-    nt: int
-    warmup: int
-    config: SWEConfig
 
     @property
-    def wtime_it(self) -> float:
-        return metrics.wtime_per_it(self.wtime, self.nt, self.warmup)
+    def field(self):
+        return self.h
 
     @property
-    def t_eff(self) -> float:
+    def n_passes(self) -> int:
         # 2·(ndim+1) whole-array passes per step: read + write each of the
         # h and u_a state fields (masks are coefficient traffic, excluded —
         # the same accounting stance as the diffusion T_eff formula).
-        return metrics.t_eff_gbs(
-            self.h.shape, self.h.dtype.itemsize, self.wtime_it,
-            n_passes=2 * (len(self.us) + 1),
-        )
-
-    @property
-    def gpts(self) -> float:
-        return metrics.gpts_per_s(self.h.shape, self.wtime_it)
+        return 2 * (len(self.us) + 1)
 
 
-class ShallowWater:
+class ShallowWater(Driver):
     """Forward-backward linear shallow water on a sharded global grid."""
 
-    def __init__(
-        self,
-        config: SWEConfig,
-        grid: GlobalGrid | None = None,
-        devices=None,
-    ):
-        self.config = config
-        if grid is None:
-            grid = init_global_grid(
-                *config.global_shape,
-                lengths=config.lengths,
-                dims=config.dims,
-                devices=devices,
-            )
-        self.grid = grid
+    workload = "swe"
+    n_state = 2
 
     def face_masks(self):
         """Per-axis face masks as data arrays: exactly 0.0 on the global
@@ -198,18 +173,25 @@ class ShallowWater:
 
         return make_h(), tuple(make_u() for _ in range(cfg.ndim))
 
-    def _step(self, variant: str, Mus):
-        """(h, us) -> (h', us')."""
+    def state_and_aux(self):
+        return self.init_state(), self.face_masks()
+
+    def _result(self, state, **window) -> SWERunResult:
+        return SWERunResult(h=state[0], us=state[1], **window)
+
+    def _step(self, variant: str):
+        """(`step((h, us), Mus) -> (h', us')`, None): the face masks `Mus`
+        are the step's operand as they are."""
         cfg, grid = self.config, self.grid
         dt = cfg.dt
         cH, cg = swe_coeffs(dt, cfg.spacing, cfg.H0, cfg.g)
 
         if variant == "ap":
 
-            def step(h, us):
-                return masked_swe_step(h, us, Mus, cH, cg)
+            def step(s, Mus):
+                return masked_swe_step(*s, Mus, cH, cg)
 
-            return step
+            return step, None
         if variant == "shard":
             # The explicit-decomposition jnp rung (the diffusion/wave
             # "shard" vocabulary): one exchange of the full state + the
@@ -218,7 +200,9 @@ class ShallowWater:
             # multi-tenant advance vmaps (docs/SERVING.md).
             from rocm_mpi_tpu.ops.swe_kernels import swe_step_padded
 
-            def step(h, us):
+            def step(s, Mus):
+                h, us = s
+
                 def local(hl, *rest):
                     uls, Ml = rest[: cfg.ndim], rest[cfg.ndim:]
                     Sp = tuple(
@@ -238,11 +222,13 @@ class ShallowWater:
                 )(h, *us, *Mus)
                 return outs[0], tuple(outs[1:])
 
-            return step
+            return step, None
         if variant == "perf":
             from rocm_mpi_tpu.ops.swe_kernels import swe_step_padded_pallas
 
-            def step(h, us):
+            def step(s, Mus):
+                h, us = s
+
                 def local(hl, *rest):
                     uls, Ml = rest[: cfg.ndim], rest[cfg.ndim:]
                     Sp = tuple(
@@ -263,7 +249,7 @@ class ShallowWater:
                 )(h, *us, *Mus)
                 return outs[0], tuple(outs[1:])
 
-            return step
+            return step, None
         if variant == "hide":
             from rocm_mpi_tpu.ops.swe_kernels import swe_step_padded_pallas
             from rocm_mpi_tpu.parallel.overlap import make_overlap_step
@@ -271,7 +257,7 @@ class ShallowWater:
             if grid.nprocs == 1:
                 # No neighbors → nothing to hide (same routing policy as
                 # the diffusion and wave models' single-device hide).
-                return self._step("perf", Mus)
+                return self._step("perf")
 
             def pu(Sp, Ml, lam, dt_, spacing):
                 del lam
@@ -286,7 +272,9 @@ class ShallowWater:
                 wire_mode=cfg.wire_mode,
             )
 
-            def step(h, us):
+            def step(s, Mus):
+                h, us = s
+
                 def shard_fn(hl, *rest):
                     uls, Ml = rest[: cfg.ndim], rest[cfg.ndim:]
                     return local(
@@ -303,31 +291,15 @@ class ShallowWater:
                 )(h, *us, *Mus)
                 return outs[0], tuple(outs[1:])
 
-            return step
+            return step, None
         raise ValueError(
             f"unknown SWE variant {variant!r} (ap, shard, perf, hide)"
         )
 
     # ---- multi-tenant batching (docs/SERVING.md) ------------------------
 
-    def make_batched_grid(self, batch: int, batch_dims: int = 1,
-                          devices=None):
-        """Space×batch mesh for `batch` lanes of this model's space
-        problem (see HeatDiffusion.make_batched_grid)."""
-        from rocm_mpi_tpu.parallel.mesh import init_batched_grid
-
-        cfg = self.config
-        return init_batched_grid(
-            batch,
-            *cfg.global_shape,
-            lengths=cfg.lengths,
-            space_dims=self.grid.dims,
-            batch_dims=batch_dims,
-            devices=devices,
-        )
-
     def _make_batched_step(self, bgrid, variant: str):
-        """(`step(hb, usb, Mus) -> (hb', usb')`, prepare-or-None) over
+        """(`step((hb, usb), Mus) -> (hb', usb')`, prepare-or-None) over
         lane-batched SWE state; the face masks `Mus` are UNBATCHED
         (wall geometry is config-derived, shared by every lane). Same
         vocabulary (and return convention) as
@@ -341,11 +313,11 @@ class ShallowWater:
 
         if variant == "ap":
 
-            def step(hb, usb, Mus):
+            def step(s, Mus):
                 return jax.vmap(
                     lambda h, us: masked_swe_step(h, us, Mus, cH, cg),
                     in_axes=(0, 0),
-                )(hb, usb)
+                )(*s)
 
             return step, None
 
@@ -372,7 +344,8 @@ class ShallowWater:
 
             return jax.vmap(lane)(*padded)
 
-        def step(hb, usb, Mus):
+        def step(s, Mus):
+            hb, usb = s
             outs = shard_map(
                 lane_local,
                 mesh=bgrid.mesh,
@@ -385,144 +358,6 @@ class ShallowWater:
 
         return step, None
 
-    def batched_advance_fn(
-        self,
-        batch: int | None = None,
-        variant: str = "shard",
-        bgrid=None,
-        batch_dims: int = 1,
-        devices=None,
-    ):
-        """(jitted `advance(hb, usb, Mus, lane_steps, n) -> (hb, usb)`,
-        bgrid) — the SWE edition of the multi-tenant batched advance
-        (HeatDiffusion.batched_advance_fn has the lane_steps/bitwise
-        contract; every state field freezes together when a lane's count
-        is reached). Donates (hb, usb) — aliasing proven from the
-        compiled program by analysis/lowered.audit_batched_drivers."""
-        if bgrid is None:
-            if batch is None:
-                raise ValueError("pass batch= or a prebuilt bgrid=")
-            bgrid = self.make_batched_grid(batch, batch_dims, devices)
-        step, _ = self._make_batched_step(bgrid, variant)
-        shape1 = (-1,) + (1,) * bgrid.space.ndim
-
-        @functools.partial(jax.jit, donate_argnums=(0, 1))
-        def advance(hb, usb, Mus, lane_steps, n):
-            def body(i, s):
-                h, us = s
-                nh, nus = step(h, us, Mus)
-                active = (i < lane_steps).reshape(shape1)
-                return (
-                    jnp.where(active, nh, h),
-                    tuple(
-                        jnp.where(active, nu, u)
-                        for nu, u in zip(nus, us)
-                    ),
-                )
-
-            return lax.fori_loop(0, n, body, (hb, usb))
-
-        return advance, bgrid
-
-    def advance_fn(self, variant: str = "perf"):
-        """jitted (h, us, Mus, n) -> (h, us) after n steps."""
-
-        @functools.partial(jax.jit, donate_argnums=(0, 1))
-        def advance(h, us, Mus, n):
-            step = self._step(variant, Mus)
-            return lax.fori_loop(
-                0, n, lambda _, s: step(s[0], s[1]), (h, us)
-            )
-
-        return advance
-
-    def scan_advance_fn(
-        self,
-        variant: str = "perf",
-        nt: int | None = None,
-        warmup: int | None = None,
-        chunk: int | None = None,
-        config: str | None = None,
-    ):
-        """(jitted (h, us, Mus, n) -> (h, us), chunk q) — the
-        donation-aware scan driver, SWE edition (see
-        HeatDiffusion.scan_advance_fn): the whole coupled state pytree is
-        the scan carry and every state leaf is donated; the masks ride
-        along undonated (they are read-only data). `n` must be a multiple
-        of q. `config="auto"` gcd's an unset chunk from the tuning cache
-        (op "swe.scan" — see the diffusion edition's contract)."""
-        from rocm_mpi_tpu.models.diffusion import (
-            auto_scan_chunk,
-            effective_block_steps,
-        )
-
-        cfg = self.config
-        nt_v = cfg.nt if nt is None else nt
-        wu_v = cfg.warmup if warmup is None else warmup
-        explicit = chunk is not None
-        if not explicit:
-            chunk = auto_scan_chunk("swe.scan", self.grid, cfg.jax_dtype,
-                                    config)
-        q = effective_block_steps(
-            nt_v, wu_v, (nt_v - wu_v) if chunk is None else chunk,
-            label="SWE scan driver chunk", warn=explicit,
-        )
-
-        @functools.partial(jax.jit, donate_argnums=(0, 1))
-        def advance(h, us, Mus, n):
-            step = self._step(variant, Mus)
-
-            def q_steps(carry, _):
-                return step(carry[0], carry[1]), None
-
-            def body(_, carry):
-                carry, _ = lax.scan(q_steps, carry, xs=None, length=q)
-                return carry
-
-            return lax.fori_loop(0, n // q, body, (h, us))
-
-        return advance, q
-
-    def _run_timed(self, advance, nt, warmup) -> SWERunResult:
-        """Shared scaffold: warmup-advance / tic / advance / toc (the
-        framework's timing protocol; `advance(h, us, Mus, n)` must serve
-        both windows with one compiled program)."""
-        cfg = self.config
-        nt = cfg.nt if nt is None else nt
-        warmup = cfg.warmup if warmup is None else warmup
-        if not 0 <= warmup < nt:
-            raise ValueError(f"need 0 <= warmup < nt, got {warmup}, {nt}")
-        h, us = self.init_state()
-        Mus = self.face_masks()
-        timer = metrics.Timer(label="step_window", phase="step",
-                              steps=nt - warmup, workload="swe")
-        h, us = advance(h, us, Mus, warmup)
-        metrics.force(h)
-        with timer:
-            h, us = advance(h, us, Mus, nt - warmup)
-            wtime = timer.toc(h)
-        return SWERunResult(
-            h=h, us=us, wtime=wtime, nt=nt, warmup=warmup, config=cfg
-        )
-
-    def run(
-        self, variant: str = "perf",
-        nt: int | None = None, warmup: int | None = None,
-        driver: str = "step", config: str | None = None,
-    ) -> SWERunResult:
-        """`driver="scan"` routes to the donation-aware scan driver
-        (scan_advance_fn); "step" keeps the per-step fori_loop. Same step
-        program either way — results are bitwise identical.
-        `config="auto"` lets the scan chunk consult the tuning cache."""
-        if driver not in ("step", "scan"):
-            raise ValueError(f"driver must be 'step' or 'scan', got {driver!r}")
-        if driver == "scan":
-            advance, _ = self.scan_advance_fn(variant, nt=nt, warmup=warmup,
-                                              config=config)
-        else:
-            advance = self.advance_fn(variant)
-        return self._run_timed(advance, nt, warmup)
-
     def run_vmem_resident(
         self, nt: int | None = None, warmup: int | None = None,
         chunk: int | None = None, config: str | None = None,
@@ -532,7 +367,6 @@ class ShallowWater:
         (ops.swe_kernels.swe_multi_step). `config="auto"` fills an unset
         chunk from the tuning cache (op "swe.vmem_loop"), resolved here
         outside any trace and gcd'd against the windows."""
-        from rocm_mpi_tpu.models.diffusion import effective_block_steps
         from rocm_mpi_tpu.ops.pallas_kernels import DEFAULT_STEP_CHUNK
         from rocm_mpi_tpu.ops.swe_kernels import swe_multi_step
 
@@ -572,7 +406,7 @@ class ShallowWater:
                 chunk=eff_chunk, warn_on_cap=False,
             )
 
-        return self._run_timed(advance, nt, warmup)
+        return self._timed(advance, nt, warmup, variant="vmem")
 
     DEFAULT_DEEP_STEPS = 8
 
@@ -588,8 +422,6 @@ class ShallowWater:
         ADVICE r3: a DEFAULT depth clamps to the shard, an EXPLICIT one is
         gcd-degraded against the windows and then raises if it still
         exceeds the shard)."""
-        from rocm_mpi_tpu.models.diffusion import effective_block_steps
-
         cfg = self.config
         explicit = block_steps is not None
         if block_steps is None:
@@ -612,70 +444,16 @@ class ShallowWater:
             )
         return eff
 
-    def deep_advance_fn(
-        self,
-        block_steps: int | None = None,
-        nt: int | None = None,
-        warmup: int | None = None,
-        wire_mode: str | None = None,
-    ):
-        """(jitted (h, us, Mus, n_steps) -> (h, us), executed depth k) —
-        the SWE deep schedule's advance as a first-class function
-        (HeatDiffusion.deep_advance_fn); `n_steps` must be a multiple of
-        k (the fori_loop trip count floors). Mus is accepted and ignored
-        so the signature matches advance_fn's (deep sweeps build padded
-        masks internally). `wire_mode` overrides the config's on-wire
-        precision; stateful modes carry the exchange state internally."""
+    def _deep_schedule(self, k: int, wire_mode: str):
+        """The SWE deep schedule (parallel.deep_halo.make_swe_deep_sweep):
+        ONE width-k ghost exchange of the whole coupled state per k
+        steps. Its `prepare` builds the padded face masks from the
+        state's dtype and sharding (`_deep_operand`), not from `Mus`."""
         from rocm_mpi_tpu.parallel.deep_halo import make_swe_deep_sweep
 
         cfg = self.config
-        k = self.effective_deep_depth(nt, warmup, block_steps)
-        wm = cfg.wire_mode if wire_mode is None else wire_mode
-        sched = make_swe_deep_sweep(
-            self.grid, k, cfg.dt, cfg.spacing, cfg.H0, cfg.g,
-            wire_mode=wm,
-        )
+        return make_swe_deep_sweep(self.grid, k, cfg.dt, cfg.spacing,
+                                   cfg.H0, cfg.g, wire_mode=wire_mode)
 
-        if sched.init_wire is None:
-
-            @functools.partial(jax.jit, donate_argnums=(0, 1))
-            def advance(h, us, Mus, n):
-                del Mus
-                # The padded face masks are geometry-only: built ONCE per
-                # compiled advance (DeepSchedule.prepare), not inside every
-                # sweep — the loop carries only the coupled state.
-                Mp = sched.prepare(h)
-                return lax.fori_loop(
-                    0, n // k, lambda _, s: sched.sweep(s[0], s[1], Mp),
-                    (h, us),
-                )
-
-        else:
-
-            @functools.partial(jax.jit, donate_argnums=(0, 1))
-            def advance(h, us, Mus, n):
-                del Mus
-                Mp = sched.prepare(h)
-                ws0 = sched.init_wire(h.dtype)
-                out = lax.fori_loop(
-                    0, n // k,
-                    lambda _, s: sched.sweep(s[0], s[1], Mp, s[2]),
-                    (h, us, ws0),
-                )
-                return out[0], out[1]
-
-        return advance, k
-
-    def run_deep(
-        self,
-        nt: int | None = None,
-        warmup: int | None = None,
-        block_steps: int | None = None,
-        wire_mode: str | None = None,
-    ) -> SWERunResult:
-        """Sharded fast path: deep-halo sweeps — ONE width-k ghost
-        exchange of the whole coupled state per k steps
-        (parallel.deep_halo.make_swe_deep_sweep)."""
-        advance, _ = self.deep_advance_fn(block_steps, nt, warmup,
-                                          wire_mode=wire_mode)
-        return self._run_timed(advance, nt, warmup)
+    def _deep_operand(self, state, aux):
+        return state[0]

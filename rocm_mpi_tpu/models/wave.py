@@ -42,17 +42,15 @@ import math
 
 import jax
 import jax.numpy as jnp
-from jax import lax
 
 from rocm_mpi_tpu.utils.compat import shard_map
 
 from rocm_mpi_tpu.config import DTYPES
+from rocm_mpi_tpu.models.driver import Driver, Result, effective_block_steps
 from rocm_mpi_tpu.ops.diffusion import gaussian_ic
 from rocm_mpi_tpu.ops.stencil import inn
 from rocm_mpi_tpu.ops.wave_kernels import wave_step_padded  # noqa: F401  (re-export)
 from rocm_mpi_tpu.parallel.halo import exchange_halo, global_boundary_mask
-from rocm_mpi_tpu.parallel.mesh import GlobalGrid, init_global_grid
-from rocm_mpi_tpu.utils import metrics
 
 
 @dataclasses.dataclass(frozen=True)
@@ -113,47 +111,21 @@ def wave_step_fused(U, Uprev, C2, dt, spacing):
 
 
 @dataclasses.dataclass
-class WaveRunResult:
+class WaveRunResult(Result):
     U: jax.Array
-    wtime: float
-    nt: int
-    warmup: int
-    config: WaveConfig
+    # 4 whole-array passes per step: read U, U_prev, C2; write U⁺.
+    n_passes = 4
 
     @property
-    def wtime_it(self) -> float:
-        return metrics.wtime_per_it(self.wtime, self.nt, self.warmup)
-
-    @property
-    def t_eff(self) -> float:
-        # 4 whole-array passes per step: read U, U_prev, C2; write U⁺.
-        return metrics.t_eff_gbs(
-            self.U.shape, self.U.dtype.itemsize, self.wtime_it, n_passes=4
-        )
-
-    @property
-    def gpts(self) -> float:
-        return metrics.gpts_per_s(self.U.shape, self.wtime_it)
+    def field(self):
+        return self.U
 
 
-class AcousticWave:
+class AcousticWave(Driver):
     """Leapfrog acoustic wave on a sharded global grid."""
 
-    def __init__(
-        self,
-        config: WaveConfig,
-        grid: GlobalGrid | None = None,
-        devices=None,
-    ):
-        self.config = config
-        if grid is None:
-            grid = init_global_grid(
-                *config.global_shape,
-                lengths=config.lengths,
-                dims=config.dims,
-                devices=devices,
-            )
-        self.grid = grid
+    workload = "wave"
+    n_state = 2
 
     def init_state(self):
         """(U, U_prev, C2): Gaussian displacement at rest, uniform c²."""
@@ -174,6 +146,13 @@ class AcousticWave:
 
         U = make_U()
         return U, jnp.copy(U), make_C2()
+
+    def state_and_aux(self):
+        U, Uprev, C2 = self.init_state()
+        return (U, Uprev), C2
+
+    def _result(self, state, **window) -> WaveRunResult:
+        return WaveRunResult(U=state[0], **window)
 
     def _mask_prepare(self):
         """prepare(C2) -> (M, Cw): the interior mask (1.0 on updating
@@ -196,16 +175,16 @@ class AcousticWave:
         return prepare
 
     def _step(self, variant: str):
-        """(step, prepare): `step(U, Uprev, C2, P) -> (U⁺, U)` with `P`
-        the loop-invariant operands `prepare(C2)` builds once per jitted
-        program (None for variants that need none)."""
+        """(step, prepare): `step((U, Uprev), P) -> (U⁺, U)` with `P` the
+        loop-invariant operands `prepare(C2)` builds once per jitted
+        program — C2 itself for the variants with no prepare."""
         cfg, grid = self.config, self.grid
         dt = cfg.jax_dtype(cfg.dt)
 
         if variant == "ap":
 
-            def step(U, Uprev, C2, P):
-                del P
+            def step(s, C2):
+                U, Uprev = s
                 return wave_step_fused(U, Uprev, C2, dt, cfg.spacing), U
 
             return step, None
@@ -217,8 +196,8 @@ class AcousticWave:
             # per-lane body the batched multi-tenant advance vmaps
             # (docs/SERVING.md: batched results must be bitwise-equal to
             # a standalone run of the SAME op sequence).
-            def step(U, Uprev, C2, P):
-                del P
+            def step(s, C2):
+                U, Uprev = s
 
                 def local(Ul, Upl, C2l):
                     pad = exchange_halo(Ul, grid, wire_mode=cfg.wire_mode)
@@ -238,8 +217,8 @@ class AcousticWave:
         if variant == "perf":
             from rocm_mpi_tpu.ops.wave_kernels import wave_step_padded_pallas
 
-            def step(U, Uprev, C2, P):
-                del P
+            def step(s, C2):
+                U, Uprev = s
 
                 def local(Ul, Upl, C2l):
                     pad = exchange_halo(Ul, grid,
@@ -291,7 +270,8 @@ class AcousticWave:
                 wire_mode=cfg.wire_mode,
             )
 
-            def step(U, Uprev, C2, P):
+            def step(s, P):
+                U, Uprev = s
                 M, Cw = P
                 new = shard_map(
                     lambda Ul, Upl, Ml, Cwl: local(
@@ -311,24 +291,8 @@ class AcousticWave:
 
     # ---- multi-tenant batching (docs/SERVING.md) ------------------------
 
-    def make_batched_grid(self, batch: int, batch_dims: int = 1,
-                          devices=None):
-        """Space×batch mesh for `batch` lanes of this model's space
-        problem (see HeatDiffusion.make_batched_grid)."""
-        from rocm_mpi_tpu.parallel.mesh import init_batched_grid
-
-        cfg = self.config
-        return init_batched_grid(
-            batch,
-            *cfg.global_shape,
-            lengths=cfg.lengths,
-            space_dims=self.grid.dims,
-            batch_dims=batch_dims,
-            devices=devices,
-        )
-
     def _make_batched_step(self, bgrid, variant: str):
-        """(`step(Ub, Upb, C2) -> (Ub⁺, Ub)`, prepare-or-None) over
+        """(`step((Ub, Upb), C2) -> (Ub⁺, Ub)`, prepare-or-None) over
         lane-batched leapfrog state; `C2` is the UNBATCHED squared wave
         speed every lane shares. Same vocabulary (and return
         convention) as HeatDiffusion._make_batched_step."""
@@ -340,7 +304,8 @@ class AcousticWave:
 
         if variant == "ap":
 
-            def step(Ub, Upb, C2):
+            def step(s, C2):
+                Ub, Upb = s
                 new = jax.vmap(
                     lambda U, Up: wave_step_fused(U, Up, C2, dt,
                                                   cfg.spacing)
@@ -367,7 +332,8 @@ class AcousticWave:
 
             return jax.vmap(lane)(Ub_l, Upb_l, pad)
 
-        def step(Ub, Upb, C2):
+        def step(s, C2):
+            Ub, Upb = s
             new = shard_map(
                 lane_local,
                 mesh=bgrid.mesh,
@@ -379,71 +345,18 @@ class AcousticWave:
 
         return step, None
 
-    def batched_advance_fn(
-        self,
-        batch: int | None = None,
-        variant: str = "shard",
-        bgrid=None,
-        batch_dims: int = 1,
-        devices=None,
-    ):
-        """(jitted `advance(Ub, Upb, C2, lane_steps, n) -> (Ub, Upb)`,
-        bgrid) — the wave edition of the multi-tenant batched advance
-        (HeatDiffusion.batched_advance_fn has the lane_steps/bitwise
-        contract; both leapfrog carries freeze together when a lane's
-        count is reached). Donates (Ub, Upb) — aliasing proven from the
-        compiled program by analysis/lowered.audit_batched_drivers."""
-        if bgrid is None:
-            if batch is None:
-                raise ValueError("pass batch= or a prebuilt bgrid=")
-            bgrid = self.make_batched_grid(batch, batch_dims, devices)
-        step, _ = self._make_batched_step(bgrid, variant)
-        shape1 = (-1,) + (1,) * bgrid.space.ndim
-
-        @functools.partial(jax.jit, donate_argnums=(0, 1))
-        def advance(Ub, Upb, C2, lane_steps, n):
-            def body(i, s):
-                U, Up = s
-                newU, newUp = step(U, Up, C2)
-                active = (i < lane_steps).reshape(shape1)
-                return (
-                    jnp.where(active, newU, U),
-                    jnp.where(active, newUp, Up),
-                )
-
-            return lax.fori_loop(0, n, body, (Ub, Upb))
-
-        return advance, bgrid
-
-    def batched_ladder_advance_fn(
-        self,
-        batch: int | None = None,
-        bgrid=None,
-        batch_dims: int = 1,
-        devices=None,
-    ):
-        """(jitted `advance(Ub, Upb, C2, hold, dt2, inv_d2, lane_steps,
-        n) -> (Ub, Upb)`, bgrid) — the wave edition of the LADDER
-        batched advance (HeatDiffusion.batched_ladder_advance_fn has the
-        full contract): per-lane `hold` masks (original Dirichlet ring +
-        out-of-domain padding), per-lane `dt2` = dt² (batch,) and
-        `inv_d2` = a TUPLE of ndim per-axis (batch,) 1/spacing²
-        operands, precomputed host-side in f64 from each lane's
-        ORIGINAL-shape config (ops.wave_kernels.wave_step_padded_geom;
-        per-axis scalars, not an indexed vector — the diffusion
-        edition's fori-fusion ulp note applies here too). Both leapfrog
-        carries freeze together under `hold` exactly as under
-        `lane_steps`. Donates (Ub, Upb)."""
+    def _ladder_step(self, bgrid):
+        """The ladder's batched step, `step(state, (C2, hold, dt2,
+        inv_d2)) -> (U⁺, U)`: `dt2` the per-lane dt², and `inv_d2`
+        precomputed host-side in f64 from each lane's ORIGINAL-shape
+        config (ops.wave_kernels.wave_step_padded_geom; per-axis scalars,
+        not an indexed vector — the diffusion edition's ulp note applies
+        here too; batched_ladder_advance_fn)."""
         from rocm_mpi_tpu.ops.wave_kernels import wave_step_padded_geom
         from rocm_mpi_tpu.parallel.halo import exchange_halo_batched
 
-        if bgrid is None:
-            if batch is None:
-                raise ValueError("pass batch= or a prebuilt bgrid=")
-            bgrid = self.make_batched_grid(batch, batch_dims, devices)
         cfg = self.config
         ndim = bgrid.space.ndim
-        shape1 = (-1,) + (1,) * ndim
 
         def lane_local(Ub_l, Upb_l, C2l, Hb_l, dt2_l, *invd2_l):
             pad = exchange_halo_batched(Ub_l, bgrid,
@@ -465,122 +378,7 @@ class AcousticWave:
             out_specs=bgrid.spec,
             check_vma=False,
         )
-
-        @functools.partial(jax.jit, donate_argnums=(0, 1))
-        def advance(Ub, Upb, C2, hold, dt2, inv_d2, lane_steps, n):
-            def body(i, s):
-                U, Up = s
-                newU = inner(U, Up, C2, hold, dt2, *inv_d2)
-                active = (i < lane_steps).reshape(shape1)
-                return (
-                    jnp.where(active, newU, U),
-                    jnp.where(active, U, Up),
-                )
-
-            return lax.fori_loop(0, n, body, (Ub, Upb))
-
-        return advance, bgrid
-
-    def advance_fn(self, variant: str = "perf"):
-        """jitted (U, Uprev, C2, n) -> (U after n steps, U after n-1)."""
-        step, prep = self._step(variant)
-
-        @functools.partial(jax.jit, donate_argnums=(0, 1))
-        def advance(U, Uprev, C2, n):
-            P = None if prep is None else prep(C2)
-            return lax.fori_loop(
-                0, n, lambda _, s: step(s[0], s[1], C2, P), (U, Uprev)
-            )
-
-        return advance
-
-    def scan_advance_fn(
-        self,
-        variant: str = "perf",
-        nt: int | None = None,
-        warmup: int | None = None,
-        chunk: int | None = None,
-        config: str | None = None,
-    ):
-        """(jitted (U, Uprev, C2, n) -> (U, Uprev), chunk q) — the
-        donation-aware scan driver, wave edition (see
-        HeatDiffusion.scan_advance_fn): the state pair is the scan carry
-        (XLA's double buffer — the leapfrog's natural `U, U⁻ = U⁺, U`
-        swap) and both leaves are donated. `n` must be a multiple of q.
-        `config="auto"` gcd's an unset chunk from the tuning cache (op
-        "wave.scan" — see the diffusion edition's contract)."""
-        from rocm_mpi_tpu.models.diffusion import (
-            auto_scan_chunk,
-            effective_block_steps,
-        )
-
-        cfg = self.config
-        step, prep = self._step(variant)
-        nt_v = cfg.nt if nt is None else nt
-        wu_v = cfg.warmup if warmup is None else warmup
-        explicit = chunk is not None
-        if not explicit:
-            chunk = auto_scan_chunk("wave.scan", self.grid, cfg.jax_dtype,
-                                    config)
-        q = effective_block_steps(
-            nt_v, wu_v, (nt_v - wu_v) if chunk is None else chunk,
-            label="wave scan driver chunk", warn=explicit,
-        )
-
-        @functools.partial(jax.jit, donate_argnums=(0, 1))
-        def advance(U, Uprev, C2, n):
-            P = None if prep is None else prep(C2)
-
-            def q_steps(carry, _):
-                return step(carry[0], carry[1], C2, P), None
-
-            def body(_, carry):
-                carry, _ = lax.scan(q_steps, carry, xs=None, length=q)
-                return carry
-
-            return lax.fori_loop(0, n // q, body, (U, Uprev))
-
-        return advance, q
-
-    def _run_timed(self, advance, nt, warmup) -> WaveRunResult:
-        """Shared run scaffold: validate the windows, init, then
-        warmup-advance / tic / advance / toc (the same protocol as the
-        diffusion runners; `advance(U, Uprev, C2, n) -> (U, Uprev)` must
-        serve both windows with one compiled program)."""
-        cfg = self.config
-        nt = cfg.nt if nt is None else nt
-        warmup = cfg.warmup if warmup is None else warmup
-        if not 0 <= warmup < nt:
-            raise ValueError(f"need 0 <= warmup < nt, got {warmup}, {nt}")
-        U, Uprev, C2 = self.init_state()
-        timer = metrics.Timer(label="step_window", phase="step",
-                              steps=nt - warmup, workload="wave")
-        U, Uprev = advance(U, Uprev, C2, warmup)
-        metrics.force(U)
-        with timer:
-            U, Uprev = advance(U, Uprev, C2, nt - warmup)
-            wtime = timer.toc(U)
-        return WaveRunResult(
-            U=U, wtime=wtime, nt=nt, warmup=warmup, config=cfg
-        )
-
-    def run(
-        self, variant: str = "perf",
-        nt: int | None = None, warmup: int | None = None,
-        driver: str = "step", config: str | None = None,
-    ) -> WaveRunResult:
-        """`driver="scan"` routes to the donation-aware scan driver
-        (scan_advance_fn); "step" keeps the per-step fori_loop. Same step
-        program either way — results are bitwise identical.
-        `config="auto"` lets the scan chunk consult the tuning cache."""
-        if driver not in ("step", "scan"):
-            raise ValueError(f"driver must be 'step' or 'scan', got {driver!r}")
-        if driver == "scan":
-            advance, _ = self.scan_advance_fn(variant, nt=nt, warmup=warmup,
-                                              config=config)
-        else:
-            advance = self.advance_fn(variant)
-        return self._run_timed(advance, nt, warmup)
+        return lambda s, P: (inner(*s, P[0], P[1], P[2], *P[3]), s[0])
 
     def run_vmem_resident(
         self, nt: int | None = None, warmup: int | None = None,
@@ -595,7 +393,6 @@ class AcousticWave:
         tuning cache (op "wave.vmem_loop") — resolved here, outside any
         trace, then gcd'd against the windows like every granularity.
         """
-        from rocm_mpi_tpu.models.diffusion import effective_block_steps
         from rocm_mpi_tpu.ops.pallas_kernels import DEFAULT_STEP_CHUNK
         from rocm_mpi_tpu.ops.wave_kernels import wave_multi_step
 
@@ -633,7 +430,7 @@ class AcousticWave:
                 warn_on_cap=False,
             )
 
-        return self._run_timed(advance, nt, warmup)
+        return self._timed(advance, nt, warmup, variant="vmem")
 
     DEFAULT_DEEP_STEPS = 8
 
@@ -654,8 +451,6 @@ class AcousticWave:
         EFFECTIVE depth still exceeds the shard — the strict validation
         make_wave_deep_sweep applies, surfaced before any compile.
         """
-        from rocm_mpi_tpu.models.diffusion import effective_block_steps
-
         cfg = self.config
         explicit = block_steps is not None
         if block_steps is None:
@@ -678,69 +473,14 @@ class AcousticWave:
             )
         return eff
 
-    def deep_advance_fn(
-        self,
-        block_steps: int | None = None,
-        nt: int | None = None,
-        warmup: int | None = None,
-        wire_mode: str | None = None,
-    ):
-        """(jitted (U, Uprev, C2, n_steps) -> (U, Uprev), executed depth
-        k) — the wave deep schedule's advance as a first-class function
-        (HeatDiffusion.deep_advance_fn); `n_steps` must be a multiple of
-        k (the fori_loop trip count floors). `wire_mode` overrides the
-        config's on-wire precision; the stateful modes carry the
-        exchange state internally (zero-initialized per call)."""
+    def _deep_schedule(self, k: int, wire_mode: str):
+        """The wave deep schedule (parallel.deep_halo
+        .make_wave_deep_sweep): one width-k ghost exchange of the
+        leapfrog state pair per k steps, the second workload on the
+        flagship multi-chip schedule; the time-invariant c² is exchanged
+        and masked ONCE per compiled advance (DeepSchedule.prepare)."""
         from rocm_mpi_tpu.parallel.deep_halo import make_wave_deep_sweep
 
         cfg = self.config
-        k = self.effective_deep_depth(nt, warmup, block_steps)
-        dt = cfg.jax_dtype(cfg.dt)
-        wm = cfg.wire_mode if wire_mode is None else wire_mode
-        sched = make_wave_deep_sweep(self.grid, k, dt, cfg.spacing,
-                                     wire_mode=wm)
-
-        if sched.init_wire is None:
-
-            @functools.partial(jax.jit, donate_argnums=(0, 1))
-            def advance(U, Uprev, C2, n):
-                # The time-invariant c² is exchanged + masked ONCE per
-                # compiled advance (DeepSchedule.prepare), not inside
-                # every sweep — the loop carries only the leapfrog
-                # state pair.
-                P = sched.prepare(C2)
-                return lax.fori_loop(
-                    0, n // k, lambda _, s: sched.sweep(s[0], s[1], P),
-                    (U, Uprev),
-                )
-
-        else:
-
-            @functools.partial(jax.jit, donate_argnums=(0, 1))
-            def advance(U, Uprev, C2, n):
-                P = sched.prepare(C2)
-                ws0 = sched.init_wire(U.dtype)
-                out = lax.fori_loop(
-                    0, n // k,
-                    lambda _, s: sched.sweep(s[0], s[1], P, s[2]),
-                    (U, Uprev, ws0),
-                )
-                return out[0], out[1]
-
-        return advance, k
-
-    def run_deep(
-        self,
-        nt: int | None = None,
-        warmup: int | None = None,
-        block_steps: int | None = None,
-        wire_mode: str | None = None,
-    ) -> WaveRunResult:
-        """Sharded fast path: deep-halo sweeps for the wave — one width-k
-        ghost exchange of the leapfrog state pair per k steps
-        (parallel.deep_halo.make_wave_deep_sweep), the second workload on
-        the flagship multi-chip schedule (HeatDiffusion.run_deep).
-        """
-        advance, _ = self.deep_advance_fn(block_steps, nt, warmup,
-                                          wire_mode=wire_mode)
-        return self._run_timed(advance, nt, warmup)
+        return make_wave_deep_sweep(self.grid, k, cfg.jax_dtype(cfg.dt),
+                                    cfg.spacing, wire_mode=wire_mode)

@@ -249,18 +249,22 @@ def _to_global(np_arr, sharding):
 class _Program:
     """One compiled program class: the batched advance bound to its
     space×batch grid, plus the cached base state the lanes scale.
-    `base_dev` are the workload's standard-IC state leaves ON DEVICE
-    (space-sharded); `base_np` their host copies (single-controller
-    only — the lane-assembly fast path); `init` the lazily-jitted
-    device-side lane initializer (scales → batched leaves) the
-    multi-controller path uses instead."""
+    `base_dev` are the leaves of the workload's standard-IC state ON
+    DEVICE (space-sharded) and `state_def` the state's structure, which
+    the adapter rebuilds the advance's state arguments from; `base_np`
+    their host copies (single-controller only — the lane-assembly fast
+    path); `init` the lazily-jitted device-side lane initializer
+    (scales → batched leaves) the multi-controller path uses instead."""
 
-    def __init__(self, advance, bgrid, aux, base_dev, adapter,
+    def __init__(self, advance, bgrid, aux, base_state, adapter,
                  ladder: bool = False):
+        import jax
+
         self.advance = advance
         self.bgrid = bgrid
-        self.aux = aux  # device aux operand(s), lane-shared
-        self.base_dev = tuple(base_dev)
+        self.aux = aux  # device aux operand, lane-shared
+        leaves, self.state_def = jax.tree.flatten(tuple(base_state))
+        self.base_dev = tuple(leaves)
         self.adapter = adapter
         # Ladder program: the advance takes per-lane geometry operands
         # (hold mask, dt terms, spacing terms) so lanes of different
@@ -343,16 +347,18 @@ class _Program:
 
 
 class _Adapter:
-    """Per-workload glue: config/model construction, the batched
-    advance's calling convention, and the state-leaf layout (the
-    session-checkpoint pytree is exactly `leaves`)."""
+    """Per-workload glue: the config a BinKey names, the model, and —
+    for the ladder — the per-lane geometry. Everything else is written
+    once here over the model's state/aux split
+    (`Driver.state_and_aux`, models/driver.py): the session-checkpoint
+    pytree is exactly the state's leaves."""
 
     name: str = ""
     # Ladder support (docs/SERVING.md "Continuous batching"): a
-    # workload opts in by providing build_ladder/run_ladder/
-    # ladder_state_leaves/ladder_geom. SWE stays out — its face-mask
-    # aux is geometry-DEPENDENT (per-axis staggered masks derived from
-    # the exact domain), so embedded lanes cannot share one aux.
+    # workload opts in by providing ladder_geom. SWE stays out — its
+    # face-mask aux is geometry-DEPENDENT (per-axis staggered masks
+    # derived from the exact domain), so embedded lanes cannot share
+    # one aux.
     supports_ladder: bool = False
 
     def make_config(self, key: BinKey, space_dims):
@@ -361,41 +367,64 @@ class _Adapter:
     def make_model(self, cfg):
         raise NotImplementedError
 
-    def build(self, model, width, batch_dims, bgrid=None):
-        """-> (advance, bgrid, aux_device, base_leaves_numpy)."""
-        raise NotImplementedError
-
-    def run(self, prog: _Program, leaves_dev, lane_steps_dev, n):
-        """-> tuple of advanced state leaves (device)."""
-        raise NotImplementedError
-
-    def build_ladder(self, model, width, batch_dims):
-        """-> (ladder advance, bgrid, aux_device, base_leaves) for the
-        RUNG-shaped model (per-lane geometry rides operands)."""
-        raise NotImplementedError(f"{self.name} has no ladder support")
-
-    def run_ladder(self, prog: _Program, leaves_dev, hold_dev, a_dev,
-                   g_dev, lane_steps_dev, n):
-        """-> advanced state leaves; `hold_dev` the per-lane hold mask,
-        `a_dev` the per-lane dt term, `g_dev` the per-lane per-axis
-        spacing term (workload-specific; `ladder_geom`)."""
-        raise NotImplementedError(f"{self.name} has no ladder support")
-
-    def ladder_state_leaves(self, model):
-        """Unscaled standard-IC STATE leaves (numpy) of the
-        original-shape model — the per-original-shape IC the service
-        embeds into rung-shaped lanes."""
-        raise NotImplementedError(f"{self.name} has no ladder support")
-
     def ladder_geom(self, cfg):
         """(dt_term, per-axis spacing terms) for one lane, computed
         host-side with exactly the roundings the standalone python-float
         path produces (ops.diffusion.step_fused_padded_geom)."""
         raise NotImplementedError(f"{self.name} has no ladder support")
 
+    def build(self, model, width, batch_dims, variant="shard",
+              ladder=False):
+        """-> (advance, bgrid, aux_device, base state): the batched
+        advance (its ladder edition when `ladder`), the aux resharded
+        onto the batched mesh, and the standard-IC state."""
+        import jax
+
+        bgrid = model.make_batched_grid(width, batch_dims)
+        if ladder:
+            advance, _ = model.batched_ladder_advance_fn(bgrid=bgrid)
+        else:
+            advance, _ = model.batched_advance_fn(bgrid=bgrid,
+                                                  variant=variant)
+        state, aux = model.state_and_aux()
+        aux = jax.tree.map(lambda a: _reshard(a, bgrid.aux_sharding), aux)
+        return advance, bgrid, aux, state
+
+    def run(self, prog: _Program, leaves_dev, lane_steps_dev, n):
+        """-> tuple of advanced state leaves (device)."""
+        return self._advance(prog, leaves_dev, (prog.aux,), lane_steps_dev, n)
+
+    def run_ladder(self, prog: _Program, leaves_dev, hold_dev, a_dev,
+                   g_dev, lane_steps_dev, n):
+        """-> advanced state leaves; `hold_dev` the per-lane hold mask,
+        `a_dev` the per-lane dt term, `g_dev` the per-lane per-axis
+        spacing term (`ladder_geom`)."""
+        return self._advance(prog, leaves_dev,
+                             (prog.aux, hold_dev, a_dev, g_dev),
+                             lane_steps_dev, n)
+
+    @staticmethod
+    def _advance(prog, leaves_dev, operands, lane_steps_dev, n):
+        import jax
+
+        state = jax.tree.unflatten(prog.state_def, leaves_dev)
+        out = prog.advance(*state, *operands, lane_steps_dev, n)
+        return tuple(jax.tree.leaves(out))
+
+    def ladder_state_leaves(self, model):
+        """Unscaled standard-IC STATE leaves (numpy) of the
+        original-shape model — the per-original-shape IC the service
+        embeds into rung-shaped lanes."""
+        import jax
+        import numpy as np
+
+        state, _ = model.state_and_aux()
+        return tuple(np.asarray(l) for l in jax.tree.leaves(state))
+
 
 class _DiffusionAdapter(_Adapter):
     name = "diffusion"
+    supports_ladder = True
 
     def make_config(self, key, space_dims):
         from rocm_mpi_tpu.config import DiffusionConfig
@@ -415,42 +444,6 @@ class _DiffusionAdapter(_Adapter):
         from rocm_mpi_tpu.models import HeatDiffusion
 
         return HeatDiffusion(cfg)
-
-    def build(self, model, width, batch_dims, variant="shard"):
-        bgrid = model.make_batched_grid(width, batch_dims)
-        advance, _ = model.batched_advance_fn(bgrid=bgrid, variant=variant)
-        T0, Cp = model.init_state()
-        aux = (_reshard(Cp, bgrid.aux_sharding),)
-        return advance, bgrid, aux, (T0,)
-
-    def run(self, prog, leaves_dev, lane_steps_dev, n):
-        out = prog.advance(
-            leaves_dev[0], prog.aux[0], lane_steps_dev, n
-        )
-        return (out,)
-
-    supports_ladder = True
-
-    def build_ladder(self, model, width, batch_dims):
-        bgrid = model.make_batched_grid(width, batch_dims)
-        advance, _ = model.batched_ladder_advance_fn(bgrid=bgrid)
-        T0, Cp = model.init_state()
-        aux = (_reshard(Cp, bgrid.aux_sharding),)
-        return advance, bgrid, aux, (T0,)
-
-    def run_ladder(self, prog, leaves_dev, hold_dev, a_dev, g_dev,
-                   lane_steps_dev, n):
-        out = prog.advance(
-            leaves_dev[0], prog.aux[0], hold_dev, a_dev, g_dev,
-            lane_steps_dev, n,
-        )
-        return (out,)
-
-    def ladder_state_leaves(self, model):
-        import numpy as np
-
-        T0, _Cp = model.init_state()
-        return (np.asarray(T0),)
 
     def ladder_geom(self, cfg):
         # The exact roundings of the python-float standalone path: dt
@@ -476,6 +469,7 @@ class _DiffusionAdapter(_Adapter):
 
 class _WaveAdapter(_Adapter):
     name = "wave"
+    supports_ladder = True
 
     def make_config(self, key, space_dims):
         from rocm_mpi_tpu.models.wave import WaveConfig
@@ -495,42 +489,6 @@ class _WaveAdapter(_Adapter):
         from rocm_mpi_tpu.models.wave import AcousticWave
 
         return AcousticWave(cfg)
-
-    def build(self, model, width, batch_dims, variant="shard"):
-        bgrid = model.make_batched_grid(width, batch_dims)
-        advance, _ = model.batched_advance_fn(bgrid=bgrid, variant=variant)
-        U0, Up0, C2 = model.init_state()
-        aux = (_reshard(C2, bgrid.aux_sharding),)
-        return advance, bgrid, aux, (U0, Up0)
-
-    def run(self, prog, leaves_dev, lane_steps_dev, n):
-        U, Up = prog.advance(
-            leaves_dev[0], leaves_dev[1], prog.aux[0], lane_steps_dev, n
-        )
-        return (U, Up)
-
-    supports_ladder = True
-
-    def build_ladder(self, model, width, batch_dims):
-        bgrid = model.make_batched_grid(width, batch_dims)
-        advance, _ = model.batched_ladder_advance_fn(bgrid=bgrid)
-        U0, Up0, C2 = model.init_state()
-        aux = (_reshard(C2, bgrid.aux_sharding),)
-        return advance, bgrid, aux, (U0, Up0)
-
-    def run_ladder(self, prog, leaves_dev, hold_dev, a_dev, g_dev,
-                   lane_steps_dev, n):
-        U, Up = prog.advance(
-            leaves_dev[0], leaves_dev[1], prog.aux[0], hold_dev,
-            a_dev, g_dev, lane_steps_dev, n,
-        )
-        return (U, Up)
-
-    def ladder_state_leaves(self, model):
-        import numpy as np
-
-        U0, Up0, _C2 = model.init_state()
-        return (np.asarray(U0), np.asarray(Up0))
 
     def ladder_geom(self, cfg):
         # dt² in the compute dtype (the standalone path casts dt first,
@@ -569,21 +527,6 @@ class _SWEAdapter(_Adapter):
         from rocm_mpi_tpu.models.swe import ShallowWater
 
         return ShallowWater(cfg)
-
-    def build(self, model, width, batch_dims, variant="shard"):
-        bgrid = model.make_batched_grid(width, batch_dims)
-        advance, _ = model.batched_advance_fn(bgrid=bgrid, variant=variant)
-        h0, us0 = model.init_state()
-        Mus = model.face_masks()
-        aux = tuple(_reshard(M, bgrid.aux_sharding) for M in Mus)
-        return advance, bgrid, aux, (h0,) + tuple(us0)
-
-    def run(self, prog, leaves_dev, lane_steps_dev, n):
-        h, us = prog.advance(
-            leaves_dev[0], tuple(leaves_dev[1:]), prog.aux,
-            lane_steps_dev, n,
-        )
-        return (h,) + tuple(us)
 
 
 _ADAPTERS = {
@@ -798,14 +741,9 @@ class SimulationService:
             bd = _bins.pow2_floor(min(width, self._batch_dims))
             with telemetry.span("serve.compile", phase="serve",
                                 bin=key.key_str(), width=width):
-                if ladder:
-                    advance, bgrid, aux, base = adapter.build_ladder(
-                        model, width, bd
-                    )
-                else:
-                    advance, bgrid, aux, base = adapter.build(
-                        model, width, bd, variant=key.variant
-                    )
+                advance, bgrid, aux, base = adapter.build(
+                    model, width, bd, variant=key.variant, ladder=ladder
+                )
             prog = _Program(advance, bgrid, aux, base, adapter,
                             ladder=ladder)
             self._programs[pkey] = prog

@@ -187,9 +187,9 @@ def summarize(streams: dict[int, list[dict]], skipped_lines: int = 0,
                     key = f"{key}@{attrs['devices']}dev"
                 if "driver" in attrs:
                     # The loop form is part of the measurement's identity:
-                    # a scan-driver rate and a step-driver rate must land
+                    # a scan-driver rate and a deep-sweep rate must land
                     # under distinct keys so the regress gate can never
-                    # compare them silently (apps/_common.py --driver).
+                    # compare them silently (apps/_common.py run_app).
                     key = f"{key}:{attrs['driver']}"
                 if attrs.get("wire") and attrs["wire"] != "f32":
                     # Same identity rule for the wire-precision plane:

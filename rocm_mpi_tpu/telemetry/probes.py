@@ -41,8 +41,8 @@ def run_diffusion_phase_probes(model, iters: int = 10,
     runs through utils.checkpoint — whose own spans provide the
     checkpoint attribution (every process must call this on multi-host
     runs: orbax saves are collective). `driver` stamps the loop form the
-    probed run used (apps --driver) on every probe span: phase
-    attributions banked from a scan-driver run and a step-driver run are
+    probed run used (scan or deep) on every probe span: phase
+    attributions banked from a scan-driver run and a deep-sweep run are
     different measurements and must say so.
     """
     if not events.enabled():

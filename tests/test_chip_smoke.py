@@ -83,9 +83,9 @@ def test_check_fails_frozen_hide_slabs(smoke, regime):
     from rocm_mpi_tpu.parallel.overlap import effective_b_width
 
     nt = 40
-    cfg, driver = smoke.config(64, nt, 4, dims=(2, 2))
+    cfg = smoke.config(64, nt, 4, dims=(2, 2))
     model = HeatDiffusion(cfg)
-    got = np.asarray(model.run(variant="hide", driver=driver).T, np.float64)
+    got = np.asarray(model.run(variant="hide").T, np.float64)
     ref, T0 = (np.asarray(a, np.float64) for a in smoke._oracle(cfg, nt))
     frozen = got.copy()
     local = model.grid.local_shape

@@ -426,7 +426,7 @@ def _spawn_health_run(tmp_path, inject=None, **kw):
             REPO / "apps" / "weak_scaling.py",
             "--cpu-devices", "1", "--local", "16", "--nt", "24",
             "--warmup", "4", "--counts", "2", "--dtype", "f32",
-            "--telemetry-windows", "4", "--driver", "step", "--no-probes",
+            "--telemetry-windows", "4", "--no-probes",
         ],
         nprocs=2,
         timeout=240,
@@ -439,14 +439,15 @@ def _spawn_health_run(tmp_path, inject=None, **kw):
 
 def test_watchdog_drill_stall_fault_names_rank1_by_progress(tmp_path):
     """THE acceptance drill: rank 1 wedges in a `stall` fault at a
-    window boundary (step-driver boundaries: 4, 9, 14, 19); the
+    window boundary (scan-driver boundaries, q = gcd(4, 20) = 4:
+    4, 12, 16, 20); the
     watchdog must name it by PROGRESS — its published step counter
     behind the advancing cross-rank median — dump a faulthandler
     traceback via SIGUSR2, write postmortem-rank1.json whose flight
     ring ends in a halo span, kill it, reap rank 0 with the existing
     peer grace, and bundle a merged timeline naming rank 1."""
     tel, results = _spawn_health_run(
-        tmp_path, inject="stall@step=14,rank=1",
+        tmp_path, inject="stall@step=16,rank=1",
         heartbeat_s=2.0, peer_grace_s=6.0, stall_grace_s=3.0,
     )
     report = results.report
@@ -454,9 +455,9 @@ def test_watchdog_drill_stall_fault_names_rank1_by_progress(tmp_path):
     assert len(report.watchdog_verdicts) == 1, report.events
     verdict = report.watchdog_verdicts[0]
     assert verdict["rank"] == 1
-    # detection is by progress: rank 1 never published boundary 14,
+    # detection is by progress: rank 1 never published boundary 16,
     # rank 0 did — so the median sits strictly ahead of the victim
-    assert verdict["step"] == 9
+    assert verdict["step"] == 12
     assert verdict["median_step"] > verdict["step"]
     assert verdict["last_phase"] == "halo"
     (p0, (_, _)), (p1, (out1, _)) = results
@@ -473,7 +474,7 @@ def test_watchdog_drill_stall_fault_names_rank1_by_progress(tmp_path):
     assert "fault_point" in pm["traceback"], \
         "the all-thread dump must show where rank 1 is wedged"
     hb = pm["heartbeat"]
-    assert hb["counters"]["step"] == 9
+    assert hb["counters"]["step"] == 12
     assert hb["last_phase"] == "halo"
     span_like = [r for r in hb["ring"] if r["kind"] in ("span", "phase")]
     assert span_like and span_like[-1]["name"] == "halo.heartbeat", \

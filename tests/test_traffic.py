@@ -205,28 +205,39 @@ class TestReworkedPathParity:
         ref = np.asarray(oracle.T)
 
         m = self._diffusion()
-        for label, r in (
-            ("shard/step", m.run(variant="shard")),
-            ("shard/scan", m.run(variant="shard", driver="scan")),
-            ("hide/step", m.run(variant="hide")),
-            ("hide/scan", m.run(variant="hide", driver="scan")),
-            ("deep4", m.run_deep(block_steps=4)),
+
+        def per_step(variant):
+            T, Cp = m.init_state()
+            return m.advance_fn(variant)(T, Cp, m.config.nt)
+
+        for label, T in (
+            ("shard/step", per_step("shard")),
+            ("shard/scan", m.run(variant="shard").T),
+            ("hide/step", per_step("hide")),
+            ("hide/scan", m.run(variant="hide").T),
+            ("deep4", m.run_deep(block_steps=4).T),
         ):
             np.testing.assert_allclose(
-                np.asarray(r.T), ref, rtol=1e-12, atol=1e-14,
+                np.asarray(T), ref, rtol=1e-12, atol=1e-14,
                 err_msg=f"{label} diverged from the host-staged oracle",
             )
 
-    def test_scan_driver_bitwise_equals_step_driver(self):
-        # Same step program, same order — the drivers must agree BITWISE
-        # on every workload (the scan driver changes scheduling and
-        # allocation, never values).
-        m = self._diffusion()
-        r_step = m.run(variant="shard")
-        r_scan = m.run(variant="shard", driver="scan")
-        np.testing.assert_array_equal(
-            np.asarray(r_step.T), np.asarray(r_scan.T)
-        )
+    @pytest.mark.parametrize("workload, variant, q", [
+        ("diffusion", "shard", 10), ("diffusion", "shard", 5),
+        ("diffusion", "perf", 10), ("diffusion", "perf", 5),
+        ("diffusion", "hide", 10), ("diffusion", "hide", 5),
+        ("wave", "hide", 4), ("wave", "hide", 3),
+        ("swe", "hide", 4), ("swe", "hide", 3),
+    ], ids=lambda v: str(v))
+    def test_scan_driver_bitwise_equals_step_driver(self, workload, variant,
+                                                    q):
+        # Same step program, same order — the scan driver (two steps an
+        # iteration; an odd q ends each chunk with one single step) must
+        # equal the per-step loop (advance_fn, q = 1) BITWISE on every
+        # workload: it changes scheduling and allocation, never values.
+        # Diffusion's perf and hide run the f32 Pallas rungs
+        # (masked_step, interpret mode).
+        import jax
 
         from rocm_mpi_tpu.models import (
             AcousticWave,
@@ -235,41 +246,25 @@ class TestReworkedPathParity:
             WaveConfig,
         )
 
-        w = AcousticWave(WaveConfig(
-            global_shape=(32, 32), lengths=(10.0, 10.0), nt=16, warmup=4,
-            dims=(2, 2),
-        ))
-        np.testing.assert_array_equal(
-            np.asarray(w.run(variant="hide").U),
-            np.asarray(w.run(variant="hide", driver="scan").U),
-        )
-
-        s = ShallowWater(SWEConfig(
-            global_shape=(32, 32), lengths=(10.0, 10.0), nt=16, warmup=4,
-            dims=(2, 2),
-        ))
-        r1, r2 = s.run(variant="hide"), s.run(variant="hide", driver="scan")
-        np.testing.assert_array_equal(np.asarray(r1.h), np.asarray(r2.h))
-        for u1, u2 in zip(r1.us, r2.us):
-            np.testing.assert_array_equal(np.asarray(u1), np.asarray(u2))
-
-    @pytest.mark.parametrize("variant, dims", [("perf", (1, 1)),
-                                               ("hide", (2, 2))],
-                             ids=["perf", "hide"])
-    @pytest.mark.parametrize("nt, warmup, q", [(20, 10, 10), (15, 5, 5)],
-                             ids=["q10", "q5"])
-    def test_diffusion_scan_driver_bitwise_equals_step_driver(
-            self, variant, dims, nt, warmup, q):
-        # The f32 Pallas rungs (masked_step, interpret mode) under the
-        # scan driver's two steps an iteration: an even chunk, and an odd
-        # one that ends each chunk with a single step.
-        m = self._diffusion(dtype="f32", dims=dims, nt=nt, warmup=warmup)
-        assert m.scan_advance_fn(variant)[1] == q
-        r_step = m.run(variant=variant)
-        r_scan = m.run(variant=variant, driver="scan")
-        np.testing.assert_array_equal(
-            np.asarray(r_step.T), np.asarray(r_scan.T)
-        )
+        windows = dict(nt=2 * q, warmup=q)
+        if workload == "diffusion":
+            m = self._diffusion(
+                dims=(1, 1) if variant == "perf" else (2, 2),
+                dtype="f64" if variant == "shard" else "f32", **windows,
+            )
+        else:
+            model, config = {"wave": (AcousticWave, WaveConfig),
+                             "swe": (ShallowWater, SWEConfig)}[workload]
+            m = model(config(global_shape=(32, 32), lengths=(10.0, 10.0),
+                             dims=(2, 2), **windows))
+        scan, got_q = m.scan_advance_fn(variant)
+        assert got_q == q
+        outs = []
+        for advance in (scan, m.advance_fn(variant)):
+            state, aux = m.state_and_aux()
+            outs.append(jax.tree.leaves(advance(*state, aux, 2 * q)))
+        for a, b in zip(*outs, strict=True):
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
     def test_wave_masked_hide_bitwise_equals_perf(self):
         # The mask-as-data select (wave_step_padded_masked) is built to be
@@ -296,7 +291,7 @@ class TestReworkedPathParity:
             dims=(2, 2),
         ))
         r_ap = s.run(variant="ap")
-        r = s.run(variant="hide", driver="scan")
+        r = s.run(variant="hide")
         np.testing.assert_allclose(
             np.asarray(r.h), np.asarray(r_ap.h), rtol=1e-12, atol=1e-14
         )

@@ -382,8 +382,8 @@ def test_auto_scan_driver_bitwise(_isolated_resolve):
     _write_cache(_isolated_resolve, {tkeys.key_str(key): _entry(
         {"chunk": 2}
     )})
-    r0 = _models()[0].run(variant="fused", driver="scan")
-    r1 = _models()[0].run(variant="fused", driver="scan", config="auto")
+    r0 = _models()[0].run(variant="fused")
+    r1 = _models()[0].run(variant="fused", config="auto")
     np.testing.assert_array_equal(np.asarray(r0.T), np.asarray(r1.T))
     assert tresolve.stats()["hits"] >= 1
 
