@@ -12,6 +12,8 @@ from rocm_mpi_tpu.parallel.halo import (  # noqa: F401
     HostStagedStepper,
     exchange_halo,
     exchange_into,
+    exchange_slabs,
+    ghosted_window,
     global_boundary_mask,
     neighbor_shift,
     place_core,

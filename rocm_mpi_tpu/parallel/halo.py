@@ -15,15 +15,16 @@ include the first axis's already-received ghosts and corner ghosts arrive
 from diagonal neighbors for free (the standard two-stage corner trick).
 
 Traffic discipline (the A_eff accounting the perf gate audits,
-docs/PERF.md): padding is ONE preallocated buffer — `place_core` writes
-the block into the ghost-ringed buffer once, `exchange_into` then writes
-each received ghost slice in place with `lax.dynamic_update_slice`. The
-old form rebuilt the whole padded array with a fresh `jnp.concatenate`
-copy per exchanged axis (ndim whole-shard staging copies per exchange);
-the in-place form stages exactly one, which XLA's buffer assignment can
-further alias away. `exchange_into` is exposed separately so callers that
-already hold a padded buffer (the overlap and deep-halo schedules) reuse
-it without re-staging the core.
+docs/PERF.md): the send/receive half, `exchange_slabs`, reads only the
+block's edges and returns the received ghost slabs; nothing is staged.
+A caller that reads the whole padded block (the `shard` and `perf`
+steps, the deep-halo sweeps, the probes) takes `exchange_halo`: ONE
+preallocated buffer — `place_core` writes the block into the
+ghost-ringed buffer once, `exchange_into` then writes each received slab
+in place with `lax.dynamic_update_slice`. A caller that reads only thin
+windows of it (the overlap step's boundary slabs) takes the slabs
+themselves and assembles each window with `ghosted_window`, so it
+stages the windows' bytes, not the shard's.
 
 Non-periodic boundaries: ppermute entries are omitted at the domain edge, so
 edge ghosts arrive as zeros. Their values are never *used*: the global
@@ -89,7 +90,7 @@ def place_core(u, width: int = 1, axes=None):
 
     Returns a zero buffer grown by 2*width along each `axes` entry with
     `u` placed at offset `width` there — ONE staging write, the only
-    whole-block copy the in-place exchange pays. Edge-of-domain ghost
+    whole-block copy `exchange_halo` pays. Edge-of-domain ghost
     slices that no neighbor overwrites stay zero, which IS the framework's
     zero-ghost boundary convention. Its ops carry layer="exchange"
     (docs/TELEMETRY.md "Device-side names").
@@ -109,41 +110,85 @@ def exchange_into(buf, grid: GlobalGrid, width: int = 1, axes=None,
     (inside shard_map). `buf` is a `place_core`-shaped buffer: core at
     offset `width` along every exchanged axis.
 
-    Axis k's sends span the ghosts of axes < k (the two-stage corner
-    trick) and only the core of axes > k, so the wire bytes match
-    `exchange_nbytes` exactly. The corner extensions are assembled from
-    the RECEIVED slabs of earlier axes — tiny width×width concatenates —
-    never by re-reading the updated buffer: every received slab then
-    lands via one `lax.dynamic_update_slice` in a single-consumer chain,
-    which XLA's buffer assignment executes fully in place (re-slicing the
-    updated buffer for later sends would force it to materialize a
-    defensive whole-buffer copy — the staging cost this module exists to
-    remove). Non-periodic boundaries: ppermute entries are omitted at the
-    domain edge, so edge devices receive zeros — harmless writes into the
-    zero ring.
+    The send/receive half is `exchange_slabs` on the buffer's core; each
+    received slab then lands via one `lax.dynamic_update_slice` in a
+    single-consumer chain, which XLA's buffer assignment executes fully
+    in place (re-slicing the updated buffer for later sends would force
+    it to materialize a defensive whole-buffer copy). Slabs land in axis
+    order, so a corner holds the later axis's slab. Non-periodic
+    boundaries: ppermute entries are omitted at the domain edge, so edge
+    devices receive zeros — harmless writes into the zero ring.
+
+    `wire_mode` and `wire_state` are `exchange_slabs`'; the stateful
+    modes return `(buf, new_state)`. Every op it traces, the codec's and
+    the ppermutes included, carries layer="exchange" (docs/TELEMETRY.md
+    "Device-side names").
+    """
+    axes = tuple(range(grid.ndim) if axes is None else axes)
+    width = int(width)
+    core = tuple(
+        slice(width, n - width) if a in axes else slice(None)
+        for a, n in enumerate(buf.shape)
+    )
+    with compat.set_xla_metadata(layer="exchange"):
+        got = _exchange_slabs(buf[core], grid, width, axes, wire_mode,
+                              wire_state)
+        slabs = got[0] if wire.is_stateful(wire_mode) else got
+        for i, ax in enumerate(axes):
+            n = buf.shape[ax] - 2 * width
+            for side, off in (("lo", 0), ("hi", n + width)):
+                starts = tuple(
+                    off if a == ax
+                    else 0 if a in axes[:i] or a not in axes
+                    else width
+                    for a in range(buf.ndim)
+                )
+                buf = lax.dynamic_update_slice(buf, slabs[(ax, side)],
+                                               starts)
+    if wire.is_stateful(wire_mode):
+        return buf, got[1]
+    return buf
+
+
+def exchange_slabs(u, grid: GlobalGrid, width: int = 1, axes=None,
+                   wire_mode: str = "f32", wire_state=None):
+    """The ghost slabs the cartesian neighbors send the local block `u`
+    (inside shard_map), keyed by `(axis, side)`, with no ghost-ringed
+    copy of `u`: for a caller that reads the ghosts of a few thin
+    windows (the overlap step's slabs, `ghosted_window`), not the whole
+    padded block.
+
+    Axes are exchanged in order, and axis k's sends span the ghosts of
+    axes < k (the two-stage corner trick) and only the core of axes > k,
+    so the wire bytes match `exchange_nbytes` exactly. Slab `(ax, side)`
+    is `width` thick along `ax`, spans core + 2*width along the axes
+    exchanged before `ax` and the core along the rest: the `side` ghost
+    slice of `exchange_halo(u)` along `ax`, whose ends are the corner
+    ghosts. The corner extensions of a send are assembled from the
+    RECEIVED slabs of earlier axes — tiny width×width concatenates.
+    Domain-edge devices receive zeros (the zero-ghost convention).
 
     `wire_mode` selects the on-wire slab representation (the
     wire-precision plane, parallel/wire.py): "f32" ships the slab
-    verbatim — the identical program to the pre-wire-plane exchange;
-    "bf16" downcasts each send and upcasts on receive, BEFORE the slab
-    touches the buffer or any later axis's corner assembly (the seam
-    only ever consumes decoded, buffer-dtype slabs). The stateful modes
-    ("int8", "int8_delta") additionally take and return `wire_state` —
-    the flat per-slab state tuple `wire.init_exchange_state` builds —
-    and the return value becomes `(buf, new_state)`.
+    verbatim; "bf16" downcasts each send and upcasts on receive, BEFORE
+    the slab reaches a later axis's corner assembly or the caller (the
+    seam only ever consumes decoded, state-dtype slabs). The stateful
+    modes ("int8", "int8_delta") additionally take `wire_state` — the
+    flat per-slab state tuple `wire.init_exchange_state` builds — and
+    the return value becomes `(slabs, new_state)`.
 
-    Every op it traces, the codec's and the ppermutes included, carries
-    layer="exchange" (docs/TELEMETRY.md "Device-side names").
+    Every op it traces carries layer="exchange".
     """
-    with compat.set_xla_metadata(layer="exchange"):
-        return _exchange_into(buf, grid, width, axes, wire_mode, wire_state)
-
-
-def _exchange_into(buf, grid, width, axes, wire_mode, wire_state):
     axes = tuple(range(grid.ndim) if axes is None else axes)
-    exchanged = set(axes)
-    ndim = buf.ndim
-    width = int(width)
+    _annotate_exchange("halo.exchange", u.shape, u.dtype, width, axes,
+                       wire_mode)
+    with compat.set_xla_metadata(layer="exchange"):
+        return _exchange_slabs(u, grid, int(width), axes, wire_mode,
+                               wire_state)
+
+
+def _exchange_slabs(u, grid, width, axes, wire_mode, wire_state):
+    ndim = u.ndim
     stateful = wire.is_stateful(wire_mode)
     if stateful and wire_state is None:
         raise ValueError(
@@ -157,25 +202,11 @@ def _exchange_into(buf, grid, width, axes, wire_mode, wire_state):
     new_state: list = []
     slab_i = 0
 
-    def core_extent(a):
-        return buf.shape[a] - (2 * width if a in exchanged else 0)
-
     recv: dict = {}  # (axis, side) -> received slab
     done: list = []
     for ax in axes:
         name = grid.axis_names[ax]
-        n = core_extent(ax)
-
-        def core_edge(off):
-            # The buffer's own edge hyperslab (pre-update reads only):
-            # core extent on every other exchanged axis.
-            idx = tuple(
-                slice(off, off + width) if a == ax
-                else slice(width, width + core_extent(a))
-                if a in exchanged else slice(None)
-                for a in range(ndim)
-            )
-            return buf[idx]
+        n = u.shape[ax]
 
         def send_slab(lo_side):
             # Core edge, extended along each already-exchanged axis with
@@ -183,11 +214,11 @@ def _exchange_into(buf, grid, width, axes, wire_mode, wire_state):
             # step the extents line up because recv[(a, ·)] spans full
             # padded extent on axes exchanged before `a` and core extent
             # after (the same invariant this concat establishes).
-            piece = core_edge(width if lo_side else n)
             edge = slice(0, width) if lo_side else slice(n - width, n)
             sel = tuple(
                 edge if a == ax else slice(None) for a in range(ndim)
             )
+            piece = u[sel]
             for a in done:
                 piece = jnp.concatenate(
                     [recv[(a, "lo")][sel], piece, recv[(a, "hi")][sel]],
@@ -210,24 +241,79 @@ def _exchange_into(buf, grid, width, axes, wire_mode, wire_state):
                 shipped = tuple(
                     neighbor_shift(p, name, direction) for p in payload
                 )
-                recv[(ax, side)], st = codec.recv(shipped, st, buf.dtype)
+                recv[(ax, side)], st = codec.recv(shipped, st, u.dtype)
                 new_state.extend(st)
             slab_i += 1
         done.append(ax)
-
-    for i, ax in enumerate(done):
-        n = core_extent(ax)
-        for side, off in (("lo", 0), ("hi", n + width)):
-            starts = tuple(
-                off if a == ax
-                else 0 if a in done[:i] or a not in exchanged
-                else width
-                for a in range(ndim)
-            )
-            buf = lax.dynamic_update_slice(buf, recv[(ax, side)], starts)
     if stateful:
-        return buf, tuple(new_state)
-    return buf
+        return recv, tuple(new_state)
+    return recv
+
+
+def ghosted_window(u, slabs, bounds, width: int = 1):
+    """A window of the padded block without the padded block: equal to
+    `exchange_halo(u, grid, width)` over core-coordinate ranges `bounds`
+    (one `(lo, hi)` per axis, -width <= lo < hi <= n + width), assembled
+    from `u` and the `slabs` that `exchange_slabs(u, grid, width)`
+    received (every axis exchanged, in order).
+
+    Built from the last axis back: the window's ghost rows along axis k
+    come from slab `(k, side)` — which holds the corners with every
+    earlier axis, as `exchange_into` places them — and the rest from the
+    window narrowed to the core along k. One concatenate per axis the
+    window leaves the shard along, so the bytes staged are the window's
+    own (`u.size` plays no part). Its ops carry layer="exchange"."""
+    width = int(width)
+
+    def build(k, rng):
+        if k < 0:
+            return u[tuple(slice(lo, hi) for lo, hi in rng)]
+        lo, hi = rng[k]
+        n = u.shape[k]
+
+        def slab(side, along):
+            # Slab (k, side): padded extent before k, core extent after.
+            idx = tuple(
+                along if a == k
+                else slice(r[0] + width, r[1] + width) if a < k
+                else slice(*r)
+                for a, r in enumerate(rng)
+            )
+            return slabs[(k, side)][idx]
+
+        inner = rng[:k] + ((max(lo, 0), min(hi, n)),) + rng[k + 1:]
+        parts = [build(k - 1, inner)]
+        if lo < 0:
+            parts.insert(0, slab("lo", slice(lo + width, width)))
+        if hi > n:
+            parts.append(slab("hi", slice(0, hi - n)))
+        return parts[0] if len(parts) == 1 else jnp.concatenate(parts, k)
+
+    with compat.set_xla_metadata(layer="exchange"):
+        return build(u.ndim - 1, tuple(tuple(b) for b in bounds))
+
+
+def _annotate_exchange(name, block, dtype, width, axes, wire_mode,
+                       lanes=None):
+    """Trace-time annotation of one exchange: shapes are concrete while
+    jax traces, so "this program moves N bytes per exchange" is
+    recordable exactly once per compiled program (telemetry.events.
+    annotate dedups). `bytes` is the TRUE on-wire figure for the active
+    wire mode — a bf16 exchange must never book f32 bytes into the halo
+    bytes/s aggregate or a regress baseline — and, for a lane-batched
+    exchange, the sum over its `lanes`."""
+    if not telemetry.enabled():
+        return
+    nbytes = exchange_nbytes(block, jnp.dtype(dtype).itemsize, width, axes,
+                             wire_mode)
+    extra = {} if lanes is None else {"lanes": int(lanes)}
+    telemetry.annotate(
+        name, **extra,
+        bytes=nbytes * (1 if lanes is None else int(lanes)),
+        width=width,
+        block=tuple(int(n) for n in block),
+        wire=wire_mode,
+    )
 
 
 def exchange_halo(u, grid: GlobalGrid, width: int = 1, axes=None,
@@ -237,31 +323,33 @@ def exchange_halo(u, grid: GlobalGrid, width: int = 1, axes=None,
     Returns an array grown by 2*width along each exchanged axis. This is the
     `update_halo!(T)` analog: one call per step, all axes
     (diffusion_2D_ap.jl:42). Composition of `place_core` + `exchange_into`
-    — one staged copy, ghost slices written in place.
+    — one staged copy, ghost slices written in place — for a caller that
+    reads the whole padded block; one that reads a few windows of it takes
+    `exchange_slabs` and `ghosted_window` instead.
 
-    `wire_mode` selects the on-wire slab precision (exchange_into has the
+    `wire_mode` selects the on-wire slab precision (exchange_slabs has the
     contract); the stateful modes take/return `wire_state` and the result
     becomes `(padded, new_state)`. The default "f32" traces the exact
     pre-wire-plane program — bitwise identical on every workload.
     """
     axes = tuple(range(grid.ndim) if axes is None else axes)
-    if telemetry.enabled():
-        # Trace-time annotation: shapes are concrete while jax traces, so
-        # "this program moves N bytes per exchange" is recordable exactly
-        # once per compiled program (telemetry.events.annotate dedups).
-        # `bytes` is the TRUE on-wire figure for the active wire mode —
-        # a bf16 exchange must never book f32 bytes into the halo
-        # bytes/s aggregate or a regress baseline.
-        telemetry.annotate(
-            "halo.exchange",
-            bytes=exchange_nbytes(u.shape, u.dtype.itemsize, width, axes,
-                                  wire_mode),
-            width=width,
-            block=tuple(int(n) for n in u.shape),
-            wire=wire_mode,
-        )
+    _annotate_exchange("halo.exchange", u.shape, u.dtype, width, axes,
+                       wire_mode)
     return exchange_into(place_core(u, width, axes), grid, width, axes,
                          wire_mode=wire_mode, wire_state=wire_state)
+
+
+def _lane_space(bgrid, wire_mode):
+    """The space grid of a lane-batched exchange; refuses stateful wire
+    modes (their error-feedback state is per-logical-wire, and a
+    lane-batched exchange would need a per-lane state plane nothing
+    carries yet)."""
+    if wire.is_stateful(wire_mode):
+        raise ValueError(
+            f"wire_mode {wire_mode!r} is stateful; batched exchanges "
+            "support the stateless modes (f32/bf16) only"
+        )
+    return bgrid.space if hasattr(bgrid, "space") else bgrid
 
 
 def exchange_halo_batched(ub, bgrid, width: int = 1, axes=None,
@@ -274,34 +362,33 @@ def exchange_halo_batched(ub, bgrid, width: int = 1, axes=None,
     strictly per-space-axis (ppermute's batching rule carries the lane
     dim along each slab, so lane k's ghosts only ever come from lane
     k's spatial neighbors; nothing is permuted over the `batch` axis —
-    lanes are separate tenants).
-
-    Stateless wire modes only (f32/bf16): the error-feedback state of
-    the int8 modes is per-logical-wire, and a lane-batched exchange
-    would need a per-lane state plane nothing carries yet."""
-    if wire.is_stateful(wire_mode):
-        raise ValueError(
-            f"wire_mode {wire_mode!r} is stateful; batched exchanges "
-            "support the stateless modes (f32/bf16) only"
-        )
-    space = bgrid.space if hasattr(bgrid, "space") else bgrid
-    if telemetry.enabled():
-        telemetry.annotate(
-            "halo.exchange.batched",
-            lanes=int(ub.shape[0]),
-            bytes=int(ub.shape[0]) * exchange_nbytes(
-                ub.shape[1:], ub.dtype.itemsize, width, axes, wire_mode
-            ),
-            width=width,
-            block=tuple(int(n) for n in ub.shape[1:]),
-            wire=wire_mode,
-        )
+    lanes are separate tenants). Stateless wire modes only (f32/bf16)."""
+    space = _lane_space(bgrid, wire_mode)
+    _annotate_exchange("halo.exchange.batched", ub.shape[1:], ub.dtype,
+                       width, axes, wire_mode, lanes=ub.shape[0])
     return jax.vmap(
         lambda u: exchange_into(
             place_core(u, width, axes), space, width, axes,
             wire_mode=wire_mode,
         )
     )(ub)
+
+
+def exchange_slabs_batched(ub, bgrid, width: int = 1,
+                           wire_mode: str = "f32"):
+    """`exchange_slabs` vmapped over the leading lane axis of a batched
+    block, as `exchange_halo_batched` vmaps `exchange_halo`: every slab
+    gains the lane axis in front, and the collectives stay per-space-axis.
+    Stateless wire modes only (f32/bf16)."""
+    space = _lane_space(bgrid, wire_mode)
+    _annotate_exchange("halo.exchange.batched", ub.shape[1:], ub.dtype,
+                       width, None, wire_mode, lanes=ub.shape[0])
+    with compat.set_xla_metadata(layer="exchange"):
+        return jax.vmap(
+            lambda u: _exchange_slabs(u, space, int(width),
+                                      tuple(range(space.ndim)), wire_mode,
+                                      None)
+        )(ub)
 
 
 class HaloProgram(NamedTuple):

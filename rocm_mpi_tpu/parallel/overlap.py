@@ -13,7 +13,10 @@ latency-hiding scheduler do the queue juggling:
 
 Per step, inside one shard_map program:
   1. `ppermute` the current field's edge slices to the cartesian neighbors
-     (the halo exchange) — depends only on the field's edges;
+     (the halo exchange, `halo.exchange_slabs`) — depends only on the
+     field's edges, and hands back the received ghost slabs as they are:
+     no ghost-ringed copy of the shard is staged, since only the slabs
+     of step 3 read ghosts;
   2. update the interior region — it reads the UNPADDED local block
      directly (its width-1 stencil window never leaves the shard), so it
      depends on NO ghost value and XLA is free to run the collective and
@@ -26,7 +29,11 @@ Per step, inside one shard_map program:
      full-shard result is the output buffer. Its values within one cell
      of a shard face are wrong wherever that face is not a global face,
      and step 4 overwrites every one of them;
-  3. update the boundary slabs once their ghosts arrive;
+  3. update the boundary slabs once their ghosts arrive: each slab's
+     operand is its window of the padded field, assembled from the
+     shard's own edge rows and columns and the matching pieces of the
+     received slabs (`halo.ghosted_window`) — a few thin windows, not
+     the shard;
   4. write every region's result into one output buffer with
      `lax.dynamic_update_slice` — no per-axis concatenate tree, no
      staging copies; the slabs cover every cell within b >= 1 of a shard
@@ -58,7 +65,11 @@ import numpy as np
 from jax import lax
 
 from rocm_mpi_tpu import telemetry
-from rocm_mpi_tpu.parallel.halo import exchange_halo, exchange_halo_batched
+from rocm_mpi_tpu.parallel.halo import (
+    exchange_slabs,
+    exchange_slabs_batched,
+    ghosted_window,
+)
 from rocm_mpi_tpu.parallel.mesh import GlobalGrid
 from rocm_mpi_tpu.utils import compat
 
@@ -100,7 +111,8 @@ def make_overlap_step(
     `Tl` — the exchanged state — may itself be a pytree of same-shaped
     arrays (r4: the shallow-water workload's (h, u, v), whose coupled
     update reads neighbors of every field): each leaf is halo-exchanged
-    and region-sliced, `padded_update` receives the padded pytree, returns
+    and its slab windows assembled, `padded_update` receives the padded
+    window pytree, returns
     the same-structure core pytree, and the slab/interior splice happens
     leaf-wise. A bare array is the one-leaf case — the diffusion and wave
     callers are unchanged, op for op.
@@ -146,38 +158,44 @@ def make_overlap_step(
     the buffer the slabs are written into; without it, the interior box
     is sliced out and updated by `padded_update` like the slabs. The
     `overlap.step` annotation records the form (`interior="whole_shard"`,
-    `"sliced"`, or `"none"` when the slabs tile the whole shard).
+    `"sliced"`, or `"none"` when the slabs tile the whole shard), how the
+    slabs get their ghosts (`ghosts="slabs"`: as received slabs, no
+    ghost-ringed copy of the shard), and `staged_bytes`, the bytes of
+    slab operands assembled a step.
     """
     from rocm_mpi_tpu.parallel import wire
 
     # Mode validity checked here; the stateful-mode refusal (this
-    # program is stateless) fires at trace time inside exchange_halo,
+    # program is stateless) fires at trace time inside exchange_slabs,
     # so a model whose config carries a deep-only wire mode can still
     # BUILD its per-step variants and run its deep schedule.
     wire.validate_mode(wire_mode)
     bw = effective_b_width(grid.local_shape, b_width)
-    splice, interior = _make_region_splice(
+    splice, interior, staged_cells = _make_region_splice(
         grid, padded_update, bw, mask_boundary, interior_update
     )
 
     def local_step(Tl, Cpl, lam, dt, spacing):
         if telemetry.enabled():
-            # Trace-time: the slab geometry and interior form this
-            # compiled overlap step uses (the per-leaf halo.exchange byte
-            # annotations fire inside exchange_halo below).
+            leaves = jax.tree_util.tree_leaves(Tl)
+            # Trace-time: the slab geometry, interior form and slab
+            # staging this compiled overlap step uses (the per-leaf
+            # halo.exchange byte annotations fire inside exchange_slabs).
             telemetry.annotate(
                 "overlap.step", b_width=tuple(int(b) for b in bw),
-                leaves=len(jax.tree_util.tree_leaves(Tl)),
-                wire=wire_mode, interior=interior,
+                leaves=len(leaves), wire=wire_mode, interior=interior,
+                ghosts="slabs",
+                staged_bytes=staged_cells * sum(
+                    t.dtype.itemsize for t in leaves),
             )
         # (1) halo exchange of the current state — edge-slice ppermutes,
         # one exchange per state leaf (SWE: 3 fields; diffusion/wave: 1),
         # at the wire mode's on-wire precision (received slabs arrive
-        # already decoded to the buffer dtype).
-        Tp = jax.tree_util.tree_map(
-            lambda t: exchange_halo(t, grid, wire_mode=wire_mode), Tl
-        )  # core + 2 per axis
-        return splice(Tl, Tp, Cpl, lam, dt, spacing)
+        # already decoded to the state dtype).
+        slabs = jax.tree_util.tree_map(
+            lambda t: exchange_slabs(t, grid, wire_mode=wire_mode), Tl
+        )
+        return splice(Tl, slabs, Cpl, lam, dt, spacing)
 
     return local_step
 
@@ -199,7 +217,7 @@ def make_batched_overlap_step(
     Inside a shard_map over `bgrid.mesh`, `batched_local(Tb_l, Cpl,
     lam, dt, spacing)` takes the local `(local_batch, *local_space)`
     block of `bgrid.spec`-sharded state and the UNBATCHED lane-shared
-    aux block. The exchange runs through `exchange_halo_batched`
+    aux block. The exchange runs through `exchange_slabs_batched`
     (aggregate lane bytes booked on the wire annotation; halo
     collectives stay strictly per-space-axis — nothing ever permutes
     over `batch`), and the region splice is vmapped per lane: the
@@ -208,7 +226,7 @@ def make_batched_overlap_step(
     whole hide trick — survives the vmap unchanged.
 
     Stateless wire modes only (f32/bf16), enforced by
-    `exchange_halo_batched`. `mask_boundary` defaults to False — every
+    `exchange_slabs_batched`. `mask_boundary` defaults to False — every
     in-repo batched caller is on the Cm masked-coefficient contract.
     `interior_update` is `make_overlap_step`'s, vmapped with the rest of
     the splice."""
@@ -217,27 +235,29 @@ def make_batched_overlap_step(
     wire.validate_mode(wire_mode)
     space = bgrid.space
     bw = effective_b_width(space.local_shape, b_width)
-    splice, interior = _make_region_splice(
+    splice, interior, staged_cells = _make_region_splice(
         space, padded_update, bw, mask_boundary, interior_update
     )
 
     def batched_local(Tb_l, Cpl, lam, dt, spacing):
         if telemetry.enabled():
+            leaves = jax.tree_util.tree_leaves(Tb_l)
+            lanes = int(leaves[0].shape[0])
             telemetry.annotate(
                 "overlap.step.batched",
                 b_width=tuple(int(b) for b in bw),
-                lanes=int(jax.tree_util.tree_leaves(Tb_l)[0].shape[0]),
-                leaves=len(jax.tree_util.tree_leaves(Tb_l)),
-                wire=wire_mode, interior=interior,
+                lanes=lanes, leaves=len(leaves),
+                wire=wire_mode, interior=interior, ghosts="slabs",
+                staged_bytes=lanes * staged_cells * sum(
+                    t.dtype.itemsize for t in leaves),
             )
-        Tp_b = jax.tree_util.tree_map(
-            lambda t: exchange_halo_batched(t, bgrid,
-                                            wire_mode=wire_mode),
+        slabs_b = jax.tree_util.tree_map(
+            lambda t: exchange_slabs_batched(t, bgrid, wire_mode=wire_mode),
             Tb_l,
         )
         return jax.vmap(
-            lambda Tl, Tpl: splice(Tl, Tpl, Cpl, lam, dt, spacing)
-        )(Tb_l, Tp_b)
+            lambda Tl, slabs: splice(Tl, slabs, Cpl, lam, dt, spacing)
+        )(Tb_l, slabs_b)
 
     return batched_local
 
@@ -249,14 +269,17 @@ def _make_region_splice(
     mask_boundary: bool,
     interior_update: Callable | None = None,
 ):
-    """Build `(splice, interior)`: `splice(Tl, Tp, Cpl, lam, dt,
-    spacing) -> Tl_new` is the boundary-slab/interior decomposition and
-    the in-place DUS splice of `make_overlap_step`, factored over an
-    ALREADY-exchanged padded state `Tp` so the single-lane and
-    lane-batched steps share one seam (the batched edition exchanges
-    through `exchange_halo_batched` and vmaps this per lane);
-    `interior` names the interior's form ("whole_shard", "sliced" or
-    "none") for the step's annotation.
+    """Build `(splice, interior, staged_cells)`: `splice(Tl, slabs, Cpl,
+    lam, dt, spacing) -> Tl_new` is the boundary-slab/interior
+    decomposition and the in-place DUS splice of `make_overlap_step`,
+    factored over the ghost slabs ALREADY received for each state leaf
+    (`slabs` has `Tl`'s structure, each leaf's `exchange_slabs` dict in
+    place of the leaf) so the single-lane and lane-batched steps share
+    one seam (the batched edition exchanges through
+    `exchange_slabs_batched` and vmaps this per lane); `interior` names
+    the interior's form ("whole_shard", "sliced" or "none") and
+    `staged_cells` counts the cells of slab operands assembled a step,
+    per state leaf, for the step's annotation.
 
     Every op of the splice carries layer="splice", and each region
     update region="interior" or region="slab" besides (docs/TELEMETRY.md
@@ -313,23 +336,32 @@ def _make_region_splice(
             edge_lo.append(lo)
             edge_hi.append(hi)
 
-    def splice(Tl, Tp, Cpl, lam, dt, spacing):
-        with compat.set_xla_metadata(layer="splice"):
-            return _splice(Tl, Tp, Cpl, lam, dt, spacing)
+    staged_cells = sum(
+        int(np.prod([hi - lo + 2 for lo, hi in b]))
+        for b in all_boxes if not ghost_free(b)
+    )
 
-    def _splice(Tl, Tp, Cpl, lam, dt, spacing):
+    def splice(Tl, slabs, Cpl, lam, dt, spacing):
+        with compat.set_xla_metadata(layer="splice"):
+            return _splice(Tl, slabs, Cpl, lam, dt, spacing)
+
+    def _splice(Tl, slabs, Cpl, lam, dt, spacing):
         def region(bounds):
             """Candidate update of the core box given by `bounds`. Slab
-            boxes read the padded state; ghost-free boxes (the interior)
-            read the raw block — no dataflow edge to the collective."""
+            boxes read their window of the padded state, assembled from
+            the block's edges and the received slabs; ghost-free boxes
+            (the interior) read the raw block — no dataflow edge to the
+            collective."""
             core_idx = tuple(slice(lo, hi) for lo, hi in bounds)
             cp = jax.tree_util.tree_map(lambda a: a[core_idx], Cpl)
             if ghost_free(bounds):
                 raw_idx = tuple(slice(lo - 1, hi + 1) for lo, hi in bounds)
                 tp = jax.tree_util.tree_map(lambda a: a[raw_idx], Tl)
             else:
-                pad_idx = tuple(slice(lo, hi + 2) for lo, hi in bounds)
-                tp = jax.tree_util.tree_map(lambda a: a[pad_idx], Tp)
+                window = tuple((lo - 1, hi + 1) for lo, hi in bounds)
+                tp = jax.tree_util.tree_map(
+                    lambda a, s: ghosted_window(a, s, window), Tl, slabs
+                )
                 # The barrier keeps the TPU compiler (libtpu 0.0.34) from
                 # fusing a slab kernel's output into its DUS consumer:
                 # with two such slabs that fusion pass aborts the
@@ -344,7 +376,8 @@ def _make_region_splice(
 
         # (2)+(3) region updates, (4) spliced in place: every box is
         # written exactly once, so the seed buffer's values never survive
-        # — XLA may alias it with Tl's storage (dead after the exchange),
+        # — XLA may alias it with Tl's storage (dead once the last slab
+        # window is assembled),
         # and each region+DUS link lowers to an in-place update-slice
         # fusion (observed on the CPU backend) instead of the old concat
         # tree's whole-shard staging copies. The whole-shard interior
@@ -379,4 +412,4 @@ def _make_region_splice(
             lambda old, nw: jnp.where(mask, old, nw), Tl, new
         )
 
-    return splice, interior
+    return splice, interior, staged_cells

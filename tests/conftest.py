@@ -103,3 +103,34 @@ assert len(jax.devices()) == 8, (
 from rocm_mpi_tpu.utils.backend import enable_persistent_cache  # noqa: E402
 
 enable_persistent_cache()
+
+
+@pytest.fixture
+def padded_splice(monkeypatch):
+    """Call it to turn the overlap step back into the form it had before
+    the slabs took their ghosts directly: every state leaf
+    halo-exchanged into a ghost-ringed copy `Tp = exchange_halo(Tl)` and
+    each slab's operand sliced out of it (`Tp[pad_idx]`), in place of
+    the received slabs and `ghosted_window`. The oracle of the bitwise
+    parity tests, and the staged program the traffic gate must refuse."""
+    import rocm_mpi_tpu.parallel.overlap as overlap_mod
+    from rocm_mpi_tpu.parallel.halo import exchange_halo, exchange_halo_batched
+
+    def apply():
+        monkeypatch.setattr(
+            overlap_mod, "exchange_slabs",
+            lambda u, grid, wire_mode="f32": {
+                "Tp": exchange_halo(u, grid, wire_mode=wire_mode)},
+        )
+        monkeypatch.setattr(
+            overlap_mod, "exchange_slabs_batched",
+            lambda ub, bgrid, wire_mode="f32": {
+                "Tp": exchange_halo_batched(ub, bgrid, wire_mode=wire_mode)},
+        )
+        monkeypatch.setattr(
+            overlap_mod, "ghosted_window",
+            lambda u, slabs, window: slabs["Tp"][
+                tuple(slice(lo + 1, hi + 1) for lo, hi in window)],
+        )
+
+    return apply

@@ -150,7 +150,7 @@ def test_wave_per_step_messages():
 def test_hide_interior_consumes_no_collective_results(monkeypatch):
     """NaN-poison the exchange: hide's interior must be bit-identical.
 
-    Forces every ghost cell arriving from a ppermute to NaN. Any interior
+    Forces every ghost slab arriving from a ppermute to NaN. Any interior
     cell whose value consumed a collective result would become NaN (NaN
     propagates through every arithmetic op); only the boundary slabs (width
     = effective b_width) may differ. This is the dataflow-independence that
@@ -158,7 +158,7 @@ def test_hide_interior_consumes_no_collective_results(monkeypatch):
     step (2)) — asserted on the executed program, not the docstring.
     """
     import rocm_mpi_tpu.parallel.overlap as overlap_mod
-    from rocm_mpi_tpu.parallel.halo import exchange_halo
+    from rocm_mpi_tpu.parallel.halo import exchange_slabs
     from rocm_mpi_tpu.parallel.overlap import effective_b_width
 
     b_width = (2, 2)
@@ -168,14 +168,12 @@ def test_hide_interior_consumes_no_collective_results(monkeypatch):
     out_clean = np.asarray(jax.block_until_ready(step_clean(T, Cp)))
 
     def poisoned_exchange(u, grid, width=1, axes=None, **wire_kw):
-        padded = exchange_halo(u, grid, width=width, axes=axes, **wire_kw)
-        # Everything outside the original core is ghost data that arrived
-        # (or would arrive) via collective_permute: poison it all.
-        core = tuple(slice(width, width + n) for n in u.shape)
-        poison = jnp.full_like(padded, jnp.nan)
-        return poison.at[core].set(padded[core])
+        # Every received slab is ghost data that arrived (or would
+        # arrive) via collective_permute: poison it all.
+        slabs = exchange_slabs(u, grid, width=width, axes=axes, **wire_kw)
+        return {k: jnp.full_like(s, jnp.nan) for k, s in slabs.items()}
 
-    monkeypatch.setattr(overlap_mod, "exchange_halo", poisoned_exchange)
+    monkeypatch.setattr(overlap_mod, "exchange_slabs", poisoned_exchange)
     m_poison = _diffusion(b_width=b_width)
     out_poison = np.asarray(
         jax.block_until_ready(m_poison.step_fn("hide")(T, Cp))

@@ -51,18 +51,22 @@ def _shape(shape, sharding):
 def _expect_hide_tags(tags, writes=True):
     """The hide step's tags; `writes`: its buffer writes lower to
     dynamic_update_slice (under the serving step's vmap they lower to
-    scatters, whose tag this line-wise reading does not see)."""
+    scatters, whose tag this line-wise reading does not see). The
+    exchange hands the slabs their ghosts as received slabs, assembled
+    into each slab's window by concatenates: it writes no buffer."""
     need = [
         ("stablehlo.slice", "layer", "exchange"),
         ("stablehlo.collective_permute", "layer", "exchange"),
+        ("stablehlo.concatenate", "layer", "exchange"),
         ("stablehlo.slice", "layer", "splice"),
         ("stablehlo.while", "layer", "driver"),
     ]
     if writes:
-        need += [("stablehlo.dynamic_update_slice", "layer", "exchange"),
-                 ("stablehlo.dynamic_update_slice", "layer", "splice")]
+        need += [("stablehlo.dynamic_update_slice", "layer", "splice")]
     for n in need:
         assert n in tags, n
+    assert ("stablehlo.dynamic_update_slice", "layer", "exchange") \
+        not in tags
     regions = {v for _, k, v in tags if k == "region"}
     assert regions == {"interior", "slab"}
 
@@ -103,8 +107,7 @@ def test_perf_step_tags_the_driver():
 
 
 _OPCODE = re.compile(r"\s*(?:ROOT )?%[\w.\-]+ = \S+ ([\w\-]+)\(")
-_DATA_MOVEMENT = {("slice", "exchange"), ("pad", "exchange"),
-                  ("dynamic-update-slice", "exchange"),
+_DATA_MOVEMENT = {("slice", "exchange"), ("concatenate", "exchange"),
                   ("slice", "splice"), ("dynamic-update-slice", "splice")}
 
 

@@ -131,6 +131,15 @@ def test_overlap_step_annotates_interior_form(monkeypatch, dtype, b_width,
     recs = events.records(kind="trace", name="overlap.step")
     events.clear()
     assert [r["attrs"]["interior"] for r in recs] == [form]
+    # The slabs take their ghosts as received slabs: what is staged a
+    # step is their operands, a window one cell wider than each slab.
+    (n0, n1), (b0, b1) = (16, 16), b_width
+    cells = 2 * (b0 + 2) * (n1 + 2)
+    if n0 > 2 * b0:
+        cells += 2 * (n0 - 2 * b0 + 2) * (b1 + 2)
+    itemsize = np.dtype(np.float32 if dtype == "f32" else np.float64).itemsize
+    assert recs[0]["attrs"]["ghosts"] == "slabs"
+    assert recs[0]["attrs"]["staged_bytes"] == cells * itemsize
 
 
 def test_hide_with_reference_b_width_clamped():
@@ -363,3 +372,78 @@ def test_explicit_chunk_cap_warns():
         warnings.simplefilter("always")
         fused_multi_step(T, Cp, 1.0, 1e-5, (0.1, 0.1), 64, chunk=64)
     assert any("chunk degraded" in str(x.message) for x in w)
+
+
+# ---- the slab ghosts against the padded splice --------------------------
+
+
+def _hide_run(kind, shape, dims, b_width, dtype, wire):
+    """Three hide steps of workload `kind` from a seeded state: every
+    leaf of the final state, as numpy."""
+    import jax
+    import jax.numpy as jnp
+
+    rng = np.random.default_rng(11)
+    common = dict(global_shape=shape, lengths=(10.0,) * len(shape), nt=3,
+                  warmup=0, dims=dims, b_width=b_width, dtype=dtype,
+                  wire_mode=wire)
+    if kind == "wave":
+        from rocm_mpi_tpu.models.wave import AcousticWave, WaveConfig
+
+        m = AcousticWave(WaveConfig(**common))
+        state = [jnp.copy(a) for a in m.init_state()]
+        out = m.advance_fn("hide")(*state, 3)
+    elif kind == "swe":
+        from rocm_mpi_tpu.models.swe import ShallowWater, SWEConfig
+
+        m = ShallowWater(SWEConfig(**common))
+        h, us = m.init_state()
+        out = m.advance_fn("hide")(jnp.copy(h), jax.tree_util.tree_map(
+            jnp.copy, us), m.face_masks(), 3)
+    else:
+        m = HeatDiffusion(DiffusionConfig(**common))
+        T0, Cp = m.init_state()
+        T = rng.uniform(0.5, 1.5, shape).astype(np.asarray(T0).dtype)
+        if kind == "diffusion":
+            out = m.advance_fn("hide")(jax.device_put(T, m.grid.sharding),
+                                       Cp, 3)
+        else:  # the lane-batched hide, lanes of unlike states
+            adv, bg = m.batched_advance_fn(batch=2, variant="hide")
+            lanes = np.stack([T, T[::-1] * 0.5])
+            out = adv(jax.device_put(lanes, bg.sharding),
+                      jax.device_put(np.asarray(Cp), bg.aux_sharding),
+                      jax.device_put(np.array([3, 2], np.int32),
+                                     bg.batch_sharding), 3)
+    return [np.asarray(a) for a in jax.tree_util.tree_leaves(out)]
+
+
+@pytest.mark.parametrize("kind,shape,dims,b_width,dtype,wire", [
+    ("diffusion", (64, 48), (2, 2), (1, 1), "f32", "f32"),
+    ("diffusion", (160, 48), (2, 2), (32, 4), "f32", "f32"),
+    ("diffusion", (40, 40), (2, 2), (32, 4), "f32", "f32"),  # clamped
+    ("diffusion", (32, 32), (2, 2), (8, 8), "f32", "f32"),  # no interior
+    ("diffusion", (34, 26), (2, 2), (3, 2), "f32", "f32"),  # odd shard
+    ("diffusion", (48, 32), (2, 1), (4, 4), "f32", "f32"),
+    ("diffusion", (64, 48), (2, 2), (4, 4), "f64", "f32"),  # sliced
+    ("diffusion", (35, 26), (1, 2), (32, 4), "f64", "f32"),
+    ("diffusion", (64, 48), (2, 2), (4, 4), "f32", "bf16"),
+    ("wave", (48, 40), (2, 2), (4, 4), "f32", "f32"),
+    ("wave", (48, 40), (2, 1), (4, 2), "f64", "bf16"),
+    ("swe", (32, 32), (2, 2), (4, 4), "f64", "f32"),
+    ("swe", (40, 24), (2, 1), (32, 4), "f32", "f32"),
+    ("batched", (32, 32), (2, 2), (4, 4), "f32", "f32"),
+    ("batched", (16, 16), (1, 2), (2, 2), "f64", "bf16"),
+], ids=["b1", "b32x4", "clamped", "none", "odd", "2x1", "f64", "f64-odd",
+        "bf16", "wave", "wave-f64-bf16", "swe", "swe-2x1", "batched",
+        "batched-f64-bf16"])
+def test_slab_ghosts_match_padded_splice(padded_splice, kind, shape, dims,
+                                         b_width, dtype, wire):
+    """The hide step with ghosts handed to the slabs as received slabs
+    computes the bits of the step that sliced each slab's operand out of
+    a ghost-ringed copy of the shard (`padded_splice`, the oracle)."""
+    got = _hide_run(kind, shape, dims, b_width, dtype, wire)
+    padded_splice()
+    want = _hide_run(kind, shape, dims, b_width, dtype, wire)
+    assert len(got) == len(want)
+    for g, w in zip(got, want):
+        np.testing.assert_array_equal(g, w)

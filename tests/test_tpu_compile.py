@@ -370,14 +370,14 @@ def _elements(shape: str) -> int:
 
 
 def _whole_field_moves(loop, n) -> collections.Counter:
-    """Slices, buffer writes of an update, and copies (by layout) of
-    half the `n`² field or more among the loop ops `loop`."""
+    """Slices, pads, buffer writes of an update, and copies (by layout)
+    of half the `n`² field or more among the loop ops `loop`."""
     half = n * n // 2
     shapes = {(comp, op[0]): op[2] for comp, op in loop}
     big = collections.Counter()
     for comp, (name, opcode, shape, *_, rhs) in loop:
-        if opcode == "slice" and _elements(shape) >= half:
-            big["slice"] += 1
+        if opcode in ("slice", "pad") and _elements(shape) >= half:
+            big[opcode] += 1
         elif opcode == "dynamic-update-slice":
             operands = rhs.split(opcode + "(", 1)[1]
             update = re.findall(r"%([\w.\-]+)", operands)[1]
@@ -413,11 +413,14 @@ def test_hide_step_splices_into_whole_shard_interior(topo, n_local):
     back: no slice, and no buffer write of an update, is half the shard
     or more. The carry copy is gone: the first step of an iteration
     writes a temporary and the second the carry's own buffer, so
-    neither interior kernel writes the field it reads. One whole-shard
-    copy stays, put in by the compiler: the field in the transposed
-    layout (minor-to-major {0,1}) for the first step's column-edge
-    slabs; the second step reads its column edges from the row-major
-    field."""
+    neither interior kernel writes the field it reads. The slabs get
+    their ghosts as received slabs: no pad stages the shard into a
+    ghost-ringed buffer, and nothing of the padded shape is in the
+    loop. One whole-shard copy stays, put in by the compiler: the field
+    in the transposed layout (minor-to-major {0,1}) for the first
+    step's column edges (the exchange's sends and the column slabs'
+    windows); the second step reads its column edges from the
+    row-major field."""
     with pytest.MonkeyPatch.context() as mp:
         prog = _program(_compiled(topo, mp, "hide", n_local))
     assert [op[4] for op in _kernels(prog)].count("interior") == 2
@@ -426,12 +429,26 @@ def test_hide_step_splices_into_whole_shard_interior(topo, n_local):
     if n_local != N:
         return
     assert _whole_field_moves(loop, N) == {"copy 0,1": 1}
+    assert not [op for _, op in loop
+                if op[2].startswith(f"f32[{N + 2},{N + 2}]")]
 
 
-def test_serve_lane_step_fusion_names_the_lane_step(topo, one_chip):
-    """The serving cell's program, 8 lanes of 1024² on one chip: XLA
-    fuses each lane's stencil into the per-lane freeze select, and that
-    fusion names the lane step, not the loop (layer="driver")."""
+def _moves_signature(prog):
+    """The number of ops and of fusions, and the data-movement opcodes
+    counted, over every computation of a compiled program."""
+    ops, fusions = _ops_signature(prog)
+    moved = collections.Counter()
+    for (opcode, _), k in ops.items():
+        if opcode in ("pad", "dynamic-update-slice", "slice",
+                      "concatenate", "copy", "collective-permute-start"):
+            moved[opcode] += k
+    return sum(ops.values()), fusions, dict(moved)
+
+
+@pytest.fixture(scope="module")
+def serve_program(topo):
+    """The serving cell's program, 8 lanes of 1024² on one chip: the
+    batched advance of variant `shard`."""
     from rocm_mpi_tpu.config import DiffusionConfig
     from rocm_mpi_tpu.models import HeatDiffusion
 
@@ -445,7 +462,37 @@ def test_serve_lane_step_fusion_names_the_lane_step(topo, one_chip):
     tb = jax.ShapeDtypeStruct((8, n, n), jnp.float32, sharding=bg.sharding)
     cp = jax.ShapeDtypeStruct((n, n), jnp.float32, sharding=bg.aux_sharding)
     steps = jax.ShapeDtypeStruct((8,), jnp.int32, sharding=bg.batch_sharding)
-    prog = _program(advance.lower(tb, cp, steps, 8).compile().as_text())
+    return _program(advance.lower(tb, cp, steps, 8).compile().as_text())
+
+
+@pytest.mark.parametrize("program,want", [
+    ("serve", (213, 17, {"pad": 3, "dynamic-update-slice": 4, "slice": 16,
+                         "concatenate": 2, "copy": 8,
+                         "collective-permute-start": 4})),
+    ("perf-2x2", (228, 12, {"pad": 2, "dynamic-update-slice": 8,
+                            "slice": 16, "concatenate": 4, "copy": 10,
+                            "collective-permute-start": 8})),
+])
+def test_padded_exchange_programs_unchanged(topo, serve_program, program,
+                                            want):
+    """The callers that read the whole padded block keep `place_core` +
+    `exchange_into`, and compile to the program they compiled to before
+    the overlap step took its ghosts as slabs: the serving cell's
+    batched `shard` advance, and the 12288² `perf` scan on the 2x2 mesh
+    (its two whole-shard pads are its padded kernel operands)."""
+    if program == "serve":
+        prog = serve_program
+    else:
+        with pytest.MonkeyPatch.context() as mp:
+            prog = _program(_compiled(topo, mp, "perf", N))
+    assert _moves_signature(prog) == want
+
+
+def test_serve_lane_step_fusion_names_the_lane_step(serve_program):
+    """The serving cell's program, 8 lanes of 1024² on one chip: XLA
+    fuses each lane's stencil into the per-lane freeze select, and that
+    fusion names the lane step, not the loop (layer="driver")."""
+    prog = serve_program
     stencils = [
         op for comp in prog["bodies"] for op in prog["comps"][comp]
         if op[1] == "fusion" and {"multiply", "select"} <= {

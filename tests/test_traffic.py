@@ -163,6 +163,23 @@ ENTRY %main (p0: f32[2,8]) -> f32[2,8] {
         geo = doc["geometry"]
         assert geo["dims"] == [2, 1] and geo["local"] >= 16
 
+    @pytest.mark.parametrize("row", ["overlap", "batched-hide2"])
+    def test_staged_overlap_fails_the_gate(self, padded_splice, row):
+        """The overlap step that staged the shard into a ghost-ringed
+        copy for its slabs (`padded_splice`) is over the committed
+        budget on the gate geometry; the step as it is, under it."""
+        from rocm_mpi_tpu.perf.traffic import audit_batched, audit_variants
+
+        def audited():
+            rows = audit_variants() if row == "overlap" else audit_batched()
+            (r,) = [r for r in rows if r.variant == row]
+            return r
+
+        assert audited().ok
+        padded_splice()
+        staged = audited()
+        assert staged.ratio > staged.budget and not staged.ok
+
     def test_audit_emits_traffic_annotations(self, tmp_path):
         # step.traffic facts land in the telemetry stream when enabled.
         from rocm_mpi_tpu import telemetry
@@ -339,6 +356,62 @@ class TestExchangeInto:
         np.testing.assert_array_equal(
             np.asarray(direct), np.asarray(composed)
         )
+
+    @pytest.mark.parametrize("shape,dims,width,wire", [
+        ((8, 8), (2, 2), 1, "f32"),
+        ((12, 10), (2, 2), 2, "bf16"),
+        ((12, 8), (2, 1), 1, "f32"),
+        ((8, 8, 4), (2, 2, 1), 2, "f32"),
+    ], ids=["2x2", "2x2-w2-bf16", "2x1", "3d-w2"])
+    def test_slabs_rebuild_exchange_halo(self, shape, dims, width, wire):
+        """The received slabs carry every ghost of `exchange_halo`,
+        corners included: placed by `exchange_into` they give its padded
+        block, and `ghosted_window` gives any window of it — the whole
+        block, a corner window, a thin slab's window — from the slabs
+        and the unpadded block alone."""
+        import jax
+        import jax.numpy as jnp
+
+        from rocm_mpi_tpu.parallel import (
+            exchange_halo,
+            exchange_into,
+            exchange_slabs,
+            ghosted_window,
+            init_global_grid,
+            place_core,
+        )
+        from rocm_mpi_tpu.utils.compat import shard_map
+
+        grid = init_global_grid(*shape, dims=dims)
+        rng = np.random.default_rng(5)
+        x = jax.device_put(jnp.asarray(rng.uniform(-1, 1, shape)),
+                           grid.sharding)
+        local, w = grid.local_shape, width
+        windows = [
+            tuple((-w, n + w) for n in local),
+            tuple((-w, 2) for n in local),
+            tuple((n - 1, n + w) for n in local),
+            ((-1, 3),) + tuple((-w, n + 1) for n in local[1:]),
+        ]
+
+        @jax.jit
+        def same(x):
+            def local_check(b):
+                padded = exchange_halo(b, grid, width=w, wire_mode=wire)
+                slabs = exchange_slabs(b, grid, width=w, wire_mode=wire)
+                ok = jnp.array_equal(
+                    padded, exchange_into(place_core(b, w), grid, w,
+                                          wire_mode=wire))
+                for win in windows:
+                    idx = tuple(slice(lo + w, hi + w) for lo, hi in win)
+                    ok &= jnp.array_equal(
+                        padded[idx], ghosted_window(b, slabs, win, w))
+                return jnp.full((1,) * b.ndim, ok)
+
+            return shard_map(local_check, mesh=grid.mesh,
+                             in_specs=grid.spec, out_specs=grid.spec)(x)
+
+        assert np.asarray(same(x)).all()
 
     def test_wide_halo_corners_3d(self):
         # Width-2 ghosts on a 3D mesh: every corner/edge region of the
