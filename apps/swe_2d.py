@@ -77,20 +77,13 @@ def make_parser():
     return p
 
 
-def main(argv=None) -> int:
-    args = make_parser().parse_args(argv)
-    jax = setup_jax(args)
-
-    import jax.numpy as jnp
-
-    from rocm_mpi_tpu.models.swe import SWEConfig, ShallowWater
-    from rocm_mpi_tpu.parallel import gather_to_host0
-    from rocm_mpi_tpu.utils import viz
-    from rocm_mpi_tpu.utils.logging import log0
+def build_config(args):
+    """The app's SWEConfig from its parsed arguments."""
+    from rocm_mpi_tpu.models.swe import SWEConfig
 
     dims = tuple(int(d) for d in args.dims.split(",")) if args.dims else None
     shape = (args.nx, args.ny) + ((args.nz,) if args.nz > 1 else ())
-    cfg = SWEConfig(
+    return SWEConfig(
         global_shape=shape,
         lengths=(10.0,) * len(shape),
         nt=args.nt,
@@ -99,6 +92,20 @@ def main(argv=None) -> int:
         dims=dims,
         wire_mode=args.wire_mode,
     )
+
+
+def main(argv=None) -> int:
+    args = make_parser().parse_args(argv)
+    jax = setup_jax(args)
+
+    import jax.numpy as jnp
+
+    from rocm_mpi_tpu.models.swe import ShallowWater
+    from rocm_mpi_tpu.parallel import gather_to_host0
+    from rocm_mpi_tpu.utils import viz
+    from rocm_mpi_tpu.utils.logging import log0
+
+    cfg = build_config(args)
     model = ShallowWater(cfg)
     grid = model.grid
     log0(
@@ -172,7 +179,7 @@ def main(argv=None) -> int:
         f"mass drift = {abs(mass - mass0) / abs(mass0):.3e} "
         "(closed basin: conserved up to storage-dtype rounding)"
     )
-    if args.vis and len(shape) != 2:
+    if args.vis and cfg.ndim != 2:
         log0("--vis is 2D-only (heatmap); skipping the artifact")
         args.vis = False
     h_v = (

@@ -29,7 +29,9 @@ Variants mirror the flagship's ladder:
   "ap"   — global-array jnp rolls (GSPMD partitions; wraparound reads the
            opposite wall face, which the masks hold at 0 — exact).
   "perf" — shard_map + one exchange of the full state + the whole-block
-           Pallas padded kernel (ops.swe_kernels.swe_step_padded_pallas).
+           Pallas padded kernel (ops.swe_kernels.swe_step_padded_pallas);
+           on one device, the row-striped kernel on the unpadded state
+           with no exchange (ops.swe_kernels.masked_swe_step).
   "hide" — perf's kernel on the boundary-slab/interior overlap
            decomposition, pytree state through parallel.overlap.
 Plus run_deep (width-k ghost exchange of all fields once per k steps) and
@@ -51,7 +53,7 @@ from rocm_mpi_tpu.utils.compat import shard_map
 from rocm_mpi_tpu.config import DTYPES
 from rocm_mpi_tpu.models.driver import Driver, Result, effective_block_steps
 from rocm_mpi_tpu.ops.diffusion import gaussian_ic
-from rocm_mpi_tpu.ops.swe_kernels import masked_swe_step, swe_coeffs
+from rocm_mpi_tpu.ops.swe_kernels import rolled_swe_step, swe_coeffs
 from rocm_mpi_tpu.parallel.halo import exchange_halo
 
 
@@ -189,7 +191,7 @@ class ShallowWater(Driver):
         if variant == "ap":
 
             def step(s, Mus):
-                return masked_swe_step(*s, Mus, cH, cg)
+                return rolled_swe_step(*s, Mus, cH, cg)
 
             return step, None
         if variant == "shard":
@@ -224,7 +226,18 @@ class ShallowWater(Driver):
 
             return step, None
         if variant == "perf":
-            from rocm_mpi_tpu.ops.swe_kernels import swe_step_padded_pallas
+            from rocm_mpi_tpu.ops.swe_kernels import (
+                masked_swe_step,
+                swe_step_padded_pallas,
+            )
+
+            if grid.nprocs == 1:
+                # Unsharded: no neighbours, the block edge IS the global
+                # wall — one striped kernel a step, no exchange, no pad.
+                def step(s, Mus):
+                    return masked_swe_step(*s, Mus, cH, cg)
+
+                return step, None
 
             def step(s, Mus):
                 h, us = s
@@ -315,7 +328,7 @@ class ShallowWater(Driver):
 
             def step(s, Mus):
                 return jax.vmap(
-                    lambda h, us: masked_swe_step(h, us, Mus, cH, cg),
+                    lambda h, us: rolled_swe_step(h, us, Mus, cH, cg),
                     in_axes=(0, 0),
                 )(*s)
 

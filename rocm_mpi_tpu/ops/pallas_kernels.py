@@ -955,11 +955,13 @@ def _tb_kernel(Tu_ref, Tc_ref, Td_ref, Cu_ref, Cc_ref, Cd_ref, o_ref, *,
 
 def _stripe_ghost_specs(tm, g, n0, rest):
     """(core, gup, gdn) BlockSpecs shared by the ghost-block stripe
-    pipelines (_tb_kernel and _per_step_kernel): core stripe i (tm rows)
-    plus the clamped g-row ghost blocks above/below it. The domain-edge
-    clamps re-read an interior block; the kernels zero those via the
-    i==0 / i==n-1 selects."""
+    pipelines (_tb_kernel, _per_step_kernel and the shallow-water
+    sweep): core stripe i (tm rows) plus the clamped g-row ghost blocks
+    above/below it. The domain-edge clamps re-read an interior block;
+    the kernels zero those via the i==0 / i==n-1 selects. A last g-row
+    block past n0 is partial."""
     r = tm // g
+    n_ghost = -(-n0 // g)
     zeros = (0,) * len(rest)
     core = pl.BlockSpec(
         (tm,) + rest, lambda i: (i,) + zeros, memory_space=pltpu.VMEM
@@ -971,7 +973,7 @@ def _stripe_ghost_specs(tm, g, n0, rest):
     )
     gdn = pl.BlockSpec(
         (g,) + rest,
-        lambda i: (jnp.minimum((i + 1) * r, n0 // g - 1),) + zeros,
+        lambda i: (jnp.minimum((i + 1) * r, n_ghost - 1),) + zeros,
         memory_space=pltpu.VMEM,
     )
     return core, gup, gdn

@@ -26,8 +26,10 @@ algebraic time-reversibility (the update has a closed-form inverse).
 
 The roll form below is exact even ON the global array: jnp.roll wraparound
 brings exactly the opposite wall face, which the masks hold at 0 — so one
-definition (`masked_swe_step`) serves the ap variant, the VMEM-resident
-multi-step kernel, and the deep-halo sweep fallback.
+definition (`rolled_swe_step`) serves the ap variant, the VMEM-resident
+multi-step kernel, and the deep-halo sweep fallback. `masked_swe_step` is
+the one-chip perf step: the same update as one row-striped Pallas sweep
+of the unpadded state, with zero ghosts at the domain edge.
 """
 
 from __future__ import annotations
@@ -41,10 +43,13 @@ from rocm_mpi_tpu.utils.compat import pallas_tpu as pltpu
 
 from rocm_mpi_tpu.ops.pallas_kernels import (
     _VMEM_BLOCK_BUDGET_BYTES,
+    _compute_itemsize,
     _compute_nbytes,
     _interpret_default,
     _out_struct,
+    _stripe_ghost_specs,
     _supports_compiled,
+    _tiled_elems,
     _upcast_for_compute,
 )
 
@@ -57,7 +62,7 @@ def swe_coeffs(dt, spacing, H, g):
     return cH, cg
 
 
-def masked_swe_step(h, us, Mus, cH, cg):
+def rolled_swe_step(h, us, Mus, cH, cg):
     """One forward-backward SWE step (plain jnp rolls) — THE single
     definition of the update, shared by the ap variant (global arrays:
     wraparound brings the opposite wall face, held 0 by the masks), the
@@ -175,6 +180,126 @@ def swe_step_padded_pallas(Sp, Mus, consts, dt, spacing, interpret=None):
     return tuple(outs)
 
 
+# ---------------------------------------------------------------------------
+# One-chip per-step sweep: the unpadded state in row stripes, one kernel a
+# step — the perf step of an unsharded grid, with no exchange and no pad.
+# ---------------------------------------------------------------------------
+
+# Rows of a neighbour block: one f32 sublane tile, the least block a
+# stripe's row above or below can come in.
+_SWE_GHOST_ROWS = 8
+# Scoped VMEM the striped sweep's pipeline buffers may take: per stripe
+# 8 stripe-high blocks (h, u, v, Mu, Mv in; h', u', v' out) and 4 ghost
+# blocks, each double-buffered. The compiler adds its own scratch on top
+# (_SWE_SCRATCH_BYTES); v5e has 128 MiB of VMEM.
+_SWE_STRIPE_BUFFER_BYTES = 56 * 1024 * 1024
+_SWE_SCRATCH_BYTES = 16 * 1024 * 1024
+
+
+def _swe_striped_kernel(h_ref, hd_ref, uu_ref, u_ref, ud_ref, v_ref, vd_ref,
+                        mu_ref, mv_ref, oh_ref, ou_ref, ov_ref, *, cH, cg,
+                        tm, n0):
+    """One forward-backward step of output stripe i, rows [i·tm, i·tm+tm).
+
+    h' is computed on the stripe and the g rows below it (the ghost
+    blocks hd, ud, vd), so u' takes its forward difference of h' inside
+    the stripe, as _swe_padded_math does on its core-plus-high-pad box;
+    u's backward difference takes its row above from uu. Ghost blocks
+    past the domain's edge read zeros, and so do the shifted reads across
+    the lane axis's edges: the zero-ghost convention. Where the last
+    stripe is partial, its rows past the domain read zeros too, since the
+    row below the last feeds the wall face's u', which Mu multiplies by
+    0, and 0·NaN is not 0."""
+    g = _SWE_GHOST_ROWS
+    i = pl.program_id(0)
+    first = i == 0
+    last = i == pl.num_programs(0) - 1
+    zg = jnp.zeros(hd_ref.shape, hd_ref.dtype)
+    H = jnp.concatenate([h_ref[:], jnp.where(last, zg, hd_ref[:])], 0)
+    U = jnp.concatenate([jnp.where(first, zg, uu_ref[:]), u_ref[:],
+                         jnp.where(last, zg, ud_ref[:])], 0)
+    V = jnp.concatenate([v_ref[:], jnp.where(last, zg, vd_ref[:])], 0)
+    H, U, V, Mu, Mv = _upcast_for_compute(H, U, V, mu_ref[:], mv_ref[:])
+
+    # Mosaic refuses a slice of an iota: each shape takes its own.
+    def iota(shape, axis):
+        return lax.broadcasted_iota(jnp.int32, shape, axis)
+
+    if n0 % tm:
+        inside = i * tm + iota(H.shape, 0) < n0
+        H, V = jnp.where(inside, H, 0.0), jnp.where(inside, V, 0.0)
+        U = jnp.where(i * tm - g + iota(U.shape, 0) < n0, U, 0.0)
+    n1 = H.shape[1]
+    # h' on rows [i·tm, i·tm+tm+g): the operation order of rolled_swe_step.
+    U_c = U[g:]
+    U_lo = jnp.roll(U, 1, 0)[g:]
+    V_lo = jnp.where(iota(V.shape, 1) == 0, 0.0, jnp.roll(V, 1, 1))
+    Hn = H - (cH[0] * (U_c - U_lo) + cH[1] * (V - V_lo))
+    h_new = Hn[:tm]
+    h_dn = jnp.roll(Hn, -1, 0)[:tm]
+    h_rt = jnp.where(iota(h_new.shape, 1) == n1 - 1, 0.0,
+                     jnp.roll(h_new, -1, 1))
+    oh_ref[:] = h_new.astype(oh_ref.dtype)
+    ou_ref[:] = (Mu * (U_c[:tm] - cg[0] * (h_dn - h_new))).astype(
+        ou_ref.dtype)
+    ov_ref[:] = (Mv * (V[:tm] - cg[1] * (h_rt - h_new))).astype(
+        ov_ref.dtype)
+
+
+def _swe_stripe_height(n0: int, row_bytes: int) -> int:
+    """The tallest stripe (a multiple of the ghost rows, and no taller
+    than the grid) whose pipeline buffers fit _SWE_STRIPE_BUFFER_BYTES;
+    taller stripes read their ghost rows less often."""
+    g = _SWE_GHOST_ROWS
+    fit = (_SWE_STRIPE_BUFFER_BYTES // (2 * row_bytes) - 4 * g) // 8
+    return max(g, min(fit // g * g, -(-n0 // g) * g))
+
+
+def masked_swe_step(h, us, Mus, cH, cg, interpret=None):
+    """One forward-backward step of an unsharded state as one Pallas
+    program, named `masked_swe_step`: the perf step of a one-chip grid.
+
+    The 2D state is read once and written once in row stripes
+    (_swe_stripe_height; the last may be partial), with no pad and no
+    exchange: each stripe takes its neighbour rows from 8-row blocks of
+    the stripes beside it, the ghost blocks of pallas_kernels.masked_step
+    (_stripe_ghost_specs), and the walls from the face masks `Mus`
+    (_swe_striped_kernel). Other ranks, grids of fewer than 8 rows and
+    f64 on the chip take rolled_swe_step, the same update in jnp on the
+    global arrays. Returns (h', us')."""
+    if interpret is None:
+        interpret = _interpret_default()
+    g = _SWE_GHOST_ROWS
+    if (h.ndim != 2 or h.shape[0] < g
+            or (not _supports_compiled(h.dtype) and not interpret)):
+        return rolled_swe_step(h, us, Mus, cH, cg)
+    for arr in (*us, *Mus):
+        if arr.shape != h.shape:
+            raise ValueError(
+                f"all SWE fields share one shape: h {h.shape} vs {arr.shape}"
+            )
+    n0, n1 = h.shape
+    row_bytes = _compute_itemsize(h.dtype) * _tiled_elems((n1,))
+    tm = _swe_stripe_height(n0, row_bytes)
+    core, up, dn = _stripe_ghost_specs(tm, g, n0, (n1,))
+    kernel = functools.partial(_swe_striped_kernel, cH=tuple(cH),
+                               cg=tuple(cg), tm=tm, n0=n0)
+    buffers = 2 * (8 * tm + 4 * g) * row_bytes
+    (u, v), (Mu, Mv) = us, Mus
+    h, u, v = pl.pallas_call(
+        kernel,
+        out_shape=tuple(_out_struct(h.shape, h) for _ in range(3)),
+        grid=(-(-n0 // tm),),
+        in_specs=[core, dn, up, core, dn, core, dn, core, core],
+        out_specs=(core, core, core),
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=buffers + _SWE_SCRATCH_BYTES),
+        interpret=interpret,
+        name="masked_swe_step",
+    )(h, h, u, u, u, v, v, Mu, Mv)
+    return h, (u, v)
+
+
 def _swe_multi_step_kernel(*refs, ndim, cH, cg, chunk):
     """`chunk` forward-backward steps with the whole state VMEM-resident
     (bf16 storage upcast to f32 for the chunk — one rounding per chunk,
@@ -187,7 +312,7 @@ def _swe_multi_step_kernel(*refs, ndim, cH, cg, chunk):
     h0, us0, Mus = vals[0], vals[1:n_state], vals[n_state:]
 
     def body(_, s):
-        return masked_swe_step(s[0], s[1], Mus, cH, cg)
+        return rolled_swe_step(s[0], s[1], Mus, cH, cg)
 
     h, us = lax.fori_loop(0, chunk, body, (h0, tuple(us0)), unroll=True)
     outs[0][:] = h.astype(outs[0].dtype)

@@ -445,7 +445,7 @@ def make_swe_deep_sweep(grid: GlobalGrid, k: int, dt, spacing, H,
 
     Local compute: the VMEM-resident masked multi-step kernel
     (ops.swe_kernels.swe_multi_step_masked) when the padded state fits,
-    else the identical-semantics jnp roll fallback (masked_swe_step — the
+    else the identical-semantics jnp roll fallback (rolled_swe_step — the
     one definition of the update)."""
     _validate_depth(grid, k, "sweep depth")
     wire.validate_mode(wire_mode)
@@ -455,7 +455,7 @@ def make_swe_deep_sweep(grid: GlobalGrid, k: int, dt, spacing, H,
         _compute_nbytes,
     )
     from rocm_mpi_tpu.ops.swe_kernels import (
-        masked_swe_step,
+        rolled_swe_step,
         swe_coeffs,
         swe_multi_step_masked,
     )
@@ -471,7 +471,7 @@ def make_swe_deep_sweep(grid: GlobalGrid, k: int, dt, spacing, H,
 
     def jnp_k_steps(h, us, Mus):
         for _ in range(k):
-            h, us = masked_swe_step(h, us, Mus, cH, cg)
+            h, us = rolled_swe_step(h, us, Mus, cH, cg)
         return h, us
 
     def local_prepare(hl):

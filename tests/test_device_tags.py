@@ -154,6 +154,25 @@ def test_shardy_keeps_data_movement_tags():
     assert _DATA_MOVEMENT <= _compiled_tags(shardy=True)
 
 
+def test_swe_one_chip_kernel_keeps_its_name():
+    """ShallowWater's one-chip perf step compiles its kernel under the
+    name `masked_swe_step` (interpret mode here keeps it in the ops'
+    op_name; the described TPU's custom call is pinned in
+    tests/test_tpu_compile.py), and the loop keeps its driver tag."""
+    from rocm_mpi_tpu.models.swe import SWEConfig, ShallowWater
+
+    m = ShallowWater(SWEConfig(global_shape=(48, 40), nt=8, warmup=0,
+                               dtype="f32", dims=(1, 1)),
+                     devices=jax.devices()[:1])
+    advance, _ = m.scan_advance_fn("perf")
+    a = _shape((48, 40), m.grid.sharding)
+    lowered = advance.lower(a, (a, a), (a, a), 8)
+    text = lowered.compile().as_text()
+    assert re.search(r'op_name="[^"]*masked_swe_step', text)
+    assert ("stablehlo.while", "layer", "driver") in _tagged_ops(
+        lowered.as_text())
+
+
 def _pallas_calls():
     for path in sorted(glob.glob(str(REPO / "rocm_mpi_tpu/**/*.py"),
                                  recursive=True)):

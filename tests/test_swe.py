@@ -308,3 +308,149 @@ def test_swe_bf16_rounding_is_per_kernel_not_per_step():
     }
     # 3 state in + 2 masks in + 3 state out = 8, whatever the unroll.
     assert counts[4] == counts[16] == 8, counts
+
+
+# ---- the one-chip perf step: masked_swe_step's striped sweep -------------
+
+# (shape, stripe rows): several whole stripes; a partial last stripe
+# (4 rows of 16, so the row below the last is past the domain); a grid of
+# one stripe taller than the grid; 8-row stripes over 5 rows at the end.
+STRIPES = {
+    "several": ((64, 40), 16),
+    "partial": ((68, 40), 16),
+    "one": ((20, 24), 32),
+    "thin": ((37, 130), 8),
+}
+
+
+def _stripes(monkeypatch, tm):
+    """masked_swe_step takes `tm`-row stripes whatever the grid."""
+    from rocm_mpi_tpu.ops import swe_kernels
+
+    monkeypatch.setattr(swe_kernels, "_swe_stripe_height",
+                        lambda n0, row_bytes: tm)
+
+
+def _seeded_state(shape, dtype, seed):
+    """(h, (u, v), (Mu, Mv)): h about 1 plus noise, u and v noise with
+    the wall faces exactly 0, the closed basin's face masks."""
+    rng = np.random.default_rng(seed)
+    Mu = np.ones(shape)
+    Mu[-1, :] = 0.0
+    Mv = np.ones(shape)
+    Mv[:, -1] = 0.0
+    h = 1.0 + rng.uniform(size=shape)
+    u = Mu * rng.uniform(-0.5, 0.5, size=shape)
+    v = Mv * rng.uniform(-0.5, 0.5, size=shape)
+    return tuple(jnp.asarray(a, dtype) for a in (h, u, v, Mu, Mv))
+
+
+def _kernel_steps(shape, dtype, seed, n):
+    from rocm_mpi_tpu.ops.swe_kernels import masked_swe_step
+
+    cfg = _cfg(shape=shape)
+    cH, cg = swe_coeffs(cfg.dt, cfg.spacing, cfg.H0, cfg.g)
+    h, u, v, Mu, Mv = _seeded_state(shape, dtype, seed)
+    state = (h, (u, v))
+    for _ in range(n):
+        state = masked_swe_step(*state, (Mu, Mv), cH, cg)
+    return cfg, _seeded_state(shape, dtype, seed), state
+
+
+@pytest.mark.parametrize("case", sorted(STRIPES))
+def test_masked_swe_step_matches_plain_reference(monkeypatch, case):
+    """f32, against the benchmark's plain reference (jnp, its own
+    operation order) over 20 steps. Tolerance: 4 roundings of the
+    largest field a step (the kernel folds dt·H/dx into one constant,
+    the reference divides), 4·n·eps32·max|field|, about 1e-5; a state
+    stored in bf16 is off by 2^-9 of it at once, so it fails (checked)."""
+    from benchmark.references import swe2d as reference
+
+    shape, tm = STRIPES[case]
+    _stripes(monkeypatch, tm)
+    n = 20
+    cfg, (h, u, v, Mu, Mv), (kh, (ku, kv)) = _kernel_steps(
+        shape, jnp.float32, 3, n)
+    config = {"global_shape": list(shape), "lengths": list(cfg.lengths),
+              "H0": cfg.H0, "g": cfg.g, "cfl": cfg.cfl}
+    ref = reference.advance_fn(config, n, jnp.float32)
+    rh, (ru, rv) = ref((h, (u, v)), (Mu, Mv))
+    tol = 4 * n * float(jnp.finfo(jnp.float32).eps) * float(jnp.max(h))
+    for got, want in ((kh, rh), (ku, ru), (kv, rv)):
+        assert float(jnp.max(jnp.abs(got - want))) <= tol
+    bf16 = jax.tree.map(lambda a: a.astype(jnp.bfloat16).astype(
+        jnp.float32), (h, (u, v)))
+    bh, _ = ref(bf16, (Mu, Mv))
+    assert float(jnp.max(jnp.abs(bh - rh))) > tol
+
+
+@pytest.mark.parametrize("case", sorted(STRIPES))
+def test_masked_swe_step_matches_numpy_oracle(monkeypatch, case):
+    """f64 in interpret mode, against the transparent numpy oracle over
+    25 steps, to rounding (rtol 1e-12, as the ap variant's own test)."""
+    shape, tm = STRIPES[case]
+    _stripes(monkeypatch, tm)
+    cfg, (h, u, v, *_), (kh, kus) = _kernel_steps(shape, jnp.float64, 5,
+                                                  25)
+    ref_h, ref_us = _numpy_fb(h, (u, v), cfg.dt, cfg.spacing, cfg.H0,
+                              cfg.g, 25)
+    np.testing.assert_allclose(np.asarray(kh), ref_h, rtol=1e-12)
+    for got, want in zip(kus, ref_us):
+        np.testing.assert_allclose(np.asarray(got), want, rtol=1e-12,
+                                   atol=1e-15)
+
+
+def test_swe_one_chip_perf_is_one_kernel_without_exchange(monkeypatch):
+    """On one device the perf step is masked_swe_step alone: no pad of
+    the state into a ghost-ringed buffer, one kernel a step."""
+    from rocm_mpi_tpu.parallel import halo
+
+    def no_exchange(*args, **kwargs):
+        raise AssertionError("the one-chip perf step exchanged a halo")
+
+    monkeypatch.setattr(halo, "exchange_halo", no_exchange)
+    monkeypatch.setattr("rocm_mpi_tpu.models.swe.exchange_halo",
+                        no_exchange)
+    model = ShallowWater(_cfg(shape=(48, 40), dtype="f32"),
+                         devices=jax.devices()[:1])
+    h, us = model.init_state()
+    text = str(jax.make_jaxpr(model.advance_fn("perf"))(
+        h, us, model.face_masks(), 4))
+    assert text.count("pallas_call") == 1
+    assert "masked_swe_step" in text
+    assert " pad" not in text
+
+
+@pytest.mark.parametrize("case", ["several", "partial"])
+@pytest.mark.parametrize("invariant", ["mass", "walls", "reversal"])
+def test_swe_one_chip_perf_invariants(monkeypatch, case, invariant):
+    """The workload's invariants on the one-chip perf path, striped:
+    exact mass conservation, wall faces exactly 0, and the closed-form
+    inverse running the state back to where it started."""
+    shape, tm = STRIPES[case]
+    _stripes(monkeypatch, tm)
+    n = 40
+    cfg = _cfg(shape=shape, nt=n, warmup=0)
+    model = ShallowWater(cfg, devices=jax.devices()[:1])
+    h0, u0, v0, Mu, Mv = _seeded_state(shape, jnp.float64, 11)
+    Mus = (Mu, Mv)
+    h, us = model.advance_fn("perf")(jnp.copy(h0), (jnp.copy(u0),
+                                                    jnp.copy(v0)), Mus, n)
+    if invariant == "mass":
+        mass0 = float(jnp.sum(h0))
+        assert abs(float(jnp.sum(h)) - mass0) <= 1e-13 * abs(mass0)
+    elif invariant == "walls":
+        np.testing.assert_array_equal(np.asarray(us[0])[-1, :], 0.0)
+        np.testing.assert_array_equal(np.asarray(us[1])[:, -1], 0.0)
+    else:
+        cH, cg = swe_coeffs(cfg.dt, cfg.spacing, cfg.H0, cfg.g)
+        for _ in range(n):
+            us = tuple(u + cg[a] * Mus[a] * (jnp.roll(h, -1, a) - h)
+                       for a, u in enumerate(us))
+            h = h + sum(cH[a] * (u - jnp.roll(u, 1, a))
+                        for a, u in enumerate(us))
+        np.testing.assert_allclose(np.asarray(h), np.asarray(h0),
+                                   rtol=1e-11, atol=1e-13)
+        for u, u_start in zip(us, (u0, v0)):
+            np.testing.assert_allclose(np.asarray(u), np.asarray(u_start),
+                                       atol=1e-12)

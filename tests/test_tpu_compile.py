@@ -500,3 +500,50 @@ def test_serve_lane_step_fusion_names_the_lane_step(serve_program):
             for inner in prog["comps"][f]}]
     assert stencils
     assert {op[3] for op in stencils} == {"lane_step"}
+
+
+# ---- shallow water's one-chip perf step ---------------------------------
+
+
+def _swe_perf_program(topo, mp, n):
+    """The compiled scan driver of the swe2d-12288-perf cell at n² on
+    one described chip: ShallowWater's perf variant, q = 10."""
+    from rocm_mpi_tpu.models.swe import SWEConfig, ShallowWater
+    from rocm_mpi_tpu.ops import swe_kernels
+
+    mp.setattr(swe_kernels, "_interpret_default", lambda: False)
+    model = ShallowWater(
+        SWEConfig(global_shape=(n, n), dtype="f32", dims=(1, 1), nt=100,
+                  warmup=10),
+        devices=topo.devices[:1],
+    )
+    advance, _ = model.scan_advance_fn("perf")
+    a = _sds((n, n), model.grid.sharding)
+    return _program(advance.lower(a, (a, a), (a, a), 100).compile()
+                    .as_text())
+
+
+def test_swe_perf_scan_is_one_striped_kernel_a_step(topo):
+    """The benchmark's 12288² SWE program: each step is one
+    `masked_swe_step` kernel, two to a scan iteration, and nothing of the
+    ghost-ringed 12290² shape is left: no exchange pads the state."""
+    with pytest.MonkeyPatch.context() as mp:
+        prog = _swe_perf_program(topo, mp, N)
+    kernels = _kernels(prog)
+    assert {op[0].rsplit(".", 1)[0] for op in kernels} == {"masked_swe_step"}
+    per_body = collections.Counter(
+        comp for comp in prog["bodies"] for op in prog["comps"][comp]
+        if op[0].rsplit(".", 1)[0] == "masked_swe_step")
+    assert list(per_body.values()) == [2]
+    assert not [op for ops in prog["comps"].values() for op in ops
+                if f"[{N + 2},{N + 2}]" in op[2]]
+
+
+@pytest.mark.parametrize("cell,ops", [("perf", 91), ("hide", 423)])
+def test_diffusion_cells_keep_their_op_counts(topo, cell, ops):
+    """The two 12288² diffusion programs that share the striped kernels'
+    launch code compile to as many ops as before the SWE kernel came."""
+    dims = (1, 1) if cell == "perf" else (2, 2)
+    with pytest.MonkeyPatch.context() as mp:
+        prog = _program(_compiled(topo, mp, cell, N, dims=dims))
+    assert _moves_signature(prog)[0] == ops

@@ -499,9 +499,9 @@ def test_swe_padded_kernel_compiled():
 
 def test_swe_vmem_multi_step_compiled():
     # The whole-loop-in-VMEM coupled multi-step, compiled, vs the jnp
-    # roll form (masked_swe_step — the one definition of the update).
+    # roll form (rolled_swe_step — the one definition of the update).
     from rocm_mpi_tpu.ops.swe_kernels import (
-        masked_swe_step,
+        rolled_swe_step,
         swe_coeffs,
         swe_multi_step,
     )
@@ -518,7 +518,7 @@ def test_swe_vmem_multi_step_compiled():
     cH, cg = swe_coeffs(dt, spacing, 1.0, 1.0)
     ref_h, ref_us = h0, us0
     for _ in range(16):
-        ref_h, ref_us = masked_swe_step(ref_h, ref_us, Mus, cH, cg)
+        ref_h, ref_us = rolled_swe_step(ref_h, ref_us, Mus, cH, cg)
     got_h, got_us = swe_multi_step(
         h0, us0, Mus, dt, spacing, 1.0, 1.0, 16, chunk=8
     )
