@@ -182,8 +182,13 @@ class ShallowWater(Driver):
         return SWERunResult(h=state[0], us=state[1], **window)
 
     def _step(self, variant: str):
-        """(`step((h, us), Mus) -> (h', us')`, None): the face masks `Mus`
-        are the step's operand as they are."""
+        """(`step((h, us), P) -> (h', us')`, prepare): `P` is the face
+        masks `Mus` as they are (`prepare` None), except on one device's
+        `perf` (and `hide`, which routes there), where `prepare` packs
+        them once a call into the 1-byte wall code
+        (ops.swe_kernels.pack_walls) that the striped kernel reads. That
+        path honours any 0/1 mask pattern, an interior wall or all ones
+        included."""
         cfg, grid = self.config, self.grid
         dt = cfg.dt
         cH, cg = swe_coeffs(dt, cfg.spacing, cfg.H0, cfg.g)
@@ -228,16 +233,18 @@ class ShallowWater(Driver):
         if variant == "perf":
             from rocm_mpi_tpu.ops.swe_kernels import (
                 masked_swe_step,
+                pack_walls,
                 swe_step_padded_pallas,
             )
 
             if grid.nprocs == 1:
                 # Unsharded: no neighbours, the block edge IS the global
-                # wall — one striped kernel a step, no exchange, no pad.
-                def step(s, Mus):
-                    return masked_swe_step(*s, Mus, cH, cg)
+                # wall — one striped kernel a step, no exchange, no pad,
+                # the walls read as the code packed once a call.
+                def step(s, walls):
+                    return masked_swe_step(*s, walls, cH, cg)
 
-                return step, None
+                return step, pack_walls
 
             def step(s, Mus):
                 h, us = s

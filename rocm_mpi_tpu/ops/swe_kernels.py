@@ -29,7 +29,8 @@ brings exactly the opposite wall face, which the masks hold at 0 — so one
 definition (`rolled_swe_step`) serves the ap variant, the VMEM-resident
 multi-step kernel, and the deep-halo sweep fallback. `masked_swe_step` is
 the one-chip perf step: the same update as one row-striped Pallas sweep
-of the unpadded state, with zero ghosts at the domain edge.
+of the unpadded state, with zero ghosts at the domain edge, reading its
+walls from the masks packed into a 1-byte code (`pack_walls`).
 """
 
 from __future__ import annotations
@@ -188,17 +189,44 @@ def swe_step_padded_pallas(Sp, Mus, consts, dt, spacing, interpret=None):
 # Rows of a neighbour block: one f32 sublane tile, the least block a
 # stripe's row above or below can come in.
 _SWE_GHOST_ROWS = 8
+# Rows of the wall code's sublane tile: a 1-byte block's stripe height is
+# a multiple of it on the chip.
+_SWE_CODE_ROWS = 32
+# The tallest stripe: at 12288 columns a v5e steps 6.14 / 6.00 / 5.93 /
+# 5.86 ms at 64 / 96 / 128 / 192 rows (fewer ghost rows read), but the
+# kernel's own scoped VMEM grows faster than its stripe (6 MiB at 128
+# rows, 18 at 192 over 8192 columns, past _SWE_SCRATCH_BYTES).
+_SWE_STRIPE_ROWS = 128
 # Scoped VMEM the striped sweep's pipeline buffers may take: per stripe
-# 8 stripe-high blocks (h, u, v, Mu, Mv in; h', u', v' out) and 4 ghost
-# blocks, each double-buffered. The compiler adds its own scratch on top
-# (_SWE_SCRATCH_BYTES); v5e has 128 MiB of VMEM.
-_SWE_STRIPE_BUFFER_BYTES = 56 * 1024 * 1024
+# 6 stripe-high f32 blocks (h, u, v in; h', u', v' out), 1 stripe-high
+# 1-byte block (the wall code) and 4 ghost blocks, each double-buffered;
+# 128-row stripes up to 12288 columns. The compiler adds its own scratch
+# on top (_SWE_SCRATCH_BYTES); v5e has 128 MiB of VMEM.
+_SWE_STRIPE_BUFFER_BYTES = 80 * 1024 * 1024
 _SWE_SCRATCH_BYTES = 16 * 1024 * 1024
 
 
+def pack_walls(Mus):
+    """The face masks as one 1-byte wall code: bit a is set where face
+    mask a is nonzero (open), clear where it is 0 (a wall). Masks are
+    exactly 0.0 or 1.0 (models.swe.face_masks), so the code holds them
+    whole; `masked_swe_step` reads it in their place, once a step, at a
+    byte a point instead of 4·ndim."""
+    code = None
+    for a, M in enumerate(Mus):
+        bit = (M != 0).astype(jnp.int8) << a
+        code = bit if code is None else code | bit
+    return code
+
+
+def unpack_walls(walls, ndim, dtype):
+    """pack_walls' inverse: the `ndim` face masks, 0.0 or 1.0 in
+    `dtype`, of the wall code `walls`."""
+    return tuple(((walls >> a) & 1).astype(dtype) for a in range(ndim))
+
+
 def _swe_striped_kernel(h_ref, hd_ref, uu_ref, u_ref, ud_ref, v_ref, vd_ref,
-                        mu_ref, mv_ref, oh_ref, ou_ref, ov_ref, *, cH, cg,
-                        tm, n0):
+                        w_ref, oh_ref, ou_ref, ov_ref, *, cH, cg, tm, n0):
     """One forward-backward step of output stripe i, rows [i·tm, i·tm+tm).
 
     h' is computed on the stripe and the g rows below it (the ghost
@@ -206,10 +234,14 @@ def _swe_striped_kernel(h_ref, hd_ref, uu_ref, u_ref, ud_ref, v_ref, vd_ref,
     the stripe, as _swe_padded_math does on its core-plus-high-pad box;
     u's backward difference takes its row above from uu. Ghost blocks
     past the domain's edge read zeros, and so do the shifted reads across
-    the lane axis's edges: the zero-ghost convention. Where the last
-    stripe is partial, its rows past the domain read zeros too, since the
-    row below the last feeds the wall face's u', which Mu multiplies by
-    0, and 0·NaN is not 0."""
+    the lane axis's edges: the zero-ghost convention. h' one column
+    past the domain is a zero-ghost cell's, cH1·v of the last column, as
+    h' one row past it is, so an open high face steps as in the padded
+    form (a wall face is 0 there, so h' past it is too). Where the last
+    stripe is partial, its rows past the domain read zeros too, since
+    the row below the last feeds h' past the domain. The walls come from
+    the wall code w (pack_walls): u' and v' are selected to 0 where
+    bit 0 or bit 1 is clear, so a wall face is exactly 0."""
     g = _SWE_GHOST_ROWS
     i = pl.program_id(0)
     first = i == 0
@@ -219,7 +251,8 @@ def _swe_striped_kernel(h_ref, hd_ref, uu_ref, u_ref, ud_ref, v_ref, vd_ref,
     U = jnp.concatenate([jnp.where(first, zg, uu_ref[:]), u_ref[:],
                          jnp.where(last, zg, ud_ref[:])], 0)
     V = jnp.concatenate([v_ref[:], jnp.where(last, zg, vd_ref[:])], 0)
-    H, U, V, Mu, Mv = _upcast_for_compute(H, U, V, mu_ref[:], mv_ref[:])
+    H, U, V = _upcast_for_compute(H, U, V)
+    code = w_ref[:].astype(jnp.int32)
 
     # Mosaic refuses a slice of an iota: each shape takes its own.
     def iota(shape, axis):
@@ -237,66 +270,77 @@ def _swe_striped_kernel(h_ref, hd_ref, uu_ref, u_ref, ud_ref, v_ref, vd_ref,
     Hn = H - (cH[0] * (U_c - U_lo) + cH[1] * (V - V_lo))
     h_new = Hn[:tm]
     h_dn = jnp.roll(Hn, -1, 0)[:tm]
-    h_rt = jnp.where(iota(h_new.shape, 1) == n1 - 1, 0.0,
+    V_c = V[:tm]
+    h_rt = jnp.where(iota(h_new.shape, 1) == n1 - 1, cH[1] * V_c,
                      jnp.roll(h_new, -1, 1))
     oh_ref[:] = h_new.astype(oh_ref.dtype)
-    ou_ref[:] = (Mu * (U_c[:tm] - cg[0] * (h_dn - h_new))).astype(
-        ou_ref.dtype)
-    ov_ref[:] = (Mv * (V[:tm] - cg[1] * (h_rt - h_new))).astype(
-        ov_ref.dtype)
+    ou_ref[:] = jnp.where((code & 1) != 0,
+                          U_c[:tm] - cg[0] * (h_dn - h_new),
+                          0.0).astype(ou_ref.dtype)
+    ov_ref[:] = jnp.where((code & 2) != 0,
+                          V_c - cg[1] * (h_rt - h_new),
+                          0.0).astype(ov_ref.dtype)
 
 
-def _swe_stripe_height(n0: int, row_bytes: int) -> int:
-    """The tallest stripe (a multiple of the ghost rows, and no taller
-    than the grid) whose pipeline buffers fit _SWE_STRIPE_BUFFER_BYTES;
-    taller stripes read their ghost rows less often."""
-    g = _SWE_GHOST_ROWS
-    fit = (_SWE_STRIPE_BUFFER_BYTES // (2 * row_bytes) - 4 * g) // 8
-    return max(g, min(fit // g * g, -(-n0 // g) * g))
+def _swe_stripe_height(n0: int, row_bytes: int, code_row_bytes: int) -> int:
+    """The tallest stripe, up to _SWE_STRIPE_ROWS (a multiple of the wall
+    code's tile rows, and no taller than the grid rounded up to them),
+    whose pipeline buffers fit _SWE_STRIPE_BUFFER_BYTES; taller stripes
+    read their ghost rows less often."""
+    g, c = _SWE_GHOST_ROWS, _SWE_CODE_ROWS
+    fit = ((_SWE_STRIPE_BUFFER_BYTES // 2 - 4 * g * row_bytes)
+           // (6 * row_bytes + code_row_bytes))
+    return max(c, min(fit // c * c, _SWE_STRIPE_ROWS, -(-n0 // c) * c))
 
 
-def masked_swe_step(h, us, Mus, cH, cg, interpret=None):
+def masked_swe_step(h, us, walls, cH, cg, interpret=None):
     """One forward-backward step of an unsharded state as one Pallas
     program, named `masked_swe_step`: the perf step of a one-chip grid.
 
-    The 2D state is read once and written once in row stripes
-    (_swe_stripe_height; the last may be partial), with no pad and no
-    exchange: each stripe takes its neighbour rows from 8-row blocks of
-    the stripes beside it, the ghost blocks of pallas_kernels.masked_step
-    (_stripe_ghost_specs), and the walls from the face masks `Mus`
-    (_swe_striped_kernel). Other ranks, grids of fewer than 8 rows and
-    f64 on the chip take rolled_swe_step, the same update in jnp on the
-    global arrays. Returns (h', us')."""
+    `walls` is the face masks' 1-byte wall code (pack_walls), made once
+    a call outside the step loop: the kernel reads a byte a point for
+    the walls, not two f32 masks. Any 0/1 mask pattern is honoured as
+    handed, an interior wall or all ones included. The 2D state is read
+    once and written once in row stripes (_swe_stripe_height; the last
+    may be partial), with no pad and no exchange: each stripe takes its
+    neighbour rows from 8-row blocks of the stripes beside it, the ghost
+    blocks of pallas_kernels.masked_step (_stripe_ghost_specs), and its
+    walls from the code's stripe (_swe_striped_kernel). Other ranks,
+    grids of fewer than 8 rows and f64 on the chip take rolled_swe_step,
+    the same update in jnp on the global arrays, with the masks unpacked
+    from the code. Returns (h', us')."""
     if interpret is None:
         interpret = _interpret_default()
     g = _SWE_GHOST_ROWS
     if (h.ndim != 2 or h.shape[0] < g
             or (not _supports_compiled(h.dtype) and not interpret)):
+        Mus = unpack_walls(walls, h.ndim, h.dtype)
         return rolled_swe_step(h, us, Mus, cH, cg)
-    for arr in (*us, *Mus):
+    for arr in (*us, walls):
         if arr.shape != h.shape:
             raise ValueError(
                 f"all SWE fields share one shape: h {h.shape} vs {arr.shape}"
             )
     n0, n1 = h.shape
     row_bytes = _compute_itemsize(h.dtype) * _tiled_elems((n1,))
-    tm = _swe_stripe_height(n0, row_bytes)
+    code_row_bytes = walls.dtype.itemsize * _tiled_elems((n1,))
+    tm = _swe_stripe_height(n0, row_bytes, code_row_bytes)
     core, up, dn = _stripe_ghost_specs(tm, g, n0, (n1,))
     kernel = functools.partial(_swe_striped_kernel, cH=tuple(cH),
                                cg=tuple(cg), tm=tm, n0=n0)
-    buffers = 2 * (8 * tm + 4 * g) * row_bytes
-    (u, v), (Mu, Mv) = us, Mus
+    buffers = 2 * ((6 * tm + 4 * g) * row_bytes + tm * code_row_bytes)
+    u, v = us
     h, u, v = pl.pallas_call(
         kernel,
         out_shape=tuple(_out_struct(h.shape, h) for _ in range(3)),
         grid=(-(-n0 // tm),),
-        in_specs=[core, dn, up, core, dn, core, dn, core, core],
+        in_specs=[core, dn, up, core, dn, core, dn, core],
         out_specs=(core, core, core),
         compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=buffers + _SWE_SCRATCH_BYTES),
         interpret=interpret,
         name="masked_swe_step",
-    )(h, h, u, u, u, v, v, Mu, Mv)
+    )(h, h, u, u, u, v, v, walls)
     return h, (u, v)
 
 

@@ -328,7 +328,7 @@ def _stripes(monkeypatch, tm):
     from rocm_mpi_tpu.ops import swe_kernels
 
     monkeypatch.setattr(swe_kernels, "_swe_stripe_height",
-                        lambda n0, row_bytes: tm)
+                        lambda n0, *row_bytes: tm)
 
 
 def _seeded_state(shape, dtype, seed):
@@ -346,14 +346,15 @@ def _seeded_state(shape, dtype, seed):
 
 
 def _kernel_steps(shape, dtype, seed, n):
-    from rocm_mpi_tpu.ops.swe_kernels import masked_swe_step
+    from rocm_mpi_tpu.ops.swe_kernels import masked_swe_step, pack_walls
 
     cfg = _cfg(shape=shape)
     cH, cg = swe_coeffs(cfg.dt, cfg.spacing, cfg.H0, cfg.g)
     h, u, v, Mu, Mv = _seeded_state(shape, dtype, seed)
+    walls = pack_walls((Mu, Mv))
     state = (h, (u, v))
     for _ in range(n):
-        state = masked_swe_step(*state, (Mu, Mv), cH, cg)
+        state = masked_swe_step(*state, walls, cH, cg)
     return cfg, _seeded_state(shape, dtype, seed), state
 
 
@@ -454,3 +455,79 @@ def test_swe_one_chip_perf_invariants(monkeypatch, case, invariant):
         for u, u_start in zip(us, (u0, v0)):
             np.testing.assert_allclose(np.asarray(u), np.asarray(u_start),
                                        atol=1e-12)
+
+
+# ---- the wall code: the one-chip perf step honours the masks it is handed
+
+
+@pytest.mark.parametrize("mu,mv", [(0, 0), (1, 0), (0, 1), (1, 1)])
+def test_wall_code_round_trips(mu, mv):
+    """pack_walls then unpack_walls gives back each of the four (Mu, Mv)
+    combinations, from a one-byte code."""
+    from rocm_mpi_tpu.ops.swe_kernels import pack_walls, unpack_walls
+
+    Mu = jnp.full((5, 7), float(mu), jnp.float32).at[2, 3].set(1.0 - mu)
+    Mv = jnp.full((5, 7), float(mv), jnp.float32).at[2, 3].set(1.0 - mv)
+    walls = pack_walls((Mu, Mv))
+    assert walls.dtype.itemsize == 1
+    assert int(walls[0, 0]) == mu + 2 * mv
+    for got, want in zip(unpack_walls(walls, 2, jnp.float32), (Mu, Mv)):
+        assert got.dtype == jnp.float32
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
+def _walls(kind, shape):
+    """Face masks: all ones (every high wall open), or the closed basin
+    with an extra interior wall row in Mu and column in Mv."""
+    Mu, Mv = np.ones(shape), np.ones(shape)
+    if kind == "interior":
+        Mu[-1, :] = Mu[shape[0] // 3, :] = 0.0
+        Mv[:, -1] = Mv[:, shape[1] // 2] = 0.0
+    return Mu, Mv
+
+
+def _rolled_zero_ghost(h, us, Mus, cH, cg, n):
+    """`n` steps of rolled_swe_step on the state padded each step by a
+    ring of zeros (masks 0 on the ring), cropped back: the zero-ghost
+    convention at every domain edge, whatever the masks hold."""
+    from rocm_mpi_tpu.ops.swe_kernels import rolled_swe_step
+
+    core = (slice(1, -1),) * h.ndim
+    Mps = tuple(jnp.pad(M, 1) for M in Mus)
+    for _ in range(n):
+        hp, ups = rolled_swe_step(jnp.pad(h, 1),
+                                  tuple(jnp.pad(u, 1) for u in us), Mps,
+                                  cH, cg)
+        h, us = hp[core], tuple(u[core] for u in ups)
+    return h, us
+
+
+@pytest.mark.parametrize("case", ["several", "partial"])
+@pytest.mark.parametrize("kind", ["open", "interior"])
+def test_swe_one_chip_perf_honours_the_masks(monkeypatch, case, kind):
+    """The one-chip perf step (its `prepare` packs the masks, the striped
+    kernel reads the code) equals rolled_swe_step given the same float
+    masks, with every wall open and with an interior wall, over 15
+    steps (f64, rtol 1e-12, as the numpy-oracle test)."""
+    shape, tm = STRIPES[case]
+    _stripes(monkeypatch, tm)
+    n = 15
+    cfg = _cfg(shape=shape, nt=n, warmup=0)
+    cH, cg = swe_coeffs(cfg.dt, cfg.spacing, cfg.H0, cfg.g)
+    rng = np.random.default_rng(13)
+    Mus = tuple(jnp.asarray(M) for M in _walls(kind, shape))
+    h0 = jnp.asarray(1.0 + rng.uniform(size=shape))
+    us0 = tuple(M * jnp.asarray(rng.uniform(-0.5, 0.5, size=shape))
+                for M in Mus)
+    model = ShallowWater(cfg, devices=jax.devices()[:1])
+    h, us = model.advance_fn("perf")(jnp.copy(h0),
+                                     tuple(jnp.copy(u) for u in us0),
+                                     Mus, n)
+    ref_h, ref_us = _rolled_zero_ghost(h0, us0, Mus, cH, cg, n)
+    np.testing.assert_allclose(np.asarray(h), np.asarray(ref_h),
+                               rtol=1e-12)
+    for got, want, M in zip(us, ref_us, Mus):
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                   rtol=1e-12, atol=1e-15)
+        np.testing.assert_array_equal(np.asarray(got)[np.asarray(M) == 0],
+                                      0.0)

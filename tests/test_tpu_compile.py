@@ -539,6 +539,47 @@ def test_swe_perf_scan_is_one_striped_kernel_a_step(topo):
                 if f"[{N + 2},{N + 2}]" in op[2]]
 
 
+def _kernel_operands(op) -> list:
+    """[(name, shape)] of a custom call's operands, in order: the names
+    from its argument list, the shapes from its layout constraints."""
+    args = op[5].split("custom-call(", 1)[1].split(")", 1)[0]
+    names = [re.sub(r"/\*.*?\*/", "", a).strip() for a in args.split(",")]
+    rest = op[5].split("operand_layout_constraints=", 1)[1]
+    shapes = re.findall(r"(\w+\[[\d,]*\])\{[\d,]*\}",
+                        rest[:rest.index("}}") + 2])
+    assert len(names) == len(shapes)
+    return list(zip(names, shapes))
+
+
+def test_swe_perf_kernel_reads_one_byte_wall_code(topo):
+    """Each `masked_swe_step` of the 12288² SWE scan reads the state's
+    three fields and exactly one other operand, a 1-byte 12288² wall
+    code: no f32 face mask reaches the kernel."""
+    with pytest.MonkeyPatch.context() as mp:
+        prog = _swe_perf_program(topo, mp, N)
+    kernels = _kernels(prog)
+    assert kernels
+    for op in kernels:
+        operands = _kernel_operands(op)
+        assert len(operands) == 8
+        assert sorted(dict(operands).values()) == (
+            [f"f32[{N},{N}]"] * 3 + [f"s8[{N},{N}]"])
+
+
+def test_swe_perf_scan_packs_the_walls_once_a_call(topo):
+    """The wall code is made by one op of the advance's own computation,
+    once a call, outside the while loop; no loop body makes one."""
+    with pytest.MonkeyPatch.context() as mp:
+        prog = _swe_perf_program(topo, mp, N)
+    made = [(comp, op[1]) for comp, ops in prog["comps"].items()
+            if comp not in prog["fused"] for op in ops
+            if op[2].startswith(f"s8[{N},{N}]")
+            and op[1] not in ("get-tuple-element", "parameter", "tuple")]
+    assert len(made) == 1
+    assert made[0][0] not in prog["bodies"]
+    assert made[0][1] == "fusion"
+
+
 @pytest.mark.parametrize("cell,ops", [("perf", 91), ("hide", 423)])
 def test_diffusion_cells_keep_their_op_counts(topo, cell, ops):
     """The two 12288² diffusion programs that share the striped kernels'
