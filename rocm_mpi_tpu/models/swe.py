@@ -32,8 +32,11 @@ Variants mirror the flagship's ladder:
            Pallas padded kernel (ops.swe_kernels.swe_step_padded_pallas);
            on one device, the row-striped kernel on the unpadded state
            with no exchange (ops.swe_kernels.masked_swe_step).
-  "hide" — perf's kernel on the boundary-slab/interior overlap
-           decomposition, pytree state through parallel.overlap.
+  "hide" — the boundary-slab/interior overlap decomposition, pytree
+           state through parallel.overlap: in 2D the striped kernel
+           over the whole shard as the interior and the slab kernel
+           (ops.swe_kernels.swe_slab_step) on the slabs; in 3D perf's
+           padded kernel on every region.
 Plus run_deep (width-k ghost exchange of all fields once per k steps) and
 run_vmem_resident (whole loop in one Pallas kernel, single shard).
 """
@@ -184,11 +187,11 @@ class ShallowWater(Driver):
     def _step(self, variant: str):
         """(`step((h, us), P) -> (h', us')`, prepare): `P` is the face
         masks `Mus` as they are (`prepare` None), except on one device's
-        `perf` (and `hide`, which routes there), where `prepare` packs
-        them once a call into the 1-byte wall code
-        (ops.swe_kernels.pack_walls) that the striped kernel reads. That
-        path honours any 0/1 mask pattern, an interior wall or all ones
-        included."""
+        `perf` (and `hide`, which routes there) and the 2D sharded
+        `hide`, where `prepare` packs them once a call into the 1-byte
+        wall code (ops.swe_kernels.pack_walls) that the kernels read.
+        Those paths honour any 0/1 mask pattern, an interior wall or all
+        ones included."""
         cfg, grid = self.config, self.grid
         dt = cfg.dt
         cH, cg = swe_coeffs(dt, cfg.spacing, cfg.H0, cfg.g)
@@ -278,6 +281,8 @@ class ShallowWater(Driver):
                 # No neighbors → nothing to hide (same routing policy as
                 # the diffusion and wave models' single-device hide).
                 return self._step("perf")
+            if cfg.ndim == 2:
+                return self._hide_2d(cH, cg)
 
             def pu(Sp, Ml, lam, dt_, spacing):
                 del lam
@@ -315,6 +320,48 @@ class ShallowWater(Driver):
         raise ValueError(
             f"unknown SWE variant {variant!r} (ap, shard, perf, hide)"
         )
+
+    def _hide_2d(self, cH, cg):
+        """The 2D sharded `hide` step and its `prepare`, pack_walls: the
+        overlap splice (parallel.overlap) with `masked_swe_step` over the
+        whole unpadded shard as its interior (its values next to a shard
+        face that is not a global face are wrong, and the slabs overwrite
+        them) and `swe_slab_step` on each boundary slab's window, both
+        reading the shard's wall code, packed once a call."""
+        from rocm_mpi_tpu.ops.swe_kernels import (
+            masked_swe_step,
+            pack_walls,
+            swe_slab_step,
+        )
+        from rocm_mpi_tpu.parallel.overlap import make_overlap_step
+
+        cfg, grid = self.config, self.grid
+
+        def interior(S, walls, lam, dt_, spacing):
+            h, us = masked_swe_step(S[0], S[1:], walls, cH, cg)
+            return (h, *us)
+
+        def slab(Sp, walls, lam, dt_, spacing):
+            return swe_slab_step(Sp, walls, cH, cg)
+
+        local = make_overlap_step(
+            grid, slab, cfg.b_width, mask_boundary=False,
+            wire_mode=cfg.wire_mode, interior_update=interior,
+        )
+
+        def step(s, walls):
+            h, us = s
+            outs = shard_map(
+                lambda hl, ul, vl, wl: local((hl, ul, vl), wl, None,
+                                             cfg.dt, cfg.spacing),
+                mesh=grid.mesh,
+                in_specs=(grid.spec,) * 4,
+                out_specs=(grid.spec,) * 3,
+                check_vma=False,
+            )(h, *us, walls)
+            return outs[0], tuple(outs[1:])
+
+        return step, pack_walls
 
     # ---- multi-tenant batching (docs/SERVING.md) ------------------------
 

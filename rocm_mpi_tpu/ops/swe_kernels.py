@@ -30,7 +30,9 @@ definition (`rolled_swe_step`) serves the ap variant, the VMEM-resident
 multi-step kernel, and the deep-halo sweep fallback. `masked_swe_step` is
 the one-chip perf step: the same update as one row-striped Pallas sweep
 of the unpadded state, with zero ghosts at the domain edge, reading its
-walls from the masks packed into a 1-byte code (`pack_walls`).
+walls from the masks packed into a 1-byte code (`pack_walls`), and the
+interior of the 2D sharded hide step, whose boundary slabs
+`swe_slab_step` sweeps from their ghosted windows.
 """
 
 from __future__ import annotations
@@ -146,8 +148,9 @@ def _swe_kernel_whole(*refs, ndim, cH, cg):
 
 
 def swe_step_padded_pallas(Sp, Mus, consts, dt, spacing, interpret=None):
-    """Pallas whole-block form of the padded SWE step (the perf/hide
-    kernel). Falls back to the identical-semantics jnp padded form for
+    """Pallas whole-block form of the padded SWE step (the sharded perf
+    kernel, and the 3D hide step's on every region). Falls back to the
+    identical-semantics jnp padded form for
     blocks beyond the VMEM budget and for dtypes Mosaic cannot compile
     (f64 on a real chip) — same policy as the wave workload's kernel
     (wave_step_padded_pallas: the non-flagship models prefer a slower
@@ -342,6 +345,103 @@ def masked_swe_step(h, us, walls, cH, cg, interpret=None):
         name="masked_swe_step",
     )(h, h, u, u, u, v, v, walls)
     return h, (u, v)
+
+
+# ---------------------------------------------------------------------------
+# Boundary slabs of the 2D sharded hide step: the padded windows the
+# overlap splice assembles (halo.ghosted_window), in row stripes.
+# ---------------------------------------------------------------------------
+
+# The tallest slab stripe: a column slab (thousands of rows, a few
+# columns) takes 1024-row stripes, a row slab (b rows, a whole shard
+# wide) one stripe of a multiple of _SWE_CODE_ROWS.
+_SWE_SLAB_ROWS = 1024
+# Scoped VMEM the slab sweep's pipeline buffers may take (the compiler's
+# scratch, _SWE_SCRATCH_BYTES, on top): a 32-row slab of 12288 columns
+# holds 3 padded stripes, 3 ghost blocks, 3 outputs and the wall code,
+# double buffered, 22 MB.
+_SWE_SLAB_BUFFER_BYTES = 32 * 1024 * 1024
+
+
+def _swe_slab_kernel(h_ref, hg_ref, u_ref, ug_ref, v_ref, vg_ref, w_ref,
+                     oh_ref, ou_ref, ov_ref, *, cH, cg):
+    """One forward-backward step of output stripe i of a slab, core rows
+    [i·tm, i·tm+tm): padded rows [i·tm, i·tm+tm+2) of each window are the
+    stripe's own block and the first two rows of the ghost block below
+    it, the walls the code's stripe, and the update _swe_padded_math, as
+    swe_step_padded computes it. Rows past the window feed only rows past
+    the slab, which are dropped."""
+    ext = _upcast_for_compute(*(
+        jnp.concatenate([c[:], g[:2]], 0)
+        for c, g in ((h_ref, hg_ref), (u_ref, ug_ref), (v_ref, vg_ref))))
+    hp, up, vp = ext
+    Mus = unpack_walls(w_ref[:].astype(jnp.int32), 2, hp.dtype)
+    for o_ref, r in zip((oh_ref, ou_ref, ov_ref),
+                        _swe_padded_math(hp, (up, vp), Mus, cH, cg)):
+        o_ref[:] = r.astype(o_ref.dtype)
+
+
+def _swe_slab_stripe_height(m: int, pad_row_bytes: int, core_row_bytes: int,
+                            code_row_bytes: int) -> int:
+    """The tallest stripe, up to _SWE_SLAB_ROWS (a multiple of the wall
+    code's tile rows, and no taller than the slab rounded up to them),
+    whose pipeline buffers fit _SWE_SLAB_BUFFER_BYTES."""
+    g, c = _SWE_GHOST_ROWS, _SWE_CODE_ROWS
+    per_row = 3 * pad_row_bytes + 3 * core_row_bytes + code_row_bytes
+    fit = (_SWE_SLAB_BUFFER_BYTES // 2 - 3 * g * pad_row_bytes) // per_row
+    return max(c, min(fit // c * c, _SWE_SLAB_ROWS, -(-m // c) * c))
+
+
+def swe_slab_step(Sp, walls, cH, cg, interpret=None):
+    """One forward-backward step of a boundary slab of the 2D sharded
+    `hide` step, as one Pallas program named `swe_slab_step`.
+
+    `Sp = (hp, up, vp)` are the slab's width-1-padded windows as
+    halo.ghosted_window assembles them (the ghosts the neighbours sent,
+    zeros at a global face) and `walls` the slab's cells of the wall
+    code (pack_walls). Returns the (h', u', v') of the slab's cells,
+    swe_step_padded's update with the masks read from the code: u' and
+    v' read h' one cell after, which the window's ghost ring gives. The
+    slab is swept in row stripes (_swe_slab_stripe_height), each with
+    the 8-row block below it for its two rows past the stripe, as
+    masked_swe_step takes its ghost rows. f64 on the chip takes the same
+    update in jnp."""
+    hp = Sp[0]
+    if interpret is None:
+        interpret = _interpret_default()
+    core = walls.shape
+    if not _supports_compiled(hp.dtype) and not interpret:
+        return _swe_padded_math(hp, Sp[1:], unpack_walls(walls, 2, hp.dtype),
+                                cH, cg)
+    g = _SWE_GHOST_ROWS
+    m, n = core
+    pad_row_bytes = _compute_itemsize(hp.dtype) * _tiled_elems((n + 2,))
+    core_row_bytes = _compute_itemsize(hp.dtype) * _tiled_elems((n,))
+    code_row_bytes = walls.dtype.itemsize * _tiled_elems((n,))
+    tm = _swe_slab_stripe_height(m, pad_row_bytes, core_row_bytes,
+                                 code_row_bytes)
+    r, n_ghost = tm // g, -(-(m + 2) // g)
+    stripe = pl.BlockSpec((tm, n + 2), lambda i: (i, 0),
+                          memory_space=pltpu.VMEM)
+    ghost = pl.BlockSpec((g, n + 2),
+                         lambda i: (jnp.minimum((i + 1) * r, n_ghost - 1), 0),
+                         memory_space=pltpu.VMEM)
+    out = pl.BlockSpec((tm, n), lambda i: (i, 0), memory_space=pltpu.VMEM)
+    buffers = 2 * (tm * (3 * pad_row_bytes + 3 * core_row_bytes
+                         + code_row_bytes) + 3 * g * pad_row_bytes)
+    kernel = functools.partial(_swe_slab_kernel, cH=tuple(cH), cg=tuple(cg))
+    outs = pl.pallas_call(
+        kernel,
+        out_shape=tuple(_out_struct(core, hp) for _ in range(3)),
+        grid=(-(-m // tm),),
+        in_specs=[stripe, ghost] * 3 + [out],
+        out_specs=(out, out, out),
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=buffers + _SWE_SCRATCH_BYTES),
+        interpret=interpret,
+        name="swe_slab_step",
+    )(Sp[0], Sp[0], Sp[1], Sp[1], Sp[2], Sp[2], walls)
+    return tuple(outs)
 
 
 def _swe_multi_step_kernel(*refs, ndim, cH, cg, chunk):

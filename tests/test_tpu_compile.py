@@ -588,3 +588,38 @@ def test_diffusion_cells_keep_their_op_counts(topo, cell, ops):
     with pytest.MonkeyPatch.context() as mp:
         prog = _program(_compiled(topo, mp, cell, N, dims=dims))
     assert _moves_signature(prog)[0] == ops
+
+
+# ---- shallow water's 2D hide step on the 2x2 mesh -----------------------
+
+
+def test_swe_hide_2x2_runs_its_two_kernels_at_12288(topo):
+    """The swe2d-2x2-12288-hide cell's program, 12288² a chip on the
+    2x2 mesh, q = 10, compiles with both kernels in scoped VMEM: each
+    step is one whole-shard `masked_swe_step` (region="interior") and
+    four `swe_slab_step`s (region="slab"), two steps to a scan
+    iteration; no region takes the padded forms, the exchange is there,
+    and no loop op is of the ghost-ringed shard's shape."""
+    from rocm_mpi_tpu.models.swe import SWEConfig, ShallowWater
+    from rocm_mpi_tpu.ops import swe_kernels
+
+    n = 2 * N
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(swe_kernels, "_interpret_default", lambda: False)
+        model = ShallowWater(
+            SWEConfig(global_shape=(n, n), dtype="f32", dims=(2, 2),
+                      nt=100, warmup=10),
+            devices=topo.devices,
+        )
+        advance, _ = model.scan_advance_fn("hide")
+        a = _sds((n, n), model.grid.sharding)
+        prog = _program(advance.lower(a, (a, a), (a, a), 100).compile()
+                        .as_text())
+    kernels = collections.Counter(
+        (op[4], op[0].rsplit(".", 1)[0]) for op in _kernels(prog))
+    assert kernels == {("interior", "masked_swe_step"): 2,
+                       ("slab", "swe_slab_step"): 8}
+    loop = _loop_ops(prog)
+    assert any(op[1] == "collective-permute-start" for _, op in loop)
+    assert not [op for _, op in loop
+                if op[2].startswith(f"f32[{N + 2},{N + 2}]")]
